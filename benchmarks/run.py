@@ -7,6 +7,9 @@
   bench_roofline  EXPERIMENTS.md §Roofline table from dry-run artifacts
 
 Usage: PYTHONPATH=src python -m benchmarks.run [--full]
+
+A failing suite is recorded in the results file and the rest still run;
+the exit code is then 1.
 """
 from __future__ import annotations
 
@@ -22,13 +25,16 @@ from benchmarks import (bench_kernels, bench_mining, bench_roofline,
                         bench_sparse, bench_streams)
 
 
-def main() -> None:
+def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="full dataset sweep (slow); default quick mode")
     ap.add_argument("--only", default="",
                     help="comma list: mining,kernels,streams,sparse,roofline")
-    args = ap.parse_args()
+    ap.add_argument("--out", default=os.path.join(
+        os.path.dirname(__file__), "..", "experiments", "bench_results.json"),
+        help="results JSON path")
+    args = ap.parse_args(argv)
     quick = not args.full
     wanted = set(args.only.split(",")) if args.only else None
     suites = {
@@ -38,7 +44,7 @@ def main() -> None:
         "sparse": bench_sparse.run,
         "roofline": bench_roofline.run,
     }
-    results = {}
+    results, failed = {}, []
     for name, fn in suites.items():
         if wanted and name not in wanted:
             continue
@@ -49,9 +55,9 @@ def main() -> None:
         except Exception as e:  # keep the harness going; record the failure
             print(f"[{name}] FAILED: {e!r}", flush=True)
             results[name] = {"error": repr(e)}
+            failed.append(name)
         print(f"===== {name} done in {time.time()-t0:.1f}s =====", flush=True)
-    out = os.path.join(os.path.dirname(__file__), "..", "experiments",
-                       "bench_results.json")
+    out = args.out
     os.makedirs(os.path.dirname(out), exist_ok=True)
 
     def default(o):
@@ -59,7 +65,11 @@ def main() -> None:
 
     json.dump(results, open(out, "w"), indent=1, default=default)
     print(f"\n[bench] results -> {out}")
+    if failed:
+        print(f"[bench] FAILED suites: {', '.join(failed)}", flush=True)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -47,12 +47,13 @@ with tempfile.TemporaryDirectory() as d:
         "from repro.configs import get_arch\n"
         "from repro.models.transformer import Model, shapes_and_axes\n"
         "from repro.distributed.sharding import DEFAULT_RULES, "
-        "make_mesh_compat, shard_params_tree\n"
+        "shard_params_tree\n"
         "from repro.train.checkpoint import CheckpointManager\n"
         f"cm = CheckpointManager({ck!r})\n"
         "spec = get_arch('qwen3-0.6b'); model = Model(spec.smoke_config)\n"
         "shapes, axes = shapes_and_axes(model)\n"
-        "mesh = make_mesh_compat((4,2), ('data','model'))\n"
+        "mesh = jax.make_mesh((4,2), ('data','model'), "
+        "axis_types=(jax.sharding.AxisType.Auto,) * 2)\n"
         "psh = shard_params_tree(shapes, axes, mesh, DEFAULT_RULES)\n"
         "params, _, man = cm.restore(None, shapes, None, mesh, psh)\n"
         "print('[ft] elastic restore onto', mesh.shape, 'at step', man['step'], 'OK')\n")

@@ -4,7 +4,7 @@ Layers:
   core/        the paper's stream ISA as composable JAX ops
   graph/       CSR graph substrate (padded, degree-bucketed, bitmaps)
   mining/      pattern-enumeration applications + baselines
-  kernels/     Pallas TPU kernels (validated in interpret mode on CPU)
+  kernels/     Pallas TPU kernels (compiled on TPU, interpret mode on CPU)
   sparse/      S_VINTER applications: SpMM, TTV
   models/      assigned LM architecture zoo
   train/       training / serving runtime

@@ -16,7 +16,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -53,6 +52,6 @@ def tree_compressed_mean(grads, mesh, axis_name: str, err_tree=None):
     def body(g_tree):
         return jax.tree.map(lambda g: compressed_mean(g, axis_name)[0], g_tree)
 
-    fn = shard_map(body, mesh=mesh, in_specs=(specs,), out_specs=specs,
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(specs,), out_specs=specs,
+                       check_vma=False)
     return fn(grads)

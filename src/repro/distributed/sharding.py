@@ -27,23 +27,7 @@ import dataclasses
 import threading
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-
-def make_mesh_compat(axis_shapes, axis_names, *, devices=None) -> Mesh:
-    """``jax.make_mesh`` across jax versions.
-
-    jax >= 0.5 accepts (and on some versions wants) ``axis_types``; 0.4.x
-    does not have ``jax.sharding.AxisType`` at all. Everything in this repo
-    uses plain Auto axes, so the portable call simply omits the kwarg when
-    the enum is missing.
-    """
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axis_names)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 def make_mining_mesh(shards: int | None = None, axis: str = "mine", *,
@@ -65,20 +49,8 @@ def make_mining_mesh(shards: int | None = None, axis: str = "mine", *,
             f"mining mesh wants {n} shards but only {len(devs)} device(s) "
             f"are visible; on CPU set "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={n}")
-    return make_mesh_compat((n,), (axis,), devices=devs[:n])
-
-
-def abstract_mesh(axis_shapes, axis_names):
-    """``jax.sharding.AbstractMesh`` across jax versions.
-
-    0.4.x takes a single ``((name, size), ...)`` tuple; newer jax takes
-    ``(axis_shapes, axis_names)``. Only axis sizes matter for resolution
-    logic, so either spelling yields an equivalent mesh here.
-    """
-    try:
-        return jax.sharding.AbstractMesh(tuple(axis_shapes), tuple(axis_names))
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axis_names, axis_shapes)))
+    return jax.make_mesh((n,), (axis,), axis_types=(AxisType.Auto,),
+                         devices=devs[:n])
 
 
 class Axes(tuple):

@@ -3,7 +3,7 @@
   intersect.py  batched bounded intersection (count / match-mark) with the
                 scalar-prefetched tile-overlap schedule (the S-Cache
                 prefetcher as a static schedule)
-  svinter.py    S_VINTER: intersect keys then MAC the value pairs on the MXU
+  svinter.py    S_VINTER: intersect keys then MAC the matched value pairs
   bitmap.py     beyond-paper bitmap path: AND + popcount for dense rows
   ops.py        backend dispatch (pallas on TPU, interpret on CPU, xla ref)
   ref.py        pure-jnp oracles

@@ -40,35 +40,33 @@ def keys_to_bitmap(keys: jax.Array, num_bits: int) -> jax.Array:
 
 
 def _and_count_kernel(a_ref, b_ref, out_ref):
-    j = pl.program_id(1)
-    anded = a_ref[0, :] & b_ref[0, :]
-    cnt = jnp.sum(jax.lax.population_count(anded))
+    anded = a_ref[0] & b_ref[0]                         # (1, TW)
+    cnt = jnp.sum(jax.lax.population_count(anded), axis=1, keepdims=True)
 
-    @pl.when(j == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _init():
-        out_ref[0, 0] = 0
+        out_ref[0] = jnp.zeros((1, 1), jnp.int32)
 
-    out_ref[0, 0] += cnt
+    out_ref[0] += cnt
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def bitmap_and_count_pallas(a_words: jax.Array, b_words: jax.Array,
-                            interpret: bool = True) -> jax.Array:
-    """counts[i] = popcount(A_i & B_i) over int32 word rows."""
+def bitmap_and_count_pallas(a_words: jax.Array, b_words: jax.Array, *,
+                            interpret: bool) -> jax.Array:
+    """counts[i] = popcount(A_i & B_i) over int32 word rows (rows travel as
+    (B, 1, W) so each (1, 1, TW) block is TPU-tileable)."""
     B, W = a_words.shape
     assert b_words.shape == (B, W) and W % TW == 0
+    spec = pl.BlockSpec((1, 1, TW), lambda i, j: (i, 0, j))
     out = pl.pallas_call(
         _and_count_kernel,
         grid=(B, W // TW),
-        in_specs=[
-            pl.BlockSpec((1, TW), lambda i, j: (i, j)),
-            pl.BlockSpec((1, TW), lambda i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.int32),
+        in_specs=[spec, spec],
+        out_specs=pl.BlockSpec((1, 1, 1), lambda i, j: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, 1, 1), jnp.int32),
         interpret=interpret,
-    )(a_words, b_words)
-    return out[:, 0]
+    )(a_words[:, None, :], b_words[:, None, :])
+    return out[:, 0, 0]
 
 
 def bitmap_and_count_ref(a_words: jax.Array, b_words: jax.Array) -> jax.Array:

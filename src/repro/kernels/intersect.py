@@ -13,7 +13,7 @@ TPU adaptation of the paper's Intersection Unit (§IV-C):
   B-tiles (one vmapped searchsorted over tile boundary keys) and feed both
   tables through *scalar prefetch*, so the grid's index_map only ever DMAs
   B-tiles that can intersect: the S-Cache prefetcher reborn as a static
-  schedule. Total tile visits obey the merge bound O((|A|+|B|)/T) per row.
+  schedule. B-tile DMA obeys the merge bound O((|A|+|B|)/T) per row.
 
 * Early termination (the R3 bound operand, §III-B) zeroes the visit count of
   every A-tile whose minimum exceeds the bound — whole tiles are skipped,
@@ -94,237 +94,418 @@ def tile_schedule(a: jax.Array, b: jax.Array, bounds: jax.Array,
     return lo_t, nv
 
 
-def _count_kernel(lo_ref, nv_ref, a_ref, b_ref, bound_ref, lbound_ref,
-                  out_ref):
+# ---------------------------------------------------------------------------
+# TPU layout (Mosaic)
+# ---------------------------------------------------------------------------
+# A grid step takes a block of R = 8 rows (one sublane tile): the (8, 128)
+# A tile of those rows and one (8, 128) B tile column that every row of the
+# block visits. Mosaic tiles the last two dims of a block in (8, 128) units,
+# so these blocks — and (8, 1) blocks of per-row scalars — are legal where
+# single-row (1, 128) blocks are not. In the kernel each row's B tile turns
+# into a (128, 1) column through one transpose of the block, its (128, 128)
+# match mask reduces to a (1, 128) hit row, and accumulators are stored as
+# vectors (VMEM takes no scalar stores).
+#
+# The block visits the union of its rows' B-tile windows (``_block_windows``),
+# each tile once. That is exact: a row's matches lie inside its own window
+# (tile_schedule), and a tile outside it can only hold keys that match
+# nothing in the A tile or fall outside the row's bound window, which the
+# kernel masks anyway. The grid has cap_b / TB visit steps, the widest a
+# union window can be; steps past a block's window repeat its last tile
+# (no DMA) and accumulate nothing, but the compare itself runs on every
+# step, so the compare work is rows x cap_a x cap_b whatever the windows.
+#
+# The scalar-prefetched (lo, n) tables live in SMEM, flattened to 1-D (a
+# 2-D SMEM array pads its minor dim) and bounded per pallas_call by
+# SMEM_TABLE_BYTES: a larger batch is split into row blocks, one
+# pallas_call each, inside the caller's jit (``_by_rows``).
+
+R = 8                       # rows per grid step (the TPU sublane count)
+SMEM_TABLE_BYTES = 512 << 10
+
+
+def rows_per_call(n_rows: int, n_schedules: int, n_a_tiles: int) -> int:
+    """Rows (a multiple of R) one pallas_call may take so that its
+    ``n_schedules`` (lo, n) int32 table pairs of ``n_a_tiles`` entries per
+    R-row block fit SMEM_TABLE_BYTES."""
+    per_block = 2 * 4 * n_schedules * n_a_tiles
+    return R * max(1, min(-(-n_rows // R), SMEM_TABLE_BYTES // per_block))
+
+
+def _by_rows(call, block_rows: int, args: tuple, row_axes: tuple):
+    """``call(*args)`` over row blocks of at most ``block_rows`` rows (the
+    row axis of each arg in ``row_axes``; a multiple of R); the outputs, a
+    tuple of arrays with rows on axis 0, are concatenated back."""
+    n = args[0].shape[row_axes[0]]
+    if block_rows >= n:
+        return call(*args)
+    calls = -(-n // block_rows)
+    step = R * -(-n // (R * calls))              # equal blocks, <= 2 shapes
+    outs = [call(*(jax.lax.slice_in_dim(x, lo, min(lo + step, n), axis=ax)
+                   for x, ax in zip(args, row_axes)))
+            for lo in range(0, n, step)]
+    return tuple(jnp.concatenate(parts, axis=0) for parts in zip(*outs))
+
+
+def _pad_rows(x, axis: int, fill):
+    """Pad the row axis of ``x`` to a multiple of R with ``fill``."""
+    pad = -x.shape[axis] % R
+    if not pad:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, pad)
+    return jnp.pad(x, widths, constant_values=fill)
+
+
+def _block_windows(lo_t, nv):
+    """Per-row (..., B, nA) tile windows -> per R-row block (..., B/R, nA)
+    union windows (lo, n), flattened to 1-D for SMEM."""
+    *lead, B, n_a = lo_t.shape
+    lo = lo_t.reshape(*lead, B // R, R, n_a)
+    nv = nv.reshape(*lead, B // R, R, n_a)
+    live = nv > 0
+    first = jnp.min(jnp.where(live, lo, jnp.iinfo(jnp.int32).max), axis=-2)
+    end = jnp.max(jnp.where(live, lo + nv, 0), axis=-2)
+    n = jnp.maximum(end - first, 0)
+    return jnp.where(n > 0, first, 0).reshape(-1), n.reshape(-1)
+
+
+def _b_col(lo, n, idx):
+    """B-tile column of a visit: the window's tiles in order, then the last
+    one repeated (a resident tile: no DMA) for steps past the window."""
+    return lambda j: lo[idx] + jnp.minimum(j, jnp.maximum(n[idx] - 1, 0))
+
+
+def _row_masks(a, b):
+    """(R, TA) A tiles and (R, TB) B tiles -> per row r the (TB, TA) mask
+    m_r[t, s] = (a[r, s] == b[r, t])."""
+    bt = jnp.transpose(b)                                   # (TB, R)
+    return [bt[:, r:r + 1] == a[r:r + 1, :] for r in range(R)]
+
+
+def _stack_rows(rows, dtype=jnp.int32):
+    """R (1, N) rows -> one (R, N) array (selects; no sublane concat)."""
+    sub = jax.lax.broadcasted_iota(jnp.int32, (R, rows[0].shape[1]), 0)
+    out = jnp.zeros((R, rows[0].shape[1]), dtype)
+    for r, row in enumerate(rows):
+        out = jnp.where(sub == r, row, out)
+    return out
+
+
+def _hits(masks):
+    """Per-row match masks -> (R, TA) int32 0/1 hit per A slot."""
+    return _stack_rows([jnp.max(m.astype(jnp.int32), axis=0, keepdims=True)
+                        for m in masks])
+
+
+def _row_sum(x):
+    """(R, N) -> (R, 1) int32 row sums."""
+    return jnp.sum(x.astype(jnp.int32), axis=1, keepdims=True)
+
+
+def _window(a, bound_ref, lbound_ref):
+    """Live A slots inside each row's (lbound, bound) window, (R, TA)."""
+    return (a != SENTINEL) & (a < bound_ref[...]) & (a > lbound_ref[...])
+
+
+def _pair_kernel(want_mark: bool, want_count: bool, lo_ref, nv_ref, a_ref,
+                 b_ref, bound_ref, lbound_ref, *out_refs):
+    """Bounded S_INTER over one (row block, A-tile, visit) grid step: the
+    per-slot mark, the row counts, or both (the fused expand: one pass over
+    the tile schedule feeds the compaction mask and the survivor count)."""
     bi, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    a = a_ref[0, :]
-    bt = b_ref[0, :]
-    bound = bound_ref[0, 0]
-    valid = (a != SENTINEL) & (a < bound) & (a > lbound_ref[0, 0])
-    m = (a[:, None] == bt[None, :]) & valid[:, None]
-    cnt = jnp.sum(m.astype(jnp.int32))
+    a = a_ref[...]
+    hit = jnp.where(_window(a, bound_ref, lbound_ref),
+                    _hits(_row_masks(a, b_ref[...])), 0)
+    live = j < nv_ref[bi * pl.num_programs(1) + i]
+    if want_mark:
+        mark_ref = out_refs[0]
 
-    @pl.when((i == 0) & (j == 0))
-    def _init():
-        out_ref[0, 0] = 0
+        @pl.when(j == 0)
+        def _init_mark():
+            mark_ref[...] = jnp.zeros_like(hit)
 
-    @pl.when(j < nv_ref[bi, i])
-    def _acc():
-        out_ref[0, 0] += cnt
+        @pl.when(live)
+        def _acc_mark():
+            mark_ref[...] = mark_ref[...] | hit
+    if want_count:
+        cnt_ref = out_refs[-1]
 
+        @pl.when((i == 0) & (j == 0))
+        def _init_cnt():
+            cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
-def _mark_kernel(lo_ref, nv_ref, a_ref, b_ref, bound_ref, lbound_ref, out_ref):
-    bi, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    a = a_ref[0, :]
-    bt = b_ref[0, :]
-    bound = bound_ref[0, 0]
-    valid = (a != SENTINEL) & (a < bound) & (a > lbound_ref[0, 0])
-    hit = (jnp.sum(((a[:, None] == bt[None, :]) & valid[:, None])
-                   .astype(jnp.int32), axis=1) > 0)
-
-    @pl.when(j == 0)
-    def _init():
-        out_ref[0, :] = jnp.zeros_like(out_ref[0, :])
-
-    @pl.when(j < nv_ref[bi, i])
-    def _acc():
-        out_ref[0, :] = out_ref[0, :] | hit.astype(jnp.int32)
+        # B-rows are sorted sets and each tile is visited once: an A-slot
+        # matches at most once, so summing per-visit hits never double counts
+        @pl.when(live)
+        def _acc_cnt():
+            cnt_ref[...] += _row_sum(hit)
 
 
-def _expand_kernel(lo_ref, nv_ref, a_ref, b_ref, bound_ref, lbound_ref,
-                   mark_ref, cnt_ref):
-    """Fused mark + count: one pass over the tile schedule feeds both the
-    compaction mask and the survivor count (the device expand_compact path
-    needs both; issuing two kernels would double the B-tile DMA traffic)."""
-    bi, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    a = a_ref[0, :]
-    bt = b_ref[0, :]
-    bound = bound_ref[0, 0]
-    valid = (a != SENTINEL) & (a < bound) & (a > lbound_ref[0, 0])
-    hit = (jnp.sum(((a[:, None] == bt[None, :]) & valid[:, None])
-                   .astype(jnp.int32), axis=1) > 0)
-
-    @pl.when(j == 0)
-    def _init_mark():
-        mark_ref[0, :] = jnp.zeros_like(mark_ref[0, :])
-
-    @pl.when((i == 0) & (j == 0))
-    def _init_cnt():
-        cnt_ref[0, 0] = 0
-
-    @pl.when(j < nv_ref[bi, i])
-    def _acc():
-        # B-rows are sorted sets: an A-slot matches in at most one B-tile,
-        # so summing per-visit hits never double counts.
-        mark_ref[0, :] = mark_ref[0, :] | hit.astype(jnp.int32)
-        cnt_ref[0, 0] += jnp.sum(hit.astype(jnp.int32))
+def _bounds_of(B, bounds, lbounds):
+    ub = jnp.full((B,), SENTINEL, jnp.int32) if bounds is None \
+        else jnp.asarray(bounds, jnp.int32)
+    lb = jnp.full((B,), -1, jnp.int32) if lbounds is None \
+        else jnp.asarray(lbounds, jnp.int32)         # ids >= 0: no-op bound
+    return ub, lb
 
 
-def _common(a, b, bounds, max_visits, lbounds=None):
+def _pair_call(a, b, bounds, lbounds, interpret, want_mark: bool,
+               want_count: bool):
+    """Run ``_pair_kernel`` -> (mark (B, cap_a) | None, counts (B,) | None)."""
     B, cap_a = a.shape
     cap_b = b.shape[1]
     assert cap_a % TA == 0 and cap_b % TB == 0, "streams are LANE-padded"
-    if bounds is None:
-        bounds = jnp.full((B,), SENTINEL, jnp.int32)
-    bounds = jnp.asarray(bounds, jnp.int32)
-    if lbounds is None:
-        lbounds = jnp.full((B,), -1, jnp.int32)   # ids >= 0: no-op bound
-    lbounds = jnp.asarray(lbounds, jnp.int32)
+    bounds, lbounds = _bounds_of(B, bounds, lbounds)
+    # padding rows: all-SENTINEL streams, bound 0 (dead)
+    a, b = _pad_rows(a, 0, SENTINEL), _pad_rows(b, 0, SENTINEL)
+    bounds, lbounds = _pad_rows(bounds, 0, 0), _pad_rows(lbounds, 0, -1)
     lo_t, nv = tile_schedule(a, b, bounds, lbounds)
-    if max_visits is None:
-        max_visits = cap_b // TB          # static worst case (merge bound
-        #                                   tightens this when known on host)
-    grid = (B, cap_a // TA, int(max_visits))
-    return bounds, lbounds, lo_t, nv, grid, cap_b
+    n_a = cap_a // TA
+    a_spec = pl.BlockSpec((R, TA), lambda bi, i, j, lo, nv: (bi, i))
+    row_spec = pl.BlockSpec((R, 1), lambda bi, i, j, lo, nv: (bi, 0))
+    b_spec = pl.BlockSpec(
+        (R, TB),
+        lambda bi, i, j, lo, nv: (bi, _b_col(lo, nv, bi * n_a + i)(j)))
+    kernel = functools.partial(_pair_kernel, want_mark, want_count)
+
+    def call(lo_t, nv, a, b, bounds, lbounds):
+        n = a.shape[0]
+        out_specs, out_shape = [], []
+        if want_mark:
+            out_specs.append(a_spec)
+            out_shape.append(jax.ShapeDtypeStruct((n, cap_a), jnp.int32))
+        if want_count:
+            out_specs.append(row_spec)
+            out_shape.append(jax.ShapeDtypeStruct((n, 1), jnp.int32))
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(n // R, n_a, cap_b // TB),
+                in_specs=[a_spec, b_spec, row_spec, row_spec],
+                out_specs=tuple(out_specs),
+            ),
+            out_shape=tuple(out_shape),
+            interpret=interpret,
+        )(*_block_windows(lo_t, nv), a, b, bounds[:, None], lbounds[:, None])
+
+    outs = _by_rows(call, rows_per_call(a.shape[0], 1, n_a),
+                    (lo_t, nv, a, b, bounds, lbounds), (0,) * 6)
+    mark = outs[0][:B] if want_mark else None
+    counts = outs[-1][:B, 0] if want_count else None
+    return mark, counts
 
 
-def _b_index(bi, i, j, lo, nv, cap_b):
-    # visit lo+j, clamped (skipped steps re-point at a resident tile: no DMA)
-    return (bi, jnp.minimum(lo[bi, i] + j, cap_b // TB - 1))
-
-
-@functools.partial(jax.jit, static_argnames=("max_visits", "interpret"))
-def intersect_count_pallas(a, b, bounds=None, max_visits=None, interpret=True,
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def intersect_count_pallas(a, b, bounds=None, *, interpret: bool,
                            lbounds=None):
     """counts[i] = |{k ∈ A_i ∩ B_i : lbounds[i] < k < bounds[i]}|
     (paper S_INTER.C; the lower bound is the beyond-paper lb operand)."""
-    bounds, lbounds, lo_t, nv, grid, cap_b = _common(a, b, bounds, max_visits,
-                                                     lbounds)
-    out = pl.pallas_call(
-        _count_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, TA), lambda bi, i, j, lo, nv: (bi, i)),
-                pl.BlockSpec((1, TB),
-                             lambda bi, i, j, lo, nv: _b_index(bi, i, j, lo, nv, cap_b)),
-                pl.BlockSpec((1, 1), lambda bi, i, j, lo, nv: (bi, 0)),
-                pl.BlockSpec((1, 1), lambda bi, i, j, lo, nv: (bi, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1), lambda bi, i, j, lo, nv: (bi, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((a.shape[0], 1), jnp.int32),
-        interpret=interpret,
-    )(lo_t, nv, a, b, bounds.reshape(-1, 1), lbounds.reshape(-1, 1))
-    return out[:, 0]
+    return _pair_call(a, b, bounds, lbounds, interpret, False, True)[1]
 
 
-@functools.partial(jax.jit, static_argnames=("max_visits", "interpret"))
-def intersect_expand_pallas(a, b, bounds=None, max_visits=None, interpret=True,
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def intersect_expand_pallas(a, b, bounds=None, *, interpret: bool,
                             lbounds=None):
     """Fused S_INTER mark + count in one schedule pass -> (mark, counts).
 
     The device expand_compact path consumes both outputs; fusing them halves
     the B-tile DMA traffic vs running the mark and count kernels separately.
     """
-    bounds, lbounds, lo_t, nv, grid, cap_b = _common(a, b, bounds, max_visits,
-                                                     lbounds)
-    mark, cnt = pl.pallas_call(
-        _expand_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, TA), lambda bi, i, j, lo, nv: (bi, i)),
-                pl.BlockSpec((1, TB),
-                             lambda bi, i, j, lo, nv: _b_index(bi, i, j, lo, nv, cap_b)),
-                pl.BlockSpec((1, 1), lambda bi, i, j, lo, nv: (bi, 0)),
-                pl.BlockSpec((1, 1), lambda bi, i, j, lo, nv: (bi, 0)),
-            ],
-            out_specs=(
-                pl.BlockSpec((1, TA), lambda bi, i, j, lo, nv: (bi, i)),
-                pl.BlockSpec((1, 1), lambda bi, i, j, lo, nv: (bi, 0)),
-            ),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct(a.shape, jnp.int32),
-            jax.ShapeDtypeStruct((a.shape[0], 1), jnp.int32),
-        ),
-        interpret=interpret,
-    )(lo_t, nv, a, b, bounds.reshape(-1, 1), lbounds.reshape(-1, 1))
-    return mark, cnt[:, 0]
+    return _pair_call(a, b, bounds, lbounds, interpret, True, True)
 
 
-@functools.partial(jax.jit, static_argnames=("max_visits", "interpret"))
-def intersect_mark_pallas(a, b, bounds=None, max_visits=None, interpret=True,
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def intersect_mark_pallas(a, b, bounds=None, *, interpret: bool,
                           lbounds=None):
     """mark[i, s] = 1 iff A_i[s] ∈ B_i and lbounds[i] < A_i[s] < bounds[i].
 
     S_INTER materialisation = sort-compact A over this mask (ops.xinter)."""
-    bounds, lbounds, lo_t, nv, grid, cap_b = _common(a, b, bounds, max_visits,
-                                                     lbounds)
-    out = pl.pallas_call(
-        _mark_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, TA), lambda bi, i, j, lo, nv: (bi, i)),
-                pl.BlockSpec((1, TB),
-                             lambda bi, i, j, lo, nv: _b_index(bi, i, j, lo, nv, cap_b)),
-                pl.BlockSpec((1, 1), lambda bi, i, j, lo, nv: (bi, 0)),
-                pl.BlockSpec((1, 1), lambda bi, i, j, lo, nv: (bi, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, TA), lambda bi, i, j, lo, nv: (bi, i)),
-        ),
-        out_shape=jax.ShapeDtypeStruct(a.shape, jnp.int32),
-        interpret=interpret,
-    )(lo_t, nv, a, b, bounds.reshape(-1, 1), lbounds.reshape(-1, 1))
-    return out
+    return _pair_call(a, b, bounds, lbounds, interpret, True, False)[0]
 
 
 # ---------------------------------------------------------------------------
-# fused multi-operand level kernel (k B-streams per grid step)
+# fused multi-operand level kernel (k B-streams per grid step), with an
+# optional value lane (the SVPU, §IV-E)
 # ---------------------------------------------------------------------------
 
+AGG_IDS = {"sum": 0, "max": 1, "min": 2}
+F32_MAX = 3.4e38      # masked-reduce identities (finite: inf trips asserts)
+_AGG_IDENTITY = (0.0, -F32_MAX, F32_MAX)
 
-def _multi_kernel(n_refs: int, n_inter: int, max_visits: int,
-                  lo_ref, nv_ref, a_ref, b_ref, bound_ref, lbound_ref,
-                  excl_ref, mark_ref, cnt_ref):
+
+def _multi_kernel(n_refs: int, n_inter: int, op_id, lo_ref, nv_ref, a_ref, b_ref, bound_ref, lbound_ref,
+                  excl_ref, *refs):
     """One level's whole µop sequence in a single pass.
 
-    Grid (B, nA, k, max_visits): for each (row, A-tile) the k refs stream
-    their scheduled B-tiles through VMEM one after another while the A-tile
-    and its score accumulator stay resident. The score is a weighted hit sum
-    (+1 INTER, -(k+1) SUB; sorted sets hit at most once per ref, so the sum
-    never aliases): score == n_inter  <=>  all INTER refs matched, no SUB
-    ref did. The final grid step folds the bound window and the injectivity
-    excludes and converts the score into the 0/1 keep mask + count."""
+    Grid (B/R, nA, k, cap_b/TB): for each (row block, A-tile) the k refs
+    stream their scheduled B-tiles through VMEM one after another while the
+    A-tile and its score accumulator stay resident. The score is a weighted
+    hit sum (+1 INTER, -(k+1) SUB; sorted sets hit at most once per ref, so
+    the sum never aliases): score == n_inter  <=>  all INTER refs matched,
+    no SUB ref did. The final grid step folds the bound window and the
+    injectivity excludes and converts the score into the 0/1 keep mask +
+    count.
+
+    With ``op_id`` set, a value lane rides the SAME tile schedule (refs then
+    carry a_vals, b_vals, scale, the vals output and two (R, TA) scratch
+    lanes): per visited tile the masked column sum of each row's B values
+    recovers each A-slot's matched value for the current ref (sorted sets:
+    at most one match, so the sum *is* the matched value, exactly). ``vsum``
+    accumulates that per ref across its visits; at each INTER ref's last
+    visit it folds into the running product ``vprod``. The finalize step
+    multiplies in the slot's own feed value and the per-row prefix scale,
+    masks by keep, and reduces into the per-row aggregate with the op's
+    identity — zero extra B-tile DMA."""
+    if op_id is None:
+        mark_ref, cnt_ref = refs
+    else:
+        (aval_ref, bval_ref, scale_ref, mark_ref, cnt_ref, val_ref,
+         vsum_ref, vprod_ref) = refs
     bi, i, r, j = (pl.program_id(0), pl.program_id(1), pl.program_id(2),
                    pl.program_id(3))
-    a = a_ref[0, :]
-    bt = b_ref[0, 0, :]
-    hit = (jnp.sum((a[:, None] == bt[None, :]).astype(jnp.int32), axis=1) > 0)
+    n_blocks, n_a = pl.num_programs(0), pl.num_programs(1)
+    last = j == pl.num_programs(3) - 1
+    a = a_ref[...]
+    masks = _row_masks(a, b_ref[0])
     weight = jnp.where(r < n_inter, 1, -(n_refs + 1))
+    live = j < nv_ref[(r * n_blocks + bi) * n_a + i]
 
     @pl.when((r == 0) & (j == 0))
     def _init_mark():
-        mark_ref[0, :] = jnp.zeros_like(mark_ref[0, :])
+        mark_ref[...] = jnp.zeros_like(a)
 
     @pl.when((i == 0) & (r == 0) & (j == 0))
     def _init_cnt():
-        cnt_ref[0, 0] = 0
+        cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
-    @pl.when(j < nv_ref[r, bi, i])
+    @pl.when(live)
     def _acc():
-        mark_ref[0, :] += hit.astype(jnp.int32) * weight
+        mark_ref[...] += _hits(masks) * weight
 
-    @pl.when((r == n_refs - 1) & (j == max_visits - 1))
+    if op_id is not None:
+        @pl.when((r == 0) & (j == 0))
+        def _init_vprod():
+            vprod_ref[...] = jnp.ones_like(vprod_ref)
+
+        @pl.when(j == 0)
+        def _init_vsum():
+            vsum_ref[...] = jnp.zeros_like(vsum_ref)
+
+        @pl.when((i == 0) & (r == 0) & (j == 0))
+        def _init_val():
+            val_ref[...] = jnp.full(val_ref.shape, _AGG_IDENTITY[op_id],
+                                    jnp.float32)
+
+        @pl.when(live)
+        def _acc_val():
+            bv = jnp.transpose(bval_ref[0])                     # (TB, R)
+            vsum_ref[...] += _stack_rows(
+                [jnp.sum(jnp.where(m, bv[:, s:s + 1], 0.0), axis=0,
+                         keepdims=True) for s, m in enumerate(masks)],
+                jnp.float32)
+
+        @pl.when((r < n_inter) & last)
+        def _fold():
+            vprod_ref[...] *= vsum_ref[...]
+
+    @pl.when((r == n_refs - 1) & last)
     def _finalize():
-        bound = bound_ref[0, 0]
-        valid = (a != SENTINEL) & (a < bound) & (a > lbound_ref[0, 0])
-        ex = excl_ref[0, :]
-        valid = valid & jnp.all(a[:, None] != ex[None, :], axis=1)
-        keep = valid & (mark_ref[0, :] == n_inter)
-        mark_ref[0, :] = keep.astype(jnp.int32)
-        cnt_ref[0, 0] += jnp.sum(keep.astype(jnp.int32))
+        valid = _window(a, bound_ref, lbound_ref)
+        for e in range(excl_ref.shape[1]):
+            valid = valid & (a != excl_ref[:, e:e + 1])
+        keep = valid & (mark_ref[...] == n_inter)
+        mark_ref[...] = keep.astype(jnp.int32)
+        cnt_ref[...] += _row_sum(keep)
+        if op_id is None:
+            return
+        contrib = aval_ref[...] * vprod_ref[...] * scale_ref[...]
+        masked = jnp.where(keep, contrib, _AGG_IDENTITY[op_id])
+        if op_id == 0:
+            val_ref[...] += jnp.sum(masked, axis=1, keepdims=True)
+        elif op_id == 1:
+            val_ref[...] = jnp.maximum(val_ref[...], jnp.max(
+                masked, axis=1, keepdims=True))
+        else:
+            val_ref[...] = jnp.minimum(val_ref[...], jnp.min(
+                masked, axis=1, keepdims=True))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("pol", "max_visits", "interpret"))
-def intersect_multi_pallas(a, bs, pol, bounds=None, max_visits=None,
-                           interpret=True, lbounds=None, excludes=None):
+def _multi_call(a, bs, pol, bounds, lbounds, excludes, interpret,
+                op_id=None, a_vals=None, b_vals=None, scale=None):
+    """Shared body of the k-operand kernel entries -> (mark, counts[, vals])."""
+    assert bs.ndim == 3 and bs.shape[0] == len(pol) >= 1, \
+        "bs must be (k, B, cap_b) matching pol"
+    assert all(p == 1 for p in pol[:sum(pol)]) \
+        and all(p == 0 for p in pol[sum(pol):]), "pol must be INTER-first"
+    B, cap_a = a.shape
+    cap_b = bs.shape[2]
+    assert cap_a % TA == 0 and cap_b % TB == 0, "streams are LANE-padded"
+    bounds, lbounds = _bounds_of(B, bounds, lbounds)
+    if excludes is None:
+        excludes = jnp.full((B, 1), -1, jnp.int32)   # ids >= 0: no-op
+    excludes = jnp.asarray(excludes, jnp.int32)
+    # padding rows: all-SENTINEL streams, bound 0 (dead)
+    args = [_pad_rows(a, 0, SENTINEL), _pad_rows(bs, 1, SENTINEL),
+            _pad_rows(bounds, 0, 0), _pad_rows(lbounds, 0, -1),
+            _pad_rows(excludes, 0, -1)]
+    lo_t, nv = jax.vmap(tile_schedule, in_axes=(None, 0, None, None))(
+        *args[:4])                                   # (k, B, nA) each
+    k = len(pol)
+    n_a = cap_a // TA
+    n_excl = excludes.shape[1]
+    kernel = functools.partial(_multi_kernel, k, int(sum(pol)), op_id)
+    a_spec = pl.BlockSpec((R, TA), lambda bi, i, r, j, lo, nv: (bi, i))
+    row_spec = pl.BlockSpec((R, 1), lambda bi, i, r, j, lo, nv: (bi, 0))
+    excl_spec = pl.BlockSpec((R, n_excl), lambda bi, i, r, j, lo, nv: (bi, 0))
+
+    def call(lo_t, nv, a, bs, bounds, lbounds, excludes, *vals):
+        n = a.shape[0]
+        b_spec = pl.BlockSpec(
+            (1, R, TB),
+            lambda bi, i, r, j, lo, nv: (
+                r, bi, _b_col(lo, nv, (r * (n // R) + bi) * n_a + i)(j)))
+        in_specs = [a_spec, b_spec, row_spec, row_spec, excl_spec]
+        out_specs = [a_spec, row_spec]
+        out_shape = [jax.ShapeDtypeStruct((n, cap_a), jnp.int32),
+                     jax.ShapeDtypeStruct((n, 1), jnp.int32)]
+        args = [a, bs, bounds[:, None], lbounds[:, None], excludes]
+        scratch = []
+        if op_id is not None:
+            av, bv, sc = vals
+            in_specs += [a_spec, b_spec, row_spec]
+            out_specs.append(row_spec)
+            out_shape.append(jax.ShapeDtypeStruct((n, 1), jnp.float32))
+            args += [av, bv, sc[:, None]]
+            scratch = [pltpu.VMEM((R, TA), jnp.float32)] * 2
+        outs = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(n // R, n_a, k, cap_b // TB),
+                in_specs=in_specs,
+                out_specs=tuple(out_specs),
+                scratch_shapes=scratch,
+            ),
+            out_shape=tuple(out_shape),
+            interpret=interpret,
+        )(*_block_windows(lo_t, nv), *args)
+        return (outs[0],) + tuple(o[:, 0] for o in outs[1:])
+
+    axes = (1, 1, 0, 1, 0, 0, 0)
+    if op_id is not None:
+        args += [_pad_rows(a_vals, 0, 0.0), _pad_rows(b_vals, 1, 0.0),
+                 _pad_rows(jnp.asarray(scale, jnp.float32), 0, 0.0)]
+        axes += (0, 1, 0)
+    outs = _by_rows(call, rows_per_call(args[0].shape[0], k, n_a),
+                    (lo_t, nv, *args), axes)
+    return tuple(o[:B] for o in outs)
+
+
+@functools.partial(jax.jit, static_argnames=("pol", "interpret"))
+def intersect_multi_pallas(a, bs, pol, bounds=None, *, interpret: bool,
+                           lbounds=None, excludes=None):
     """Fused k-operand level: conjunctive mark + count in ONE schedule pass.
 
     mark[i, s] = 1 iff   A_i[s] ∈ B^r_i   for every INTER ref r (pol[r]=1)
@@ -341,147 +522,13 @@ def intersect_multi_pallas(a, bs, pol, bounds=None, max_visits=None,
     across the whole level instead of once per mark dispatch re-reading the
     A-tiles, and the count rides the same pass (S_*.C for free).
     """
-    assert bs.ndim == 3 and bs.shape[0] == len(pol) >= 1, \
-        "bs must be (k, B, cap_b) matching pol"
-    assert all(p == 1 for p in pol[:sum(pol)]) \
-        and all(p == 0 for p in pol[sum(pol):]), "pol must be INTER-first"
-    B, cap_a = a.shape
-    cap_b = bs.shape[2]
-    assert cap_a % TA == 0 and cap_b % TB == 0, "streams are LANE-padded"
-    if bounds is None:
-        bounds = jnp.full((B,), SENTINEL, jnp.int32)
-    bounds = jnp.asarray(bounds, jnp.int32)
-    if lbounds is None:
-        lbounds = jnp.full((B,), -1, jnp.int32)
-    lbounds = jnp.asarray(lbounds, jnp.int32)
-    if excludes is None:
-        excludes = jnp.full((B, 1), -1, jnp.int32)   # ids >= 0: no-op
-    excludes = jnp.asarray(excludes, jnp.int32)
-    lo_t, nv = jax.vmap(tile_schedule, in_axes=(None, 0, None, None))(
-        a, bs, bounds, lbounds)                      # (k, B, nA) each
-    if max_visits is None:
-        max_visits = cap_b // TB
-    k = len(pol)
-    grid = (B, cap_a // TA, k, int(max_visits))
-    n_excl = excludes.shape[1]
-    kernel = functools.partial(_multi_kernel, k, int(sum(pol)),
-                               int(max_visits))
-    mark, cnt = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, TA), lambda bi, i, r, j, lo, nv: (bi, i)),
-                pl.BlockSpec(
-                    (1, 1, TB),
-                    lambda bi, i, r, j, lo, nv: (
-                        r, bi, jnp.minimum(lo[r, bi, i] + j,
-                                           cap_b // TB - 1))),
-                pl.BlockSpec((1, 1), lambda bi, i, r, j, lo, nv: (bi, 0)),
-                pl.BlockSpec((1, 1), lambda bi, i, r, j, lo, nv: (bi, 0)),
-                pl.BlockSpec((1, n_excl),
-                             lambda bi, i, r, j, lo, nv: (bi, 0)),
-            ],
-            out_specs=(
-                pl.BlockSpec((1, TA), lambda bi, i, r, j, lo, nv: (bi, i)),
-                pl.BlockSpec((1, 1), lambda bi, i, r, j, lo, nv: (bi, 0)),
-            ),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct(a.shape, jnp.int32),
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
-        ),
-        interpret=interpret,
-    )(lo_t, nv, a, bs, bounds.reshape(-1, 1), lbounds.reshape(-1, 1),
-      excludes)
-    return mark, cnt[:, 0]
+    return _multi_call(a, bs, pol, bounds, lbounds, excludes, interpret)
 
 
-# ---------------------------------------------------------------------------
-# value-carrying multi-operand level kernel (the SVPU lane, §IV-E)
-# ---------------------------------------------------------------------------
-
-AGG_IDS = {"sum": 0, "max": 1, "min": 2}
-F32_MAX = 3.4e38      # masked-reduce identities (finite: inf trips asserts)
-
-
-def _multi_agg_kernel(n_refs: int, n_inter: int, max_visits: int, op_id: int,
-                      lo_ref, nv_ref, a_ref, b_ref, bound_ref, lbound_ref,
-                      excl_ref, aval_ref, bval_ref, scale_ref,
-                      mark_ref, cnt_ref, vsum_ref, vprod_ref, val_ref):
-    """``_multi_kernel`` with a value lane riding the SAME tile schedule.
-
-    The membership side is byte-identical to ``_multi_kernel`` (same score
-    accumulator, same finalize). The value side is svinter's mask-MAC
-    (§IV-E): per visited tile, ``m @ bv`` recovers each A-slot's matched
-    value for the current ref (sorted sets: at most one match, so the MAC
-    *is* the matched value). ``vsum`` accumulates that per ref across its
-    visits; at each INTER ref's last visit it folds into the running
-    product ``vprod``. The finalize step multiplies in the slot's own feed
-    value and the per-row prefix scale, masks by keep, and reduces into the
-    per-row aggregate with the op's identity — zero extra B-tile DMA, one
-    extra VPU MAC per visit."""
-    bi, i, r, j = (pl.program_id(0), pl.program_id(1), pl.program_id(2),
-                   pl.program_id(3))
-    a = a_ref[0, :]
-    bt = b_ref[0, 0, :]
-    m = (a[:, None] == bt[None, :])
-    hit = jnp.sum(m.astype(jnp.int32), axis=1) > 0
-    weight = jnp.where(r < n_inter, 1, -(n_refs + 1))
-    bv = bval_ref[0, 0, :]
-    mv = jnp.dot(m.astype(jnp.float32), bv[:, None],
-                 preferred_element_type=jnp.float32)[:, 0]
-
-    @pl.when((r == 0) & (j == 0))
-    def _init_mark():
-        mark_ref[0, :] = jnp.zeros_like(mark_ref[0, :])
-        vprod_ref[0, :] = jnp.ones_like(vprod_ref[0, :])
-
-    @pl.when(j == 0)
-    def _init_vsum():
-        vsum_ref[0, :] = jnp.zeros_like(vsum_ref[0, :])
-
-    @pl.when((i == 0) & (r == 0) & (j == 0))
-    def _init_cnt():
-        cnt_ref[0, 0] = 0
-        val_ref[0, 0] = jnp.float32(
-            0.0 if op_id == 0 else (-F32_MAX if op_id == 1 else F32_MAX))
-
-    @pl.when(j < nv_ref[r, bi, i])
-    def _acc():
-        mark_ref[0, :] += hit.astype(jnp.int32) * weight
-        vsum_ref[0, :] += mv
-
-    @pl.when((r < n_inter) & (j == max_visits - 1))
-    def _fold():
-        vprod_ref[0, :] *= vsum_ref[0, :]
-
-    @pl.when((r == n_refs - 1) & (j == max_visits - 1))
-    def _finalize():
-        bound = bound_ref[0, 0]
-        valid = (a != SENTINEL) & (a < bound) & (a > lbound_ref[0, 0])
-        ex = excl_ref[0, :]
-        valid = valid & jnp.all(a[:, None] != ex[None, :], axis=1)
-        keep = valid & (mark_ref[0, :] == n_inter)
-        mark_ref[0, :] = keep.astype(jnp.int32)
-        cnt_ref[0, 0] += jnp.sum(keep.astype(jnp.int32))
-        contrib = aval_ref[0, :] * vprod_ref[0, :] * scale_ref[0, 0]
-        if op_id == 0:
-            val_ref[0, 0] += jnp.sum(jnp.where(keep, contrib, 0.0))
-        elif op_id == 1:
-            val_ref[0, 0] = jnp.maximum(
-                val_ref[0, 0], jnp.max(jnp.where(keep, contrib, -F32_MAX)))
-        else:
-            val_ref[0, 0] = jnp.minimum(
-                val_ref[0, 0], jnp.min(jnp.where(keep, contrib, F32_MAX)))
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("pol", "op", "max_visits", "interpret"))
+@functools.partial(jax.jit, static_argnames=("pol", "op", "interpret"))
 def intersect_multi_agg_pallas(a, bs, pol, a_vals, b_vals, scale, op="sum",
-                               bounds=None, max_visits=None, interpret=True,
-                               lbounds=None, excludes=None):
+                               bounds=None, *, interpret: bool, lbounds=None,
+                               excludes=None):
     """``intersect_multi_pallas`` + SVPU value lane -> (mark, counts, vals).
 
     Same k-operand membership contract (see ``intersect_multi_pallas``);
@@ -497,69 +544,6 @@ def intersect_multi_agg_pallas(a, bs, pol, a_vals, b_vals, scale, op="sum",
     the kernel. One dispatch, the same B-tile DMA schedule as the
     unweighted kernel — the value lane is pure VPU work on tiles already
     resident."""
-    assert bs.ndim == 3 and bs.shape[0] == len(pol) >= 1, \
-        "bs must be (k, B, cap_b) matching pol"
-    assert all(p == 1 for p in pol[:sum(pol)]) \
-        and all(p == 0 for p in pol[sum(pol):]), "pol must be INTER-first"
     assert b_vals.shape == bs.shape and a_vals.shape == a.shape
-    B, cap_a = a.shape
-    cap_b = bs.shape[2]
-    assert cap_a % TA == 0 and cap_b % TB == 0, "streams are LANE-padded"
-    if bounds is None:
-        bounds = jnp.full((B,), SENTINEL, jnp.int32)
-    bounds = jnp.asarray(bounds, jnp.int32)
-    if lbounds is None:
-        lbounds = jnp.full((B,), -1, jnp.int32)
-    lbounds = jnp.asarray(lbounds, jnp.int32)
-    if excludes is None:
-        excludes = jnp.full((B, 1), -1, jnp.int32)
-    excludes = jnp.asarray(excludes, jnp.int32)
-    scale = jnp.asarray(scale, jnp.float32)
-    lo_t, nv = jax.vmap(tile_schedule, in_axes=(None, 0, None, None))(
-        a, bs, bounds, lbounds)
-    if max_visits is None:
-        max_visits = cap_b // TB
-    k = len(pol)
-    grid = (B, cap_a // TA, k, int(max_visits))
-    n_excl = excludes.shape[1]
-    kernel = functools.partial(_multi_agg_kernel, k, int(sum(pol)),
-                               int(max_visits), AGG_IDS[op])
-
-    def _b_spec(bi, i, r, j, lo, nv):
-        return (r, bi, jnp.minimum(lo[r, bi, i] + j, cap_b // TB - 1))
-
-    mark, cnt, _vs, _vp, val = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, TA), lambda bi, i, r, j, lo, nv: (bi, i)),
-                pl.BlockSpec((1, 1, TB), _b_spec),
-                pl.BlockSpec((1, 1), lambda bi, i, r, j, lo, nv: (bi, 0)),
-                pl.BlockSpec((1, 1), lambda bi, i, r, j, lo, nv: (bi, 0)),
-                pl.BlockSpec((1, n_excl),
-                             lambda bi, i, r, j, lo, nv: (bi, 0)),
-                pl.BlockSpec((1, TA), lambda bi, i, r, j, lo, nv: (bi, i)),
-                pl.BlockSpec((1, 1, TB), _b_spec),
-                pl.BlockSpec((1, 1), lambda bi, i, r, j, lo, nv: (bi, 0)),
-            ],
-            out_specs=(
-                pl.BlockSpec((1, TA), lambda bi, i, r, j, lo, nv: (bi, i)),
-                pl.BlockSpec((1, 1), lambda bi, i, r, j, lo, nv: (bi, 0)),
-                pl.BlockSpec((1, TA), lambda bi, i, r, j, lo, nv: (bi, i)),
-                pl.BlockSpec((1, TA), lambda bi, i, r, j, lo, nv: (bi, i)),
-                pl.BlockSpec((1, 1), lambda bi, i, r, j, lo, nv: (bi, 0)),
-            ),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct(a.shape, jnp.int32),
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
-            jax.ShapeDtypeStruct(a.shape, jnp.float32),
-            jax.ShapeDtypeStruct(a.shape, jnp.float32),
-            jax.ShapeDtypeStruct((B, 1), jnp.float32),
-        ),
-        interpret=interpret,
-    )(lo_t, nv, a, bs, bounds.reshape(-1, 1), lbounds.reshape(-1, 1),
-      excludes, a_vals, b_vals, scale.reshape(-1, 1))
-    return mark, cnt[:, 0], val[:, 0]
+    return _multi_call(a, bs, pol, bounds, lbounds, excludes, interpret,
+                       AGG_IDS[op], a_vals, b_vals, scale)

@@ -5,6 +5,9 @@ Public entry points used by the engine and the sparse layer. ``backend``:
   'pallas'  Pallas kernels — compiled on TPU, interpret-mode on CPU
   'auto'    pallas on TPU, xla elsewhere (interpret mode is a correctness
             vehicle, not a fast path)
+
+Whether a kernel runs in interpret mode is decided here and only here
+(``_interpret``): the kernel entries take ``interpret`` with no default.
 """
 from __future__ import annotations
 
@@ -31,6 +34,11 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _interpret() -> bool:
+    """Pallas interpret mode: everywhere except on a TPU."""
+    return not _on_tpu()
+
+
 def _resolve(backend: str) -> str:
     if backend == "auto":
         return "pallas" if _on_tpu() else "xla"
@@ -43,7 +51,7 @@ def xinter_count(a, b, bounds=None, backend: str = "auto", lbounds=None):
     backend = _resolve(backend)
     if backend == "xla":
         return batch_inter_count(a, b, bounds, lbounds=lbounds)
-    return intersect_count_pallas(a, b, bounds, interpret=not _on_tpu(),
+    return intersect_count_pallas(a, b, bounds, interpret=_interpret(),
                                   lbounds=lbounds)
 
 
@@ -57,7 +65,7 @@ def xinter(a, b, bounds=None, out_cap: int | None = None, backend: str = "auto",
     backend = _resolve(backend)
     if backend == "xla":
         return batch_inter(a, b, bounds, out_cap=out_cap, lbounds=lbounds)
-    mark = intersect_mark_pallas(a, b, bounds, interpret=not _on_tpu(),
+    mark = intersect_mark_pallas(a, b, bounds, interpret=_interpret(),
                                  lbounds=lbounds)
     cap = out_cap or min(a.shape[1], b.shape[1])
     rows, counts = batch_compact_rows(a, mark > 0, cap)
@@ -99,7 +107,7 @@ def xinter_compact(a, b, bounds=None, out_cap: int | None = None,
     if backend == "xla":
         return batch_inter_compact(a, b, bounds, cap, items, lbounds=lbounds)
     return _xinter_compact_pallas(a, b, bounds, cap, items,
-                                  interpret=not _on_tpu(), lbounds=lbounds)
+                                  interpret=_interpret(), lbounds=lbounds)
 
 
 def xmark(a, b, backend: str = "auto"):
@@ -114,7 +122,7 @@ def xmark(a, b, backend: str = "auto"):
     backend = _resolve(backend)
     if backend == "xla":
         return batch_member_mark(a, b)
-    return intersect_mark_pallas(a, b, None, interpret=not _on_tpu()) > 0
+    return intersect_mark_pallas(a, b, None, interpret=_interpret()) > 0
 
 
 def _sub_window(a, bounds, lbounds):
@@ -136,7 +144,7 @@ def xsub_count(a, b, bounds=None, backend: str = "auto", lbounds=None):
     backend = _resolve(backend)
     if backend == "xla":
         return batch_sub_count(a, b, bounds, lbounds=lbounds)
-    mark = intersect_mark_pallas(a, b, None, interpret=not _on_tpu())
+    mark = intersect_mark_pallas(a, b, None, interpret=_interpret())
     keep = (mark == 0) & _sub_window(a, bounds, lbounds)
     return jnp.sum(keep, axis=1, dtype=jnp.int32)
 
@@ -163,7 +171,7 @@ def xsub_compact(a, b, bounds=None, out_cap: int | None = None,
     if backend == "xla":
         return batch_sub_compact(a, b, bounds, cap, items, lbounds=lbounds)
     return _xsub_compact_pallas(a, b, bounds, cap, items,
-                                interpret=not _on_tpu(), lbounds=lbounds)
+                                interpret=_interpret(), lbounds=lbounds)
 
 
 @functools.partial(jax.jit,
@@ -196,7 +204,7 @@ def xlevel_count(a, bs, pol, bounds=None, backend: str = "auto",
     if backend == "xla" or not pol:
         return batch_level_count(a, bs, pol, bounds, lbounds, excludes)
     _, cnt = intersect_multi_pallas(a, bs, pol, bounds,
-                                    interpret=not _on_tpu(), lbounds=lbounds,
+                                    interpret=_interpret(), lbounds=lbounds,
                                     excludes=excludes)
     return cnt
 
@@ -219,7 +227,7 @@ def xlevel_compact(a, bs, pol, bounds=None, out_cap: int | None = None,
         return batch_level_compact(a, bs, pol, bounds, lbounds, excludes,
                                    cap, items)
     return _xlevel_compact_pallas(a, bs, pol, bounds, lbounds, excludes,
-                                  cap, items, interpret=not _on_tpu())
+                                  cap, items, interpret=_interpret())
 
 
 def xlevel_agg(a, bs, pol, a_vals, b_vals, scale, op: str = "sum",
@@ -247,7 +255,7 @@ def xlevel_agg(a, bs, pol, a_vals, b_vals, scale, op: str = "sum",
                                excludes=excludes)
     _, cnt, val = intersect_multi_agg_pallas(
         a, bs, pol, a_vals, b_vals, scale, op=op, bounds=bounds,
-        interpret=not _on_tpu(), lbounds=lbounds, excludes=excludes)
+        interpret=_interpret(), lbounds=lbounds, excludes=excludes)
     return cnt, val
 
 
@@ -265,7 +273,7 @@ def xvinter(a_keys, a_vals, b_keys, b_vals, op: str = "mac",
     if backend == "xla":
         return batch_vinter(a_keys, a_vals, b_keys, b_vals, op=op)
     return vinter_pallas(a_keys, a_vals, b_keys, b_vals, op=op,
-                         interpret=not _on_tpu())
+                         interpret=_interpret())
 
 
 def xvinter_mac(a_keys, a_vals, b_keys, b_vals, op: str = "mac",
@@ -279,7 +287,7 @@ def xbitmap_count(a_words, b_words, backend: str = "auto"):
     backend = _resolve(backend)
     if backend == "xla":
         return bitmap_and_count_ref(a_words, b_words)
-    return bitmap_and_count_pallas(a_words, b_words, interpret=not _on_tpu())
+    return bitmap_and_count_pallas(a_words, b_words, interpret=_interpret())
 
 
 __all__ = ["xinter", "xinter_count", "xinter_compact", "xmark", "xsub_count",

@@ -7,8 +7,9 @@ import; tests/benches see the 1 real device).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from repro.distributed.sharding import make_mesh_compat, make_mining_mesh
+from repro.distributed.sharding import make_mining_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -25,7 +26,8 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"mesh {shape} needs {need} devices, have {len(devs)} — run via "
             "launch/dryrun.py which sets xla_force_host_platform_device_count")
-    return make_mesh_compat(shape, axes, devices=devs[:need])
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devs[:need])
 
 
 def make_host_mesh(model_parallel: int | None = None):
@@ -34,7 +36,8 @@ def make_host_mesh(model_parallel: int | None = None):
     n = len(jax.devices())
     mp = model_parallel or 1
     assert n % mp == 0
-    return make_mesh_compat((n // mp, mp), ("data", "model"))
+    return jax.make_mesh((n // mp, mp), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def mesh_chips(mesh) -> int:

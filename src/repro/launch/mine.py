@@ -107,6 +107,7 @@ def run_baseline(app: str, g):
 
 def main(argv=None):
     from repro.launch.cli import add_graph_args, add_session_args
+    from repro.launch.compile_cache import enable_compile_cache
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--app", choices=APPS, default="T")
@@ -131,6 +132,7 @@ def main(argv=None):
                     help="wrap the query in jax.profiler start/stop "
                          "(XLA-level trace written to LOGDIR)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     g = get_dataset(args.dataset, scale=args.scale)
     print(f"[mine] {args.dataset} x{args.scale}: {dataset_stats(g)}")

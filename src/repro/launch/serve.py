@@ -27,13 +27,14 @@ import argparse
 import time
 
 
-def serve_mining(args) -> None:
+def serve_mining(args) -> dict | None:
     """Serve the app mix through one ``MiningService``.
 
     Round mode (default): each round submits the mix as concurrent
     requests and ticks once — counts must repeat bit-identically and
-    steady-state rounds must retrace nothing. ``--qps`` mode drives the
-    threaded ``LoadGenerator`` instead."""
+    steady-state rounds must retrace nothing; returns the counts by app
+    label. ``--qps`` mode drives the threaded ``LoadGenerator`` instead
+    (returns None)."""
     from repro.graph import get_dataset
     from repro.graph.datasets import dataset_stats
     from repro.mining import FOUR_MOTIF_SHAPES, MinerConfig
@@ -70,6 +71,7 @@ def serve_mining(args) -> None:
     queries_per_round = len(requests)
 
     if args.qps:
+        first = None
         lg = LoadGenerator(
             svc, list(zip(requests, classes)), requests=args.requests,
             clients=args.clients, qps=args.qps,
@@ -131,11 +133,13 @@ def serve_mining(args) -> None:
     if args.session_stats:
         print("[serve] metrics:")
         print(svc.prometheus_text(), end="")
+    return first
 
 
 def main(argv=None):
     from repro.launch.cli import add_graph_args, add_service_args, \
         add_session_args
+    from repro.launch.compile_cache import enable_compile_cache
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
@@ -150,10 +154,10 @@ def main(argv=None):
     add_session_args(ap)
     add_service_args(ap)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.mine:
-        serve_mining(args)
-        return
+        return serve_mining(args)
 
     import jax
     import jax.numpy as jnp

@@ -5,7 +5,7 @@ parallel over the level-1 edge feed: every edge's pattern-tree descent is
 independent, and every per-level executable is already written as a pure
 body over (prefix columns, carry, live count). ``ShardedWaveRunner``
 exploits exactly that: it reuses the *unmodified* level bodies and wraps
-each one's ``_jit_*`` dispatch hook in ``jax.experimental.shard_map`` over
+each one's ``_jit_*`` dispatch hook in ``jax.shard_map`` over
 a mesh axis (default ``"mine"``), so each device runs the identical wave
 program on its local feed block:
 
@@ -55,7 +55,6 @@ from typing import Callable
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.stream import round_capacity
@@ -148,10 +147,7 @@ class ShardedWaveRunner(WaveRunner):
         if feed_partition not in FEED_PARTITIONS:
             raise ValueError(f"feed_partition must be one of "
                              f"{FEED_PARTITIONS}, got {feed_partition!r}")
-        # pallas kernel calls inside shard_map are unvalidated here; 'auto'
-        # resolves to the xla lowering, explicit 'pallas' is honoured
-        super().__init__(g, chunk=chunk,
-                         backend="xla" if backend == "auto" else backend,
+        super().__init__(g, chunk=chunk, backend=backend,
                          device_compact=True, record=False,
                          fused_level=fused_level, exec_cache=exec_cache,
                          telemetry=telemetry)
@@ -179,9 +175,9 @@ class ShardedWaveRunner(WaveRunner):
 
     # ----------------------------------------------------------- dispatch
     def _shmap(self, body: Callable, in_specs, out_specs) -> Callable:
-        return jax.jit(shard_map(body, mesh=self.mesh,
-                                 in_specs=in_specs, out_specs=out_specs,
-                                 check_rep=False))
+        return jax.jit(jax.shard_map(body, mesh=self.mesh,
+                                     in_specs=in_specs, out_specs=out_specs,
+                                     check_vma=False))
 
     def _level_in_specs(self, op):
         """(g, vals, carry, n) specs shared by count/expand/emit hooks:

@@ -26,9 +26,7 @@ import numpy as np
 
 
 def _flatten_with_paths(tree):
-    # jax.tree.flatten_with_path only exists from jax 0.5; the tree_util
-    # spelling works on every version this repo supports.
-    flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     keys = ["/".join(str(getattr(k, "key", getattr(k, "idx", k)))
                      for k in path) for path, _ in flat]
     return keys, [v for _, v in flat], treedef

@@ -16,14 +16,15 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json, sys
 import jax, jax.numpy as jnp
 from repro.configs import get_arch
-from repro.distributed.sharding import (DEFAULT_RULES, make_mesh_compat,
+from repro.distributed.sharding import (DEFAULT_RULES,
                                         mesh_context, shard_params_tree)
 from repro.models.transformer import Model, shapes_and_axes
 from repro.train.train_step import make_train_step, batch_shardings
 from repro.train.optimizer import OptConfig, adamw_init, opt_state_shardings
 from repro.roofline.analysis import collective_bytes
 
-mesh = make_mesh_compat((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 spec = get_arch(sys.argv[1])
 model = Model(spec.smoke_config)
 shapes, axes = shapes_and_axes(model)

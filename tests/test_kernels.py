@@ -338,7 +338,7 @@ def test_compact_rows_pallas_matches_scan():
     keep = jnp.asarray(RNG.random((8, 256)) < 0.4) & (rows != SENTINEL)
     for out_cap in (256, 128):
         capped = keep & (jnp.cumsum(keep, axis=1) <= out_cap)
-        r_p, c_p = compact_rows_pallas(rows, capped, out_cap)
+        r_p, c_p = compact_rows_pallas(rows, capped, out_cap, interpret=True)
         r_x, c_x = batch_compact_rows(rows, capped, out_cap)
         np.testing.assert_array_equal(np.asarray(r_p), np.asarray(r_x))
         np.testing.assert_array_equal(np.asarray(c_p), np.asarray(c_x))
@@ -373,3 +373,115 @@ def test_tile_schedule_visits_are_sound():
             ti = np.searchsorted(an[i], k) // TA        # a-tile of k
             tb = np.searchsorted(bn[i], k) // TB        # b-tile of k
             assert lo[i, ti] <= tb < lo[i, ti] + nv[i, ti], (i, k)
+
+
+# ---------------------------------------------------------------------------
+# SMEM row split: a batch cut into several pallas_calls gives the same answer
+# as one call and as the XLA twin. The SMEM table budget is shrunk so that a
+# call takes ``rows`` rows; 37 rows is not a multiple of the kernels' 8-row
+# block, so the padding rows are exercised too.
+# ---------------------------------------------------------------------------
+
+SPLIT_B = 37
+
+
+@pytest.fixture
+def smem_rows(monkeypatch):
+    """``set_rows(rows, n_schedules, n_a_tiles)`` shrinks the SMEM table
+    budget so one pallas_call takes ``rows`` rows, and drops the traced
+    entries so they retrace under it."""
+    import jax
+    from repro.kernels import intersect as K
+
+    def set_rows(rows, n_schedules, n_a_tiles):
+        monkeypatch.setattr(K, "SMEM_TABLE_BYTES",
+                            rows // K.R * 2 * 4 * n_schedules * n_a_tiles)
+        jax.clear_caches()
+        assert K.rows_per_call(SPLIT_B, n_schedules, n_a_tiles) == rows
+
+    yield set_rows
+    jax.clear_caches()
+
+
+def _pallas_calls(fn, *args):
+    import jax
+    return str(jax.make_jaxpr(fn)(*args)).count("pallas_call")
+
+
+def _split_operands(k=0, cap_a=256, cap_b=384, hi=1500):
+    a = jnp.asarray(make_rows(SPLIT_B, cap_a, hi=hi))
+    bs = jnp.stack([jnp.asarray(make_rows(SPLIT_B, cap_b, hi=hi))
+                    for _ in range(max(k, 1))])
+    ub = jnp.asarray(RNG.choice([SENTINEL, 300, 900, 0], size=SPLIT_B)
+                     .astype(np.int32))
+    lb = jnp.asarray(RNG.choice([-1, 100, 600], size=SPLIT_B)
+                     .astype(np.int32))
+    return a, bs, ub, lb
+
+
+@pytest.mark.parametrize("rows", [8, 16])
+def test_pair_kernels_split_rows(rows, smem_rows):
+    from repro.kernels import intersect as K
+    a, bs, ub, lb = _split_operands()
+    b = bs[0]
+    mark1, cnt1 = K.intersect_expand_pallas(a, b, ub, interpret=True,
+                                            lbounds=lb)
+    smem_rows(rows, 1, a.shape[1] // K.TA)
+    assert _pallas_calls(
+        lambda a, b: K.intersect_count_pallas(a, b, interpret=True),
+        a, b) == -(-40 // rows)
+    mark, cnt = K.intersect_expand_pallas(a, b, ub, interpret=True,
+                                          lbounds=lb)
+    want = np.asarray(ops.xinter_count(a, b, ub, backend="xla", lbounds=lb))
+    for got in (cnt1, cnt, K.intersect_count_pallas(
+            a, b, ub, interpret=True, lbounds=lb)):
+        np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(np.asarray(mark), np.asarray(mark1))
+    np.testing.assert_array_equal(
+        np.asarray(K.intersect_mark_pallas(a, b, ub, interpret=True,
+                                           lbounds=lb)),
+        np.asarray(mark1))
+
+
+@pytest.mark.parametrize("rows", [8, 24])
+def test_multi_kernels_split_rows(rows, smem_rows):
+    from repro.kernels import intersect as K
+    pol = (1, 0)
+    a, bs, ub, lb = _split_operands(k=2, cap_b=128, hi=1200)
+    ex = jnp.asarray(RNG.integers(0, 1200, (SPLIT_B, 2)).astype(np.int32))
+    mark1, _ = K.intersect_multi_pallas(a, bs, pol, ub, interpret=True,
+                                        lbounds=lb, excludes=ex)
+    smem_rows(rows, len(pol), a.shape[1] // K.TA)
+    assert _pallas_calls(
+        lambda a, bs: K.intersect_multi_pallas(a, bs, pol, interpret=True),
+        a, bs) == -(-40 // rows)
+    mark, cnt = K.intersect_multi_pallas(a, bs, pol, ub, interpret=True,
+                                         lbounds=lb, excludes=ex)
+    np.testing.assert_array_equal(np.asarray(mark), np.asarray(mark1))
+    np.testing.assert_array_equal(
+        np.asarray(cnt),
+        np.asarray(ops.xlevel_count(a, bs, pol, ub, backend="xla",
+                                    lbounds=lb, excludes=ex)))
+    dyadic = [0.25, 0.5, 0.75, 1.0]
+    av = jnp.asarray(RNG.choice(dyadic, size=a.shape).astype(np.float32))
+    bv = jnp.asarray(RNG.choice(dyadic, size=bs.shape).astype(np.float32))
+    scale = jnp.asarray(RNG.choice(dyadic, size=SPLIT_B).astype(np.float32))
+    for op in ("sum", "max"):
+        _, c, v = K.intersect_multi_agg_pallas(
+            a, bs, pol, av, bv, scale, op, ub, interpret=True, lbounds=lb,
+            excludes=ex)
+        cx, vx = ops.xlevel_agg(a, bs, pol, av, bv, scale, op=op, bounds=ub,
+                                backend="xla", lbounds=lb, excludes=ex)
+        np.testing.assert_array_equal(np.asarray(c), np.asarray(cx))
+        np.testing.assert_array_equal(np.asarray(v), np.asarray(vx))
+
+
+def test_vinter_split_rows(smem_rows):
+    from repro.kernels.svinter import vinter_pallas
+    a, bs, _, _ = _split_operands(cap_b=256)
+    va = jnp.asarray(RNG.integers(1, 5, size=a.shape).astype(np.float32))
+    vb = jnp.asarray(RNG.integers(1, 5, size=bs[0].shape).astype(np.float32))
+    smem_rows(8, 1, a.shape[1] // 128)
+    got = vinter_pallas(a, va, bs[0], vb, "mac", interpret=True)
+    want = ops.xvinter(a, va, bs[0], vb, op="mac", backend="xla")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

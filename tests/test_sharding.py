@@ -1,15 +1,15 @@
 """Sharding rules: divisibility-aware resolution, ZeRO axes, batch specs."""
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.distributed.sharding import (Axes, DEFAULT_RULES, FSDP_RULES,
-                                        abstract_mesh, logical_to_physical,
+                                        logical_to_physical,
                                         constrain)
 from repro.train.optimizer import zero_axes
 
 
 def mk_mesh(shape, names):
     # abstract mesh: resolution logic only needs axis sizes, no devices
-    return abstract_mesh(shape, names)
+    return AbstractMesh(tuple(shape), tuple(names))
 
 
 def test_divisibility_drop():

@@ -125,17 +125,15 @@ def test_data_pipeline_deterministic():
 def test_compressed_mean_single_device():
     """Wire-format exactness: int8 psum on a 1-device mesh == quantised id."""
     from repro.distributed.compression import compressed_mean
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
-    from repro.distributed.sharding import make_mesh_compat
-    mesh = make_mesh_compat((1,), ("pod",))
+    from jax.sharding import AxisType, PartitionSpec as P
+    mesh = jax.make_mesh((1,), ("pod",), axis_types=(AxisType.Auto,))
     x = jnp.asarray(np.random.default_rng(0).normal(size=(64,))
                     .astype(np.float32))
 
     def body(x):
         return compressed_mean(x, "pod")[0]
 
-    got = shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                    check_rep=False)(x)
+    got = jax.shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
+                        check_vma=False)(x)
     err = np.abs(np.asarray(got) - np.asarray(x))
     assert err.max() <= np.abs(np.asarray(x)).max() / 127 + 1e-6
