@@ -10,9 +10,9 @@ Timing rides ``repro.obs``: every timed region is a span on the
 module-level ``TELEMETRY`` (``perf_counter`` under the hood), so
 ``telemetry_snapshot()`` hands consumers (benchmarks/ci_gate.py ->
 BENCH_mining.json) the per-report span aggregates instead of bespoke
-stopwatch plumbing. The timed runners themselves stay UNTRACED — outer
-stopwatch spans only — so no per-dispatch ``block_until_ready`` skews the
-gated wall-clock ratios.
+stopwatch plumbing. The timed runners themselves keep no span tree —
+outer stopwatch spans only — so the gated wall-clock ratios time the
+mining, not the tracer.
 """
 from __future__ import annotations
 
@@ -67,32 +67,6 @@ def _time(fn, *a, warm: bool = True, label: str | None = None):
         fn(*a)                                 # JIT warm-up excluded
     return _stopwatch(label or getattr(fn, "__name__", "timed"),
                       lambda: fn(*a))
-
-
-def modeled_tpu_triangle_time(g) -> float:
-    """Compute+DMA floor for triangle counting on one v5e core with the
-    Pallas tile-overlap schedule: visited tile pairs x (128x128 compares /
-    VPU rate) + streamed bytes / HBM bw. The §Roofline methodology applied
-    to the mining kernel (no real-TPU wall clock in this container)."""
-    import jax.numpy as jnp
-    from repro.kernels.intersect import tile_schedule
-    from repro.mining.engine import edge_wave, _neighbor_cap
-    from repro.graph.csr import padded_rows
-    VPU_OPS = 4e12          # int cmp/s per chip (conservative v5e VPU)
-    HBM = 819e9
-    visits = 0
-    bytes_moved = 0
-    for wave, n in edge_wave(g, 8192):
-        capn = _neighbor_cap(g, wave.verts)
-        nbr, _ = padded_rows(g, jnp.asarray(wave.verts), capn)
-        lo, nv = tile_schedule(jnp.asarray(wave.rows), nbr,
-                               jnp.asarray(wave.verts))
-        import numpy as _np
-        visits += int(_np.asarray(nv)[:n].sum())
-        bytes_moved += n * (wave.rows.shape[1] + capn) * 4
-    t_compute = visits * 128 * 128 / VPU_OPS
-    t_mem = bytes_moved / HBM
-    return max(t_compute, t_mem)
 
 
 def _level2_dispatches(level_execs: dict) -> int:
@@ -486,9 +460,6 @@ def run(quick: bool = True):
     for name, scale in sets:
         g = get_dataset(name, scale=scale)
         stats = dataset_stats(g)
-        t_tpu = modeled_tpu_triangle_time(g)
-        print(f"[mining] {name:14s} modeled v5e triangle kernel floor: "
-              f"{t_tpu*1e3:.2f} ms (schedule-derived)", flush=True)
         wt = wave_throughput_report(g)
         print(f"[mining] {name:14s} 4C wave loop: "
               f"host {wt['host']['items_per_s']:.0f} items/s "

@@ -70,8 +70,9 @@ print("retraces on repeat :", mw.stats["retraces"] - before)
 
 # --- observability: trace a query, see where its time went ----------------
 # a Telemetry(enabled=True) session records a span tree per query (query ->
-# compile/schedule/execute -> per-level -> per-dispatch, perf_counter wall
-# time around dispatch + block_until_ready); counters live in the same
+# compile/schedule/execute -> per-level -> per-dispatch host enqueue and
+# per-sync host waits, perf_counter wall time; every span is also an
+# "ix.<name>" jax.profiler annotation); counters live in the same
 # registry the stats dicts above are views of. write_trace() exports
 # Chrome-trace JSON for ui.perfetto.dev (same as `launch/mine.py --trace`).
 from repro.obs import Telemetry

@@ -156,17 +156,20 @@ def padded_rows(g: CSRGraph, vs: jax.Array, cap: int):
 
     Returns (keys, lengths): keys sentinel-padded/truncated to ``cap``.
     This is the data-movement core of S_NESTINTER (§IV-F): the nested
-    translator's per-key stream loads become one vectorised gather.
+    translator's per-key stream loads become one vectorised gather. Its
+    ops carry the name scope ``padded_rows`` (metadata only), so a device
+    trace can find the gathers whatever XLA names their fusions.
     """
-    vs = jnp.asarray(vs, jnp.int32)
-    starts = g.indptr[vs]
-    lens = g.indptr[vs + 1] - starts
-    col = jnp.arange(cap, dtype=jnp.int32)
-    idx = starts[:, None] + col[None, :]
-    idx = jnp.clip(idx, 0, g.indices.shape[0] - 1)
-    rows = g.indices[idx]
-    rows = jnp.where(col[None, :] < lens[:, None], rows, SENTINEL)
-    return rows, jnp.minimum(lens, cap).astype(jnp.int32)
+    with jax.named_scope("padded_rows"):
+        vs = jnp.asarray(vs, jnp.int32)
+        starts = g.indptr[vs]
+        lens = g.indptr[vs + 1] - starts
+        col = jnp.arange(cap, dtype=jnp.int32)
+        idx = starts[:, None] + col[None, :]
+        idx = jnp.clip(idx, 0, g.indices.shape[0] - 1)
+        rows = g.indices[idx]
+        rows = jnp.where(col[None, :] < lens[:, None], rows, SENTINEL)
+        return rows, jnp.minimum(lens, cap).astype(jnp.int32)
 
 
 def padded_value_rows(g: CSRGraph, vs: jax.Array, cap: int) -> jax.Array:

@@ -37,7 +37,6 @@ bound=0 so they contribute nothing (branch-free masking, no special cases).
 from __future__ import annotations
 
 import dataclasses
-from contextlib import nullcontext
 from typing import Callable
 
 import numpy as np
@@ -106,17 +105,23 @@ def _pow2cap(n: int) -> int:
     return c
 
 
-def edge_chunks(g: CSRGraph, chunk: int, symmetric: bool = True):
-    """Host half of the level-1 feed: yields (cap, v0, v1, n) degree-bucketed
-    chunk-padded int32 vertex arrays *without* materialising neighbor rows —
-    row gathers happen on-device so the feed can be double-buffered."""
+def edge_buckets(g: CSRGraph, symmetric: bool = True):
+    """Host bucketing of one level-1 feed pass: [(cap, edges), ...], the
+    (E_b, 2) edges whose prefix vertex v0 falls in degree bucket ``cap``,
+    in ascending ``cap`` order. Runs eagerly, so a caller can time it."""
     edges = half_edges(g) if symmetric else directed_edges(g)
     if edges.shape[0] == 0:
-        return
+        return []
     deg = np.asarray(g.degrees)
     caps = np.array([_pow2cap(max(int(d), 1)) for d in deg[edges[:, 0]]])
-    for cap in np.unique(caps):
-        sel = edges[caps == cap]
+    return [(int(cap), edges[caps == cap]) for cap in np.unique(caps)]
+
+
+def bucket_chunks(buckets, chunk: int):
+    """Slice ``edge_buckets`` into (cap, v0, v1, n) chunk-padded int32
+    vertex arrays *without* materialising neighbor rows — row gathers
+    happen on-device so the feed can be double-buffered."""
+    for cap, sel in buckets:
         # fixed chunk width: one compiled shape per degree bucket
         nb = min(chunk, _pow2cap(sel.shape[0]))
         for lo in range(0, sel.shape[0], nb):
@@ -124,7 +129,13 @@ def edge_chunks(g: CSRGraph, chunk: int, symmetric: bool = True):
             n = sl.shape[0]
             v0 = _pad_to(sl[:, 0].astype(np.int32), nb, 0)
             v1 = _pad_to(sl[:, 1].astype(np.int32), nb, 0)
-            yield int(cap), v0, v1, n
+            yield cap, v0, v1, n
+
+
+def edge_chunks(g: CSRGraph, chunk: int, symmetric: bool = True):
+    """Host half of the level-1 feed: ``edge_buckets`` then
+    ``bucket_chunks``; yields (cap, v0, v1, n)."""
+    return bucket_chunks(edge_buckets(g, symmetric), chunk)
 
 
 def edge_wave(g: CSRGraph, chunk: int, symmetric: bool = True):
@@ -374,16 +385,19 @@ class WaveRunner:
         # telemetry substrate (repro.obs): the metrics registry is the
         # single source of truth for every counter, and ``self.stats`` is
         # the legacy dict DERIVED from it (bit-identical view, golden-
-        # tested). The tracer is off unless the session enables it —
-        # dispatch sites then open timed spans and block to completion.
+        # tested). Spans are profiler annotations always and a span tree
+        # only when the session enables the tracer; neither synchronises.
         self.telemetry = telemetry if telemetry is not None else Telemetry()
         self.metrics = self.telemetry.metrics
         self.stats = LegacyStatsView()
         self._ct = {k: self.stats.expose_counter(k, self.metrics)
                     for k in self._STAT_KEYS}
         # registry-only extras (not part of the legacy view)
-        self._h_wave_items = self.metrics.histogram("wave_items")
         self._ct_feed_chunks = self.metrics.counter("feed_chunks")
+        # level-1 gather fill: slots the feed's row gathers move (chunk
+        # width x row capacities) and the neighbor keys among them
+        self._ct_row_slots = self.metrics.counter("feed_row_slots")
+        self._ct_row_keys = self.metrics.counter("feed_row_keys")
         # SVPU value plane: aggregate-leaf executions (each rides an
         # existing membership dispatch — value_lane_dispatches counts leaves
         # whose dispatch carried a value lane, NOT extra kernel launches)
@@ -438,39 +452,35 @@ class WaveRunner:
     # -------------------------------------------------------- traced dispatch
     def _dispatch(self, op: LevelOp, fn: Callable, args: tuple,
                   items=None, caps_sig: tuple = (), host: bool = False):
-        """Run one level executable. With tracing enabled, the call is
-        wrapped in a ``dispatch`` span (op kind/level, wavefront items,
-        capacity signature, exec-cache hit/miss) and followed by
-        ``block_until_ready`` so the span measures device wall time, not
-        async dispatch time. Disabled: the bare call — no span, no sync."""
-        tr = self.telemetry.tracer
-        if not tr.enabled:
-            return fn(*args)
+        """Run one level executable inside a ``dispatch`` span (op
+        kind/level, wavefront items, capacity signature, exec-cache
+        hit/miss). The span times the host's enqueue — trace and compile
+        included when the executable is fresh — and never waits for the
+        device: device time is the profiler's to report."""
         attrs = {"kind": op.kind, "level": op.level,
                  "dispatches": self._level_dispatches(op, host),
                  "exec_cached": not self._exec_fresh}
         if op.agg is not None:
             attrs["agg"] = op.agg
         if items is not None:
-            attrs["items"] = int(np.asarray(items).sum())
+            attrs["items"] = lambda: int(np.asarray(items).sum())
         if caps_sig:
-            attrs["caps"] = str(tuple(caps_sig))
+            attrs["caps"] = lambda: str(tuple(caps_sig))
         if host:
             attrs["host"] = True
-        with tr.span("dispatch", cat="dispatch", **attrs):
-            out = fn(*args)
-            jax.block_until_ready(out)
-        return out
+        with self.telemetry.tracer.span("dispatch", cat="dispatch", **attrs):
+            return fn(*args)
 
     def _level_span(self, op: LevelOp, n):
-        """Level-span context for one op's processing on one wave chunk
-        (children levels nest inside); no-op when tracing is off."""
-        tr = self.telemetry.tracer
-        if not tr.enabled:
-            return nullcontext()
-        return tr.span(f"L{op.level}:{op.kind}", cat="level",
-                       level=op.level, kind=op.kind,
-                       items=int(np.asarray(n).sum()))
+        """Level span for one op's processing on one wave chunk (children
+        levels nest inside)."""
+        return self.telemetry.tracer.span(
+            f"L{op.level}:{op.kind}", cat="level", level=op.level,
+            kind=op.kind, items=lambda: int(np.asarray(n).sum()))
+
+    def _sync_span(self, site: str):
+        """Span around one blocking device->host read at ``site``."""
+        return self.telemetry.tracer.span("sync", cat="sync", site=site)
 
     def _rows_fn(self, cap: int):
         def build():
@@ -497,10 +507,32 @@ class WaveRunner:
             yield pending
 
     def _edge_feed(self, symmetric: bool = True):
-        """Double-buffered level-1 feed: (cap, dv0, dv1, v1_host, n)."""
-        chunks = ((cap, v0, v1, v1, n) for cap, v0, v1, n
-                  in edge_chunks(self.g, self.chunk, symmetric))
-        return self._double_buffered(chunks, frozenset({1, 2}))
+        """Double-buffered level-1 feed: (cap, dv0, dv1, v1_host, n). The
+        bucketing runs here, eagerly, inside a ``feed_bucket`` span; the
+        returned generator only slices, counts the N(v0) rows' fill and
+        uploads."""
+        with self.telemetry.tracer.span("feed_bucket", cat="host",
+                                        symmetric=symmetric):
+            buckets = edge_buckets(self.g, symmetric)
+
+        def chunks():
+            for cap, v0, v1, n in bucket_chunks(buckets, self.chunk):
+                self._count_feed_fill(cap, v0, n)
+                yield cap, v0, v1, v1, n
+        return self._double_buffered(chunks(), frozenset({1, 2}))
+
+    def _count_feed_fill(self, cap: int, verts, n) -> None:
+        """Credit the level-1 gather of one feed chunk's rows of ``verts``
+        at capacity ``cap`` — N(v0) for every chunk, N(v1) where the level
+        gathers it — to ``feed_row_slots`` (chunk width x cap) and
+        ``feed_row_keys`` (the neighbor keys of the live edges among those
+        slots). ``n`` is the live count, or the per-shard vector when the
+        chunk holds one block per shard."""
+        deg = np.asarray(self.g.degrees)
+        live = (np.arange(verts.shape[0] // self._shards)
+                < np.reshape(n, (-1, 1))).reshape(-1)
+        self._ct_row_slots.inc(verts.shape[0] * cap)
+        self._ct_row_keys.inc(int(np.minimum(deg[verts[live]], cap).sum()))
 
     # ------------------------------------------------------------- plan parts
     @staticmethod
@@ -1013,23 +1045,22 @@ class WaveRunner:
         op0 = plan.ops[0]
         outs: list = []
         tr = self.telemetry.tracer
-        with (tr.span("execute", plan=plan.pattern.name)
-              if tr.enabled else nullcontext()):
+        with tr.span("execute", plan=plan.pattern.name):
             for cap0, dv0, dv1, v1h, n in self._edge_feed(plan.symmetric):
                 self._ct_feed_chunks.inc()
-                with (tr.span("feed", cat="level", cap=cap0,
-                              items=int(np.asarray(n).sum()))
-                      if tr.enabled else nullcontext()):
+                with tr.span("feed", cat="level", cap=cap0,
+                             items=lambda: int(np.asarray(n).sum())):
                     caps = {0: cap0}
                     if 1 in op0.row_refs():
                         caps[1] = _neighbor_cap(self.g, v1h)
+                        self._count_feed_fill(caps[1], v1h, n)
                     if self.record:
                         self._record(1, self._rows_fn(cap0)(self.g, dv0),
                                      dv1, n)
                     outs += self._plan_descend(plan, 0, {0: dv0, 1: dv1},
                                                caps, None, n)
             self._ct["host_syncs"].inc(len(outs))
-            with tr.span("finalize") if tr.enabled else nullcontext():
+            with tr.span("finalize"):
                 return self._finalize(plan, outs)
 
     def run_set(self, forest):
@@ -1046,8 +1077,7 @@ class WaveRunner:
         """
         acc: list[list] = [[] for _ in forest.plans]
         tr = self.telemetry.tracer
-        with (tr.span("execute", plans=len(forest.plans), forest=True)
-              if tr.enabled else nullcontext()):
+        with tr.span("execute", plans=len(forest.plans), forest=True):
             for symmetric, roots in ((True, forest.symmetric_roots),
                                      (False, forest.directed_roots)):
                 if not roots:
@@ -1055,12 +1085,12 @@ class WaveRunner:
                 need1 = any(1 in r.op.row_refs() for r in roots)
                 for cap0, dv0, dv1, v1h, n in self._edge_feed(symmetric):
                     self._ct_feed_chunks.inc()
-                    with (tr.span("feed", cat="level", cap=cap0,
-                                  items=int(np.asarray(n).sum()))
-                          if tr.enabled else nullcontext()):
+                    with tr.span("feed", cat="level", cap=cap0,
+                                 items=lambda: int(np.asarray(n).sum())):
                         caps = {0: cap0}
                         if need1:
                             caps[1] = _neighbor_cap(self.g, v1h)
+                            self._count_feed_fill(caps[1], v1h, n)
                         if self.record:
                             self._record(1, self._rows_fn(cap0)(self.g, dv0),
                                          dv1, n)
@@ -1068,7 +1098,7 @@ class WaveRunner:
                             self._forest_descend(root, {0: dv0, 1: dv1},
                                                  caps, None, n, acc)
             self._ct["host_syncs"].inc(sum(len(a) for a in acc))
-            with tr.span("finalize") if tr.enabled else nullcontext():
+            with tr.span("finalize"):
                 return [self._finalize(plan, parts)
                         for plan, parts in zip(forest.plans, acc)]
 
@@ -1164,7 +1194,8 @@ class WaveRunner:
                     int(src.shape[0]) // self._shards)
                 rvals = tuple(cols[c] for c in refs)
                 src_b, verts_b, tot_b = pfn(rvals, src, verts2, total)
-                tot_b, has_b = self._pack_total(tot_b)
+                with self._sync_span("pack"):
+                    tot_b, has_b = self._pack_total(tot_b)
                 self._ct["host_syncs"].inc()
                 if has_b:
                     feeds.append(([ch], src_b, verts_b, tot_b))
@@ -1230,18 +1261,18 @@ class WaveRunner:
                                     out_items)
             emb, total = self._dispatch(op, fn, (self.g, vals, carry_in, n),
                                         items=n, caps_sig=caps_sig)
-            total = int(total)
+            with self._sync_span("emit"):
+                total = int(total)
+                emb = np.asarray(emb)[:total] if total else None
             self._ct["device_compactions"].inc()
             self._ct["items"].inc(total)
-            self._h_wave_items.observe(total)
-            if total == 0:
-                return []
-            return [np.asarray(emb)[:total]]
+            return [emb] if total else []
         hfn = self._plan_expand_host_fn(op, caps_sig, cap_base, out_cap)
         rows2, counts2 = self._dispatch(op, hfn, (self.g, vals, carry_in, n),
                                         items=n, caps_sig=caps_sig, host=True)
-        wave, ii = compact(np.asarray(rows2), np.asarray(counts2),
-                           return_src=True)
+        with self._sync_span("host_compact"):
+            wave, ii = compact(np.asarray(rows2), np.asarray(counts2),
+                               return_src=True)
         self._ct["host_compactions"].inc()
         if wave is None:
             return []
@@ -1261,7 +1292,8 @@ class WaveRunner:
                                   want_count)
         rows2, src, verts2, meta = self._dispatch(
             op, fn, (self.g, vals, carry_in, n), items=n, caps_sig=caps_sig)
-        meta = [int(x) for x in np.asarray(meta)]
+        with self._sync_span("meta"):
+            meta = [int(x) for x in np.asarray(meta)]
         if want_count:
             meta, ride = meta[:-2], np.asarray(meta[-2:], dtype=np.int32)
         else:
@@ -1270,7 +1302,6 @@ class WaveRunner:
         self._ct["host_syncs"].inc()
         self._ct["device_compactions"].inc()
         self._ct["items"].inc(total)
-        self._h_wave_items.observe(total)
         if total == 0:
             return None
         caps2 = {c: _pow2cap(max(d, 1))
@@ -1356,21 +1387,23 @@ class WaveRunner:
         hfn = self._plan_expand_host_fn(op, caps_sig, cap_base, out_cap)
         rows2, counts2 = self._dispatch(op, hfn, (self.g, vals, carry_in, n),
                                         items=n, caps_sig=caps_sig, host=True)
-        if ride_out is not None:
-            t = int(np.asarray(counts2, dtype=np.int64).sum())
-            ride_out["count_part"] = np.asarray([t >> 16, t & 0xFFFF],
-                                                dtype=np.int32)
-        wave, ii = compact(np.asarray(rows2), np.asarray(counts2),
-                           return_src=True)
+        # a generator: the span closes before the first yield
+        with self._sync_span("host_compact"):
+            if ride_out is not None:
+                t = int(np.asarray(counts2, dtype=np.int64).sum())
+                ride_out["count_part"] = np.asarray([t >> 16, t & 0xFFFF],
+                                                    dtype=np.int32)
+            wave, ii = compact(np.asarray(rows2), np.asarray(counts2),
+                               return_src=True)
+            if wave is not None:
+                fwd = [c for c in op.out_cols if c < op.level]
+                hostcols = {c: np.asarray(cols[c])[ii] for c in fwd}
         self._ct["host_syncs"].inc()
         self._ct["host_compactions"].inc()
         if wave is None:
             return
         total = len(wave)
         self._ct["items"].inc(total)
-        self._h_wave_items.observe(total)
-        fwd = [c for c in op.out_cols if c < op.level]
-        hostcols = {c: np.asarray(cols[c])[ii] for c in fwd}
         caps2 = {c: _neighbor_cap(self.g, wave.verts if c == op.level
                                   else hostcols[c])
                  for c in op.gather_refs}

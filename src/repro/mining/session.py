@@ -109,16 +109,21 @@ Every session carries a ``repro.obs.Telemetry``: ``miner.telemetry``.
   key order and values). ``telemetry.prometheus_text()`` renders the
   whole registry; labeled series (per-shard feed items) export one sample
   per label set.
-* **tracing** — pass ``telemetry=Telemetry(enabled=True)`` (or call
-  ``miner.telemetry.enable()``) and every query records a span tree:
-  ``query`` → ``compile``/``schedule``/``execute`` → per-``feed`` and
-  per-level ``L{l}:{kind}`` spans → ``dispatch`` spans timed around the
-  kernel call + ``block_until_ready`` (op kind, items, capacities,
-  exec-cache hit/miss). Export with ``telemetry.write_trace(path)``
-  (Chrome-trace JSON — chrome://tracing / ui.perfetto.dev) or aggregate
-  with ``telemetry.snapshot()`` / ``tracer.level_seconds()``. Disabled
-  (the default), the engine takes the untraced branch: no spans, no
-  extra synchronization, no extra kernel dispatches.
+* **tracing** — every span is a ``jax.profiler.TraceAnnotation``
+  named ``ix.<name>``, always: under the profiler the spans share the
+  device trace's clock. Pass ``telemetry=Telemetry(enabled=True)`` (or
+  call ``miner.telemetry.enable()``) and every query also records a span
+  tree: ``query`` (attribute ``seq``, this session's query number) →
+  ``compile``/``schedule``/``execute`` → ``feed_bucket`` (host bucketing
+  of a feed pass), per-``feed`` and per-level ``L{l}:{kind}`` spans →
+  ``dispatch`` spans (op kind, items, capacities, exec-cache hit/miss)
+  and ``sync`` spans (attribute ``site``: ``meta``, ``pack``, ``emit``,
+  ``host_compact``) around each blocking device→host read. A
+  ``dispatch`` span times the host's enqueue, not the device: tracing
+  never synchronises, so on or off the same work runs. Export with
+  ``telemetry.write_trace(path)`` (Chrome-trace JSON — chrome://tracing /
+  ui.perfetto.dev) or aggregate with ``telemetry.snapshot()`` /
+  ``tracer.level_seconds()``.
 * **jax profiler** — ``with miner.telemetry.jax_profile(logdir): ...``
   wraps a query in ``jax.profiler`` start/stop for an XLA-level trace.
 
@@ -158,7 +163,7 @@ The contract, stage by stage:
 from __future__ import annotations
 
 import dataclasses
-from contextlib import nullcontext
+import itertools
 from typing import Callable, Sequence
 
 import jax
@@ -316,6 +321,7 @@ class Miner:
                 fused_level=config.fused_level, exec_cache=self.exec_cache,
                 telemetry=self.telemetry)
         self._plans: dict[tuple, WavePlan] = {}
+        self._seq = itertools.count()
         self._forests: dict[tuple, PlanForest] = {}
         self.metrics = self.telemetry.metrics
         self._stats = LegacyStatsView()
@@ -333,8 +339,7 @@ class Miner:
         compiles the weighted (SVPU value) program — see the module
         docstring's "Value streams" section."""
         tr = self.telemetry.tracer
-        with (tr.span("compile", query=str(query), emit=emit)
-              if tr.enabled else nullcontext()):
+        with tr.span("compile", query=str(query), emit=emit):
             resolved = resolve_query(query)
             key = (resolved, emit, aggregate)
             plan = self._plans.get(key)
@@ -360,8 +365,7 @@ class Miner:
         repeated and permuted-config queries skip both the search and the
         merge."""
         tr = self.telemetry.tracer
-        with (tr.span("schedule", queries=len(queries), emit=emit)
-              if tr.enabled else nullcontext()):
+        with tr.span("schedule", queries=len(queries), emit=emit):
             resolved = tuple(resolve_query(q) for q in queries)
             key = (resolved, emit, aggregate)
             forest = self._forests.get(key)
@@ -385,11 +389,10 @@ class Miner:
 
     # ------------------------------------------------------------ execute
     def _query_span(self, kind: str, **attrs):
-        """Root span of one traced query (no-op when tracing is off)."""
-        tr = self.telemetry.tracer
-        if not tr.enabled:
-            return nullcontext()
-        return tr.span("query", kind=kind, **attrs)
+        """Root span of one query; ``seq`` numbers this session's queries,
+        so every span of one query shares an identifier."""
+        return self.telemetry.tracer.span("query", kind=kind,
+                                          seq=next(self._seq), **attrs)
 
     def count(self, query) -> int:
         """Count embeddings of one pattern query."""

@@ -59,7 +59,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.stream import round_capacity
 from repro.graph.csr import CSRGraph
-from .engine import WaveRunner, _pow2cap, directed_edges, half_edges
+from .engine import WaveRunner, _pow2cap, edge_buckets
 
 __all__ = ["ShardedWaveRunner", "shard_edge_steps"]
 
@@ -94,13 +94,14 @@ def shard_edge_steps(g: CSRGraph, chunk: int, shards: int,
     if mode not in FEED_PARTITIONS:
         raise ValueError(f"feed_partition must be one of {FEED_PARTITIONS}, "
                          f"got {mode!r}")
-    edges = half_edges(g) if symmetric else directed_edges(g)
-    if edges.shape[0] == 0:
-        return
-    deg = np.asarray(g.degrees)
-    caps = np.array([_pow2cap(max(int(d), 1)) for d in deg[edges[:, 0]]])
-    for cap in np.unique(caps):
-        sel = edges[caps == cap]
+    return shard_bucket_steps(edge_buckets(g, symmetric), chunk, shards,
+                              mode)
+
+
+def shard_bucket_steps(buckets, chunk: int, shards: int,
+                       mode: str = "round_robin"):
+    """The dealing half of ``shard_edge_steps``, over ``edge_buckets``."""
+    for cap, sel in buckets:
         e = sel.shape[0]
         nb = min(chunk, _pow2cap(max(-(-e // shards), 1)))
         span = shards * nb
@@ -116,7 +117,7 @@ def shard_edge_steps(g: CSRGraph, chunk: int, shards: int,
                 n[s] = k
                 v0[s, :k] = part[:, 0]
                 v1[s, :k] = part[:, 1]
-            yield int(cap), v0.reshape(-1), v1.reshape(-1), n
+            yield cap, v0.reshape(-1), v1.reshape(-1), n
 
 
 class ShardedWaveRunner(WaveRunner):
@@ -254,16 +255,20 @@ class ShardedWaveRunner(WaveRunner):
         """Sharded level-1 feed: per-shard edge blocks are laid out back to
         back and ``device_put`` with the mining-axis sharding (still
         double-buffered — step N+1's shard transfers dispatch while the
-        mesh computes step N). ``n`` is the per-shard live-count vector."""
+        mesh computes step N). ``n`` is the per-shard live-count vector.
+        The bucketing runs here, eagerly, inside a ``feed_bucket`` span."""
         sh = self._feed_sharding
         feed = self._shard_feed
+        with self.telemetry.tracer.span("feed_bucket", cat="host",
+                                        symmetric=symmetric):
+            buckets = edge_buckets(self.g, symmetric)
 
         def gen():
-            for cap, v0, v1, n in shard_edge_steps(
-                    self.g, self.chunk, self._shards, symmetric,
-                    self.feed_partition):
+            for cap, v0, v1, n in shard_bucket_steps(
+                    buckets, self.chunk, self._shards, self.feed_partition):
                 for s in range(self._shards):
                     feed[s].inc(int(n[s]))
+                self._count_feed_fill(cap, v0, n)
                 yield (cap, jax.device_put(v0, sh), jax.device_put(v1, sh),
                        v1, n)
         return self._double_buffered(gen(), frozenset())
@@ -285,7 +290,8 @@ class ShardedWaveRunner(WaveRunner):
                                   want_count)
         rows2, src, verts2, meta = self._dispatch(
             op, fn, (self.g, vals, carry_in, n), items=n, caps_sig=caps_sig)
-        meta = np.asarray(meta).astype(np.int64)        # (shards, m)
+        with self._sync_span("meta"):
+            meta = np.asarray(meta).astype(np.int64)    # (shards, m)
         if want_count:
             meta, rpart = meta[:, :-2], meta[:, -2:].sum(axis=0)
             ride = np.asarray(rpart)                     # (hi_sum, lo_sum)
@@ -297,7 +303,6 @@ class ShardedWaveRunner(WaveRunner):
         self._ct["host_syncs"].inc()
         self._ct["device_compactions"].inc()
         self._ct["items"].inc(int(totals.sum()))
-        self._h_wave_items.observe(int(totals.sum()))
         if int(totals.max()) == 0:
             return None
         caps2 = {c: _pow2cap(max(int(d), 1))
@@ -335,13 +340,13 @@ class ShardedWaveRunner(WaveRunner):
         fn = self._plan_emit_fn(op, caps_sig, cap_base, out_cap, out_items)
         emb, totals = self._dispatch(op, fn, (self.g, vals, carry_in, n),
                                      items=n, caps_sig=caps_sig)
-        totals = np.asarray(totals, dtype=np.int64).reshape(-1)
+        with self._sync_span("emit"):
+            totals = np.asarray(totals, dtype=np.int64).reshape(-1)
+            emb = np.asarray(emb) if int(totals.max()) else None
         self._ct["device_compactions"].inc()
         self._ct["items"].inc(int(totals.sum()))
-        self._h_wave_items.observe(int(totals.sum()))
-        if int(totals.max()) == 0:
+        if emb is None:
             return []
-        emb = np.asarray(emb)
         blocks = []
         for s, t in enumerate(totals):
             if t:

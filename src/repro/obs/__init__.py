@@ -8,12 +8,15 @@ One ``Telemetry`` object carries everything a session needs to answer
   the engine's legacy ``stats`` dicts (derived views, bit-identical to
   the dicts they replaced), so metrics cost what the old dict mutations
   cost.
-* ``telemetry.tracer`` — a ``Tracer`` producing per-query span trees
-  (query → compile/schedule/execute → per-level spans → per-dispatch
-  spans with op kind, items, capacities, cache hit/miss and
-  ``perf_counter`` wall time around dispatch + ``block_until_ready``).
-  Off by default: a disabled tracer records nothing, adds no
-  synchronization and no kernel dispatches.
+* ``telemetry.tracer`` — a ``Tracer``. Every span is a
+  ``jax.profiler.TraceAnnotation`` named ``ix.<name>``, so under the
+  profiler the spans share the device trace's clock. Enabled, it also
+  keeps per-query span trees in memory (query → compile/schedule/execute
+  → feed bucketing, per-level spans, per-dispatch spans with op kind,
+  items, capacities and cache hit/miss, and ``sync`` spans around each
+  blocking device→host read). A ``dispatch`` span times the host's
+  enqueue, never device time: nothing here synchronises with the device,
+  so tracing on or off runs the same work (tested in tests/test_obs.py).
 * exporters — Chrome-trace/Perfetto JSON (``--trace out.json`` on
   ``launch/mine.py`` / ``launch/serve.py``), a Prometheus text snapshot,
   and ``snapshot()`` (metrics + per-span aggregates) consumed by

@@ -16,7 +16,7 @@ Instruments
   invariant).
 * ``Gauge`` — last-written value (e.g. per-shard feed block width).
 * ``Histogram`` — count/sum/min/max plus fixed exponential buckets; used
-  for span durations and wavefront item sizes.
+  for the service's latencies and batch sizes.
 
 Labels
 ------

@@ -2,29 +2,40 @@
 
 A ``Tracer`` records a tree of ``Span``s per traced query:
 
-    query(triangle)
+    query(triangle, seq)
     ├─ compile
     ├─ schedule            (batch queries)
     └─ execute
+       ├─ feed_bucket      (host bucketing of one level-1 feed pass)
        ├─ feed  L1         (one per edge-feed chunk: cap, items)
        │  └─ level L2 expand
-       │     ├─ dispatch   (kernel dispatch + block_until_ready wall time)
+       │     ├─ dispatch   (host enqueue of one level executable)
+       │     ├─ sync       (a blocking device->host read; attr ``site``)
        │     └─ level L3 count
        │        └─ dispatch
-       └─ ...
+       └─ finalize         (the deferred reads of the count partials)
 
-Spans nest by wall time (children run inside their parent's interval), so
-the tree exports directly to Chrome-trace/Perfetto "X" events
-(``repro.obs.export``). Each span records ``perf_counter`` start/end,
-a category, and free-form attributes — dispatch spans carry the op kind,
-level, wavefront items, capacities and the executable-cache hit/miss bit.
+Every span is also a ``jax.profiler.TraceAnnotation`` named ``ix.<name>``
+(its cheap attributes ride along as annotation metadata), entered whether
+or not the tree is on. With no profiler running it costs about a
+microsecond; under ``jax.profiler`` the span lands on the host plane of
+the same trace as the device ops, on the same clock, so an idle stretch of
+the device can be matched with what the host was doing.
 
-Timing discipline: the engine only opens dispatch spans when the tracer
-is *enabled*, and then follows the dispatch with ``block_until_ready`` so
-the span measures real device wall time instead of async dispatch time.
-Disabled (the default) the engine takes the untraced branch — no spans,
-no synchronization, no extra kernel dispatches (tested in
-tests/test_obs.py).
+The in-memory tree is kept only when the tracer is *enabled*: it feeds the
+Chrome-trace export (``repro.obs.export``), ``Telemetry.snapshot()`` and
+``level_seconds()``. Each span there records ``perf_counter`` start/end, a
+category, and free-form attributes. An attribute given as a zero-argument
+callable is evaluated only when the tree is on (an attribute that costs
+work, such as a device count summed on the host), and never reaches the
+profiler.
+
+Nothing here synchronises with the device: a ``dispatch`` span measures
+the host's enqueue (trace and compile included on a fresh executable),
+not device time, which is the profiler's to report. Host time spent
+waiting on the device sits in ``sync`` and ``finalize`` spans. A span is
+opened and closed within one step of a generator, never held across a
+``yield``, so the tree and the profiler nest by time alike.
 
 ``self_seconds`` is a span's exclusive time (duration minus direct
 children), which makes per-level attribution sum-consistent: the exclusive
@@ -35,6 +46,8 @@ from __future__ import annotations
 
 import time
 from contextlib import contextmanager
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Span", "Tracer"]
 
@@ -89,8 +102,8 @@ class Span:
 
 
 class Tracer:
-    """Span-tree recorder. ``enabled=False`` (the default) records nothing
-    and ``span()`` degenerates to a no-op context manager; finished root
+    """Span-tree recorder. ``enabled=False`` (the default) keeps no tree:
+    ``span()`` then only enters its profiler annotation. Finished root
     spans accumulate in ``self.finished``."""
 
     def __init__(self, enabled: bool = False):
@@ -105,29 +118,27 @@ class Tracer:
 
     @contextmanager
     def span(self, name: str, cat: str = "span", **attrs):
-        if not self.enabled:
-            yield None
-            return
-        sp = Span(name, cat, attrs)
-        parent = self.current
-        if parent is not None:
-            parent.children.append(sp)
-        self._stack.append(sp)
-        try:
-            yield sp
-        finally:
-            sp.close()
-            self._stack.pop()
-            if parent is None:
-                self.finished.append(sp)
-
-    def event(self, name: str, **attrs) -> None:
-        """Zero-duration marker attached to the current span."""
-        if not self.enabled or not self._stack:
-            return
-        sp = Span(name, "event", attrs)
-        sp.t1 = sp.t0
-        self._stack[-1].children.append(sp)
+        """One span: an ``ix.<name>`` profiler annotation always, and a
+        tree node (yielded) when enabled, else None. Callable attribute
+        values are evaluated only for the tree."""
+        with TraceAnnotation(f"ix.{name}", **{
+                k: v for k, v in attrs.items() if not callable(v)}):
+            if not self.enabled:
+                yield None
+                return
+            sp = Span(name, cat, {k: (v() if callable(v) else v)
+                                  for k, v in attrs.items()})
+            parent = self.current
+            if parent is not None:
+                parent.children.append(sp)
+            self._stack.append(sp)
+            try:
+                yield sp
+            finally:
+                sp.close()
+                self._stack.pop()
+                if parent is None:
+                    self.finished.append(sp)
 
     # ------------------------------------------------------------- queries
     def spans(self, name: str | None = None) -> list[Span]:

@@ -37,7 +37,6 @@ import itertools
 import threading
 import time
 from collections import deque
-from contextlib import nullcontext
 from typing import Sequence
 
 from repro.graph.csr import CSRGraph
@@ -176,8 +175,7 @@ class MiningService:
         if not batch:
             return summary
         self._batch_h.observe(len(batch))
-        with (tr.span("tick", cat="serve", requests=len(batch))
-              if tr.enabled else nullcontext()):
+        with tr.span("tick", cat="serve", requests=len(batch)):
             now = time.monotonic()
             groups: dict[tuple, list] = {}
             for req in batch:
@@ -234,9 +232,8 @@ class MiningService:
         summary["feed_passes"]["independent"] += indep
         summary["feed_passes"]["fused"] += fused
         try:
-            with (tr.span(f"execute:{tc}", cat="serve",
-                          requests=len(group), queries=len(union))
-                  if tr.enabled else nullcontext()):
+            with tr.span(f"execute:{tc}", cat="serve",
+                         requests=len(group), queries=len(union)):
                 counts = (worker.count_many(union) if agg is None
                           else worker.aggregate_many(union, op=agg))
         except Exception as e:           # noqa: BLE001 — routed per request

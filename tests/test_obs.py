@@ -80,7 +80,7 @@ def test_counter_underflow_raises():
 
 def test_histogram_snapshot():
     reg = MetricsRegistry()
-    h = reg.histogram("wave_items")
+    h = reg.histogram("batch_sizes")
     for v in (1, 10, 100):
         h.observe(v)
     snap = h.snapshot()
@@ -176,7 +176,7 @@ def test_span_tree_single_query():
     roots = tel.tracer.finished
     assert [r.name for r in roots] == ["query"]
     q = roots[0]
-    assert q.attrs == {"kind": "count", "query": "triangle"}
+    assert q.attrs == {"kind": "count", "seq": 0, "query": "triangle"}
     assert [c.name for c in q.children] == ["compile", "execute"]
     ex = q.children[1]
     feeds = ex.find("feed")
@@ -288,3 +288,145 @@ def test_telemetry_snapshot_and_nullspan():
         assert sp is None
     assert off.finished == []
     assert chrome_trace(off)["traceEvents"] == []
+
+
+# ----------------------------------------- profiler annotations (ix.* spans)
+
+def _profile(tmp_path, fn):
+    """Run ``fn`` under ``jax.profiler`` with no Python tracer; return its
+    result and the host events named ``ix.*`` as (name, t0, t1, stats)."""
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    pb = sorted(tmp_path.glob("**/*.xplane.pb"))[-1]
+    events = [(ev.name, ev.start_ns, ev.end_ns, dict(ev.stats))
+              for plane in ProfileData.from_file(str(pb)).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events
+              if ev.name.startswith("ix.")]
+    return out, events
+
+
+def _inside(ev, outer) -> bool:
+    return any(o[1] <= ev[1] and ev[2] <= o[2] for o in outer)
+
+
+def test_spans_reach_the_profiler_nested(tmp_path):
+    """With the span tree off, every span is still an ``ix.*`` profiler
+    annotation: feed bucketing once per feed pass, one ``sync`` with
+    ``site=meta`` per device compaction, nested by time as in the tree."""
+    m = Miner(_pl_graph())
+    m.count("triangle")                       # warm: compile outside
+    m.count_many(list(FOUR_MOTIF_SHAPES))
+    passes = (1 + m.schedule(list(FOUR_MOTIF_SHAPES))
+              .sharing_stats()["feed_passes"]["fused"])
+    before = m.runner.stats["device_compactions"]
+    counts, events = _profile(tmp_path, lambda: (
+        m.count("triangle"), m.count_many(list(FOUR_MOTIF_SHAPES))))
+    assert counts[0] == 440 and m.telemetry.tracer.finished == []
+    by = {}
+    for ev in events:
+        by.setdefault(ev[0], []).append(ev)
+    queries, execs = by["ix.query"], by["ix.execute"]
+    assert sorted(q[3]["seq"] for q in queries) == [2, 3]
+    assert len(execs) == 2 and all(_inside(e, queries) for e in execs)
+    assert len(by["ix.feed_bucket"]) == passes
+    assert all(_inside(f, execs) for f in by["ix.feed_bucket"])
+    assert by["ix.dispatch"] and all(_inside(d, execs)
+                                     for d in by["ix.dispatch"])
+    meta = [s for s in by["ix.sync"] if s[3].get("site") == "meta"]
+    assert len(meta) == m.runner.stats["device_compactions"] - before > 0
+    expands = [ev for name, evs in by.items()
+               if name.startswith("ix.L") and name.endswith(":expand")
+               for ev in evs]
+    assert all(_inside(s, expands) for s in meta)
+    assert all(_inside(f, execs) for f in by["ix.finalize"])
+
+
+def test_traced_dispatch_never_blocks(monkeypatch):
+    """The span tree on, no mining path waits on the device: dispatch
+    spans time the enqueue, and the reads sit in ``sync`` spans."""
+    def refuse(*a, **k):
+        raise AssertionError("block_until_ready called while tracing")
+    monkeypatch.setattr(jax, "block_until_ready", refuse)
+    tel = Telemetry(enabled=True)
+    m = Miner(_pl_graph(), telemetry=tel)
+    assert m.count("triangle") == 440
+    assert m.count("4-clique") == 78
+    assert m.embeddings("triangle").shape == (440, 3)
+    host = Miner(_pl_graph(), telemetry=tel, device_compact=False)
+    assert host.count("4-clique") == 78
+    sites = {s.attrs["site"] for s in tel.tracer.spans("sync")}
+    assert sites == {"meta", "emit", "host_compact"}
+    for d in tel.tracer.spans("dispatch"):
+        assert isinstance(d.attrs["items"], int)
+
+
+def _feed_fill_brute(g, chunk: int, gathers_v1: bool = True):
+    """Level-1 gather slots and keys from the degrees, edge by edge: half
+    edges bucketed by pow2 degree of v0, chunked, v1 rows at the chunk's
+    largest v1 degree (padding is vertex 0, as in the feed)."""
+    import numpy as np
+    deg = [int(d) for d in np.asarray(g.degrees)]
+    indptr = np.asarray(g.indptr)
+    indices = np.asarray(g.indices)
+
+    def pow2(d):
+        c = 128
+        while c < max(d, 1):
+            c *= 2
+        return c
+    buckets: dict[int, list] = {}
+    for u in range(g.num_vertices):
+        for k in range(int(indptr[u]), int(indptr[u + 1])):
+            v = int(indices[k])
+            if v < u:
+                buckets.setdefault(pow2(deg[u]), []).append((u, v))
+    slots = keys = 0
+    for cap0, edges in sorted(buckets.items()):
+        nb = min(chunk, pow2(len(edges)))
+        for lo in range(0, len(edges), nb):
+            part = edges[lo: lo + nb]
+            v1s = [v for _, v in part] + [0] * (nb - len(part))
+            cap1 = pow2(max(deg[v] for v in v1s)) if gathers_v1 else 0
+            slots += nb * (cap0 + cap1)
+            keys += sum(min(deg[u], cap0) + (min(deg[v], cap1) if cap1
+                                             else 0) for u, v in part)
+    return slots, keys
+
+
+@pytest.mark.parametrize("query", ["triangle", "4-clique"])
+def test_feed_fill_counters_brute_force(query):
+    g = _pl_graph()
+    m = Miner(g, chunk=128)
+    m.count(query)
+    slots, keys = _feed_fill_brute(g, 128)
+    assert m.metrics.value("feed_row_slots") == slots
+    assert m.metrics.value("feed_row_keys") == keys
+    m.count(query)                              # one feed pass a query
+    assert m.metrics.value("feed_row_slots") == 2 * slots
+    # registry-only: the legacy stats view does not carry them
+    assert "feed_row_slots" not in m.runner.stats
+
+
+def test_sharded_runner_spans_and_fill_on_one_device():
+    """The mesh runner's feed and reads carry the same spans and fill
+    counters; on a one-device mesh they equal the single runner's."""
+    from repro.distributed.sharding import make_mining_mesh
+    from repro.mining.shard import ShardedWaveRunner
+    g = _pl_graph()
+    tel = Telemetry(enabled=True)
+    r = ShardedWaveRunner(g, make_mining_mesh(1), chunk=128, telemetry=tel)
+    assert r.clique(4) == 78
+    plain = Miner(g, chunk=128)
+    plain.count("4-clique")
+    for k in ("feed_row_slots", "feed_row_keys"):
+        assert r.metrics.value(k) == plain.metrics.value(k) > 0
+    assert len(tel.tracer.spans("feed_bucket")) == 1
+    meta = [s for s in tel.tracer.spans("sync") if s.attrs["site"] == "meta"]
+    assert len(meta) == r.stats["device_compactions"] > 0
