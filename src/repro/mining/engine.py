@@ -105,22 +105,44 @@ def _pow2cap(n: int) -> int:
     return c
 
 
+def _class_of_degree(deg: np.ndarray) -> np.ndarray:
+    """Per-degree capacity class as log2(cap / LANE), where cap is
+    ``_pow2cap(max(d, 1))``: one lookup table, indexed by degree, so a
+    feed classifies every edge end without a Python call per edge."""
+    top = int(deg.max()) if deg.size else 0
+    base = LANE.bit_length()
+    return np.array([_pow2cap(max(d, 1)).bit_length() - base
+                     for d in range(top + 1)], dtype=np.uint16)
+
+
 def edge_buckets(g: CSRGraph, symmetric: bool = True):
     """Host bucketing of one level-1 feed pass: [(cap, edges), ...], the
     (E_b, 2) edges whose prefix vertex v0 falls in degree bucket ``cap``,
-    in ascending ``cap`` order. Runs eagerly, so a caller can time it."""
+    in ascending ``cap`` order. Within a bucket the edges are ordered by
+    the capacity class of v1, ascending (stable: CSR order among equals),
+    so consecutive edges share an N(v1) class and a chunk cut from the
+    bucket gathers N(v1) at its own edges' class, not at the widest v1 of
+    the bucket. Runs eagerly, so a caller can time it."""
     edges = half_edges(g) if symmetric else directed_edges(g)
     if edges.shape[0] == 0:
         return []
     deg = np.asarray(g.degrees)
-    caps = np.array([_pow2cap(max(int(d), 1)) for d in deg[edges[:, 0]]])
-    return [(int(cap), edges[caps == cap]) for cap in np.unique(caps)]
+    cls = _class_of_degree(deg)
+    k0, k1 = cls[deg[edges[:, 0]]], cls[deg[edges[:, 1]]]
+    # one stable sort on the (class v0, class v1) pair: a small-integer key
+    order = np.argsort((k0 << 8) | k1, kind="stable")
+    edges, k0 = edges[order], k0[order]
+    cuts = np.flatnonzero(np.diff(k0)) + 1
+    return [(LANE << int(k0[lo]), edges[lo:hi]) for lo, hi in
+            zip(np.r_[0, cuts], np.r_[cuts, edges.shape[0]])]
 
 
 def bucket_chunks(buckets, chunk: int):
     """Slice ``edge_buckets`` into (cap, v0, v1, n) chunk-padded int32
     vertex arrays *without* materialising neighbor rows — row gathers
-    happen on-device so the feed can be double-buffered."""
+    happen on-device so the feed can be double-buffered. With the bucket
+    in v1-class order, the live v1 of a chunk share one class except in
+    the few chunks that straddle a class change."""
     for cap, sel in buckets:
         # fixed chunk width: one compiled shape per degree bucket
         nb = min(chunk, _pow2cap(sel.shape[0]))
@@ -142,7 +164,9 @@ def edge_wave(g: CSRGraph, chunk: int, symmetric: bool = True):
     """Yield level-1 waves: (v0 rows are N(v0), vert = v1), bucketed by the
     prefix vertex's degree so per-edge work is O(bucket) not O(max degree)
     (<= 2x padding waste — the paper's Fig. 14 stream-length skew exploited
-    as static capacity classes; EXPERIMENTS.md §Perf mining iteration)."""
+    as static capacity classes; EXPERIMENTS.md §Perf mining iteration).
+    Each bucket runs in v1-class order (``edge_buckets``), so the v1 of a
+    wave mostly share one capacity class."""
     for cap, v0, v1, n in edge_chunks(g, chunk, symmetric):
         rows, _ = padded_rows(g, jnp.asarray(v0), cap)
         yield Wave(rows=rows, verts=v1), n
@@ -209,9 +233,10 @@ def pair_chunks(g: CSRGraph, edges: np.ndarray, chunk: int):
     if edges.shape[0] == 0:
         return
     deg = np.asarray(g.degrees)
-    cap_a = np.array([_pow2cap(max(int(d), 1)) for d in deg[edges[:, 0]]])
-    cap_b = np.array([_pow2cap(max(int(d), 1)) for d in deg[edges[:, 1]]])
-    keys = cap_a.astype(np.int64) << 32 | cap_b
+    cls = _class_of_degree(deg).astype(np.int64)
+    cap_a = LANE << cls[deg[edges[:, 0]]]
+    cap_b = LANE << cls[deg[edges[:, 1]]]
+    keys = cap_a << 32 | cap_b
     for key in np.unique(keys):
         ca, cb = int(key >> 32), int(key & 0xFFFFFFFF)
         sel = edges[keys == key]
@@ -521,18 +546,26 @@ class WaveRunner:
                 yield cap, v0, v1, v1, n
         return self._double_buffered(chunks(), frozenset({1, 2}))
 
+    def _live(self, verts, n):
+        """The live entries of a feed chunk's vertex column: ``n`` is the
+        live count, or the per-shard vector when the chunk holds one block
+        per shard. A chunk's N(v1) capacity is taken over these alone, as
+        an expand's meta takes the next level's over live items: every
+        level masks dead rows by ``n``, so their gather may truncate."""
+        live = (np.arange(verts.shape[0] // self._shards)
+                < np.reshape(n, (-1, 1))).reshape(-1)
+        return verts[live]
+
     def _count_feed_fill(self, cap: int, verts, n) -> None:
         """Credit the level-1 gather of one feed chunk's rows of ``verts``
         at capacity ``cap`` — N(v0) for every chunk, N(v1) where the level
         gathers it — to ``feed_row_slots`` (chunk width x cap) and
         ``feed_row_keys`` (the neighbor keys of the live edges among those
-        slots). ``n`` is the live count, or the per-shard vector when the
-        chunk holds one block per shard."""
+        slots)."""
         deg = np.asarray(self.g.degrees)
-        live = (np.arange(verts.shape[0] // self._shards)
-                < np.reshape(n, (-1, 1))).reshape(-1)
         self._ct_row_slots.inc(verts.shape[0] * cap)
-        self._ct_row_keys.inc(int(np.minimum(deg[verts[live]], cap).sum()))
+        self._ct_row_keys.inc(
+            int(np.minimum(deg[self._live(verts, n)], cap).sum()))
 
     # ------------------------------------------------------------- plan parts
     @staticmethod
@@ -1052,7 +1085,7 @@ class WaveRunner:
                              items=lambda: int(np.asarray(n).sum())):
                     caps = {0: cap0}
                     if 1 in op0.row_refs():
-                        caps[1] = _neighbor_cap(self.g, v1h)
+                        caps[1] = _neighbor_cap(self.g, self._live(v1h, n))
                         self._count_feed_fill(caps[1], v1h, n)
                     if self.record:
                         self._record(1, self._rows_fn(cap0)(self.g, dv0),
@@ -1089,7 +1122,7 @@ class WaveRunner:
                                  items=lambda: int(np.asarray(n).sum())):
                         caps = {0: cap0}
                         if need1:
-                            caps[1] = _neighbor_cap(self.g, v1h)
+                            caps[1] = _neighbor_cap(self.g, self._live(v1h, n))
                             self._count_feed_fill(caps[1], v1h, n)
                         if self.record:
                             self._record(1, self._rows_fn(cap0)(self.g, dv0),

@@ -78,7 +78,9 @@ def shard_edge_steps(g: CSRGraph, chunk: int, shards: int,
     divided across the mesh, so a sharded pass takes ~``1/shards`` the
     super-steps of the single-device feed (the dispatch-scaling contract
     gated in benchmarks/ci_gate.py). Each super-step spans
-    ``shards * nb`` consecutive bucket edges:
+    ``shards * nb`` consecutive bucket edges, in the bucket's v1-class
+    order (``edge_buckets``), so a step's N(v1) capacity is that of its own
+    widest live v1:
 
     * ``round_robin`` (default): shard s takes ``step_edges[s::shards]``.
       CSR edge order groups a vertex's edges consecutively, so dealing

@@ -367,10 +367,14 @@ def test_traced_dispatch_never_blocks(monkeypatch):
         assert isinstance(d.attrs["items"], int)
 
 
-def _feed_fill_brute(g, chunk: int, gathers_v1: bool = True):
+def _feed_fill_brute(g, chunk: int, gathers_v1: bool = True,
+                     ordered: bool = True):
     """Level-1 gather slots and keys from the degrees, edge by edge: half
-    edges bucketed by pow2 degree of v0, chunked, v1 rows at the chunk's
-    largest v1 degree (padding is vertex 0, as in the feed)."""
+    edges bucketed by pow2 degree of v0 and, when ``ordered`` (the feed),
+    stably sorted by pow2 degree of v1 inside each bucket, then chunked;
+    v1 rows at the class of the chunk's largest live v1 degree. With
+    ``ordered`` False it counts the CSR-order feed that gathered every v1
+    row of a chunk at its widest row, dead slots padded with vertex 0."""
     import numpy as np
     deg = [int(d) for d in np.asarray(g.degrees)]
     indptr = np.asarray(g.indptr)
@@ -389,10 +393,14 @@ def _feed_fill_brute(g, chunk: int, gathers_v1: bool = True):
                 buckets.setdefault(pow2(deg[u]), []).append((u, v))
     slots = keys = 0
     for cap0, edges in sorted(buckets.items()):
+        if ordered:
+            edges = sorted(edges, key=lambda e: pow2(deg[e[1]]))
         nb = min(chunk, pow2(len(edges)))
         for lo in range(0, len(edges), nb):
             part = edges[lo: lo + nb]
-            v1s = [v for _, v in part] + [0] * (nb - len(part))
+            v1s = [v for _, v in part]
+            if not ordered:
+                v1s += [0] * (nb - len(part))
             cap1 = pow2(max(deg[v] for v in v1s)) if gathers_v1 else 0
             slots += nb * (cap0 + cap1)
             keys += sum(min(deg[u], cap0) + (min(deg[v], cap1) if cap1
