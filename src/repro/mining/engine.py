@@ -137,21 +137,36 @@ def edge_buckets(g: CSRGraph, symmetric: bool = True):
             zip(np.r_[0, cuts], np.r_[cuts, edges.shape[0]])]
 
 
+def feed_cuts(buckets, chunk: int, shards: int = 1):
+    """The steps of one level-1 feed pass over ``edge_buckets``, as
+    (cap, block, nb): ``block`` is the bucket's next ``shards * nb`` edges
+    (fewer at the bucket's end) and ``nb`` the width of one shard's part,
+    ``min(chunk, pow2cap(ceil(E / shards)))`` for a bucket of E edges: one
+    compiled shape per degree bucket. A block is a view; padding it
+    (``chunk_of``) or dealing it over shards (``shard.deal``) is the
+    step's own work."""
+    for cap, sel in buckets:
+        e = sel.shape[0]
+        nb = min(chunk, _pow2cap(max(-(-e // shards), 1)))
+        for lo in range(0, e, shards * nb):
+            yield cap, sel[lo: lo + shards * nb], nb
+
+
+def chunk_of(block: np.ndarray, nb: int):
+    """(v0, v1, n): one feed chunk's int32 vertex columns, padded to ``nb``
+    with vertex 0, and its live count."""
+    return (_pad_to(block[:, 0].astype(np.int32), nb, 0),
+            _pad_to(block[:, 1].astype(np.int32), nb, 0), block.shape[0])
+
+
 def bucket_chunks(buckets, chunk: int):
     """Slice ``edge_buckets`` into (cap, v0, v1, n) chunk-padded int32
     vertex arrays *without* materialising neighbor rows — row gathers
     happen on-device so the feed can be double-buffered. With the bucket
     in v1-class order, the live v1 of a chunk share one class except in
     the few chunks that straddle a class change."""
-    for cap, sel in buckets:
-        # fixed chunk width: one compiled shape per degree bucket
-        nb = min(chunk, _pow2cap(sel.shape[0]))
-        for lo in range(0, sel.shape[0], nb):
-            sl = sel[lo: lo + nb]
-            n = sl.shape[0]
-            v0 = _pad_to(sl[:, 0].astype(np.int32), nb, 0)
-            v1 = _pad_to(sl[:, 1].astype(np.int32), nb, 0)
-            yield cap, v0, v1, n
+    for cap, block, nb in feed_cuts(buckets, chunk):
+        yield (cap, *chunk_of(block, nb))
 
 
 def edge_chunks(g: CSRGraph, chunk: int, symmetric: bool = True):
@@ -375,6 +390,9 @@ class WaveRunner:
     # the mesh size on ShardedWaveRunner (which also divides the host-side
     # batch arithmetic below by it).
     _shards: int = 1
+    # where a feed step's arrays go: the default device here, split over
+    # the mining axis on ShardedWaveRunner
+    _feed_sharding = None
     # prepended to every executable-cache key so sharded (shard_map-wrapped)
     # traces can never collide with unsharded traces of the same LevelOp
     _exec_prefix: tuple = ()
@@ -517,34 +535,41 @@ class WaveRunner:
 
     # ------------------------------------------------------------------ feeds
     @staticmethod
-    def _double_buffered(chunks, put_idx: frozenset):
-        """Run one item ahead of the consumer, ``jax.device_put``-ing the
-        arrays at ``put_idx``: chunk N+1's upload dispatches (async) while
-        the consumer computes on chunk N."""
+    def _double_buffered(steps):
+        """Run one step ahead of the consumer: step N+1 is built and its
+        upload dispatched (async) while the consumer computes on step N."""
         pending = None
-        for tup in chunks:
-            nxt = tuple(jax.device_put(x) if i in put_idx else x
-                        for i, x in enumerate(tup))
+        for step in steps:
             if pending is not None:
                 yield pending
-            pending = nxt
+            pending = step
         if pending is not None:
             yield pending
 
     def _edge_feed(self, symmetric: bool = True):
         """Double-buffered level-1 feed: (cap, dv0, dv1, v1_host, n). The
-        bucketing runs here, eagerly, inside a ``feed_bucket`` span; the
-        returned generator only slices, counts the N(v0) rows' fill and
-        uploads."""
-        with self.telemetry.tracer.span("feed_bucket", cat="host",
-                                        symmetric=symmetric):
+        bucketing runs here, eagerly, inside a ``feed_bucket`` span; each
+        step's padding (or dealing), fill count and upload run inside a
+        ``feed_step`` span, closed before the step is handed on, so it
+        never spans a dispatch."""
+        tr = self.telemetry.tracer
+        with tr.span("feed_bucket", cat="host", symmetric=symmetric):
             buckets = edge_buckets(self.g, symmetric)
 
-        def chunks():
-            for cap, v0, v1, n in bucket_chunks(buckets, self.chunk):
-                self._count_feed_fill(cap, v0, n)
-                yield cap, v0, v1, v1, n
-        return self._double_buffered(chunks(), frozenset({1, 2}))
+        def steps():
+            for cap, block, nb in feed_cuts(buckets, self.chunk,
+                                            self._shards):
+                with tr.span("feed_step", cat="host"):
+                    v0, v1, n = self._cut(block, nb)
+                    self._count_feed_fill(cap, v0, n)
+                    step = (cap, jax.device_put(v0, self._feed_sharding),
+                            jax.device_put(v1, self._feed_sharding), v1, n)
+                yield step
+        return self._double_buffered(steps())
+
+    def _cut(self, block: np.ndarray, nb: int):
+        """(v0, v1, n) of one feed step: the block padded to ``nb``."""
+        return chunk_of(block, nb)
 
     def _live(self, verts, n):
         """The live entries of a feed chunk's vertex column: ``n`` is the

@@ -38,9 +38,15 @@ The level-1 feed is dealt by ``shard_edge_steps``: per degree bucket,
 edges are round-robin dealt across shards (CSR edge order is sorted by
 source vertex, so a hub's edge run would land on one shard under a
 contiguous split — the dealt assignment bounds the per-step imbalance at
-one item). ``stats["shard_feed_items"]`` exposes the per-shard feed item
-counts so the balance is measurable; ``mode="contiguous"`` keeps the
-chunk-granular contiguous assignment as the measurable foil.
+one item). The runner deals each super-step in the base feed's
+``feed_step`` span (``WaveRunner._edge_feed``; dealing, fill count and
+the sharded upload). ``stats["shard_feed_items"]`` exposes the per-shard
+feed item counts so the balance is measurable, and the registry counter
+``shard_pad_items`` the lockstep slots no live item fills (level-1
+super-steps and expand chunks); ``mode="contiguous"`` keeps the
+chunk-granular contiguous assignment as the measurable foil. The leaf
+reductions run in the name scope ``mesh_psum`` (``MESH_SCOPE``), so their
+device ops can be told apart in a profile.
 
 Use via the session API (``Miner(g, mesh=8)``); the mesh itself comes from
 ``repro.distributed.sharding.make_mining_mesh`` and its axes are part of
@@ -59,11 +65,14 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.stream import round_capacity
 from repro.graph.csr import CSRGraph
-from .engine import WaveRunner, _pow2cap, edge_buckets
+from .engine import WaveRunner, _pow2cap, edge_buckets, feed_cuts
 
 __all__ = ["ShardedWaveRunner", "shard_edge_steps"]
 
 FEED_PARTITIONS = ("round_robin", "contiguous")
+# name scope of the cross-shard reductions of count and aggregate leaves:
+# their device ops carry it in their ``tf_op``
+MESH_SCOPE = "mesh_psum"
 
 
 def shard_edge_steps(g: CSRGraph, chunk: int, shards: int,
@@ -103,23 +112,26 @@ def shard_edge_steps(g: CSRGraph, chunk: int, shards: int,
 def shard_bucket_steps(buckets, chunk: int, shards: int,
                        mode: str = "round_robin"):
     """The dealing half of ``shard_edge_steps``, over ``edge_buckets``."""
-    for cap, sel in buckets:
-        e = sel.shape[0]
-        nb = min(chunk, _pow2cap(max(-(-e // shards), 1)))
-        span = shards * nb
-        for lo in range(0, e, span):
-            blk = sel[lo: lo + span]
-            v0 = np.zeros((shards, nb), np.int32)
-            v1 = np.zeros((shards, nb), np.int32)
-            n = np.zeros((shards,), np.int32)
-            for s in range(shards):
-                part = blk[s::shards] if mode == "round_robin" \
-                    else blk[s * nb: (s + 1) * nb]
-                k = part.shape[0]
-                n[s] = k
-                v0[s, :k] = part[:, 0]
-                v1[s, :k] = part[:, 1]
-            yield cap, v0.reshape(-1), v1.reshape(-1), n
+    for cap, block, nb in feed_cuts(buckets, chunk, shards):
+        yield (cap, *deal(block, nb, shards, mode))
+
+
+def deal(block: np.ndarray, nb: int, shards: int,
+         mode: str = "round_robin"):
+    """(v0, v1, n) of one lockstep super-step: the ``block``'s edges dealt
+    into ``shards`` blocks of ``nb`` items laid back to back (dead slots
+    vertex 0), and the (shards,) per-shard live counts."""
+    v0 = np.zeros((shards, nb), np.int32)
+    v1 = np.zeros((shards, nb), np.int32)
+    n = np.zeros((shards,), np.int32)
+    for s in range(shards):
+        part = block[s::shards] if mode == "round_robin" \
+            else block[s * nb: (s + 1) * nb]
+        k = part.shape[0]
+        n[s] = k
+        v0[s, :k] = part[:, 0]
+        v1[s, :k] = part[:, 1]
+    return v0.reshape(-1), v1.reshape(-1), n
 
 
 class ShardedWaveRunner(WaveRunner):
@@ -175,6 +187,10 @@ class ShardedWaveRunner(WaveRunner):
                             for s in range(self._shards)]
         self.stats.expose("shard_feed_items",
                           lambda: [c.value for c in self._shard_feed])
+        # registry-only: the slots of lockstep blocks that no live item
+        # fills (level-1 super-steps and expand chunks); beside
+        # shard_feed_items it gives the mesh's feed fill
+        self._ct_shard_pad = self.metrics.counter("shard_pad_items")
 
     # ----------------------------------------------------------- dispatch
     def _shmap(self, body: Callable, in_specs, out_specs) -> Callable:
@@ -197,9 +213,10 @@ class ShardedWaveRunner(WaveRunner):
             part = body(g, vals, carry, n)
             # 16-bit limb split BEFORE the psum: per-shard hi can reach
             # 2^30, limb sums stay < 2^19 (hi) / 2^31 (lo) at any mesh size
-            limbs = jnp.stack([part[0] >> 16, part[0] & 0xFFFF,
-                               part[1] >> 16, part[1] & 0xFFFF])
-            return jax.lax.psum(limbs, axis)
+            with jax.named_scope(MESH_SCOPE):
+                limbs = jnp.stack([part[0] >> 16, part[0] & 0xFFFF,
+                                   part[1] >> 16, part[1] & 0xFFFF])
+                return jax.lax.psum(limbs, axis)
         return self._shmap(wrapped, self._level_in_specs(op), self._prp)
 
     def _jit_agg(self, op, body):
@@ -212,8 +229,9 @@ class ShardedWaveRunner(WaveRunner):
             # value reduces with the leaf's own op (a dead shard carries the
             # op identity, so pmax/pmin absorb it); live always psums —
             # finalize gates the identity out when the whole mesh is dead
-            return jnp.stack([red(part[0], axis),
-                              jax.lax.psum(part[1], axis)])
+            with jax.named_scope(MESH_SCOPE):
+                return jnp.stack([red(part[0], axis),
+                                  jax.lax.psum(part[1], axis)])
         return self._shmap(wrapped, self._level_in_specs(op), self._prp)
 
     def _jit_expand(self, op, body, want_count):
@@ -253,27 +271,16 @@ class ShardedWaveRunner(WaveRunner):
             self._ct["psum_reductions"].inc()
 
     # --------------------------------------------------------------- feed
-    def _edge_feed(self, symmetric: bool = True):
-        """Sharded level-1 feed: per-shard edge blocks are laid out back to
-        back and ``device_put`` with the mining-axis sharding (still
-        double-buffered — step N+1's shard transfers dispatch while the
-        mesh computes step N). ``n`` is the per-shard live-count vector.
-        The bucketing runs here, eagerly, inside a ``feed_bucket`` span."""
-        sh = self._feed_sharding
-        feed = self._shard_feed
-        with self.telemetry.tracer.span("feed_bucket", cat="host",
-                                        symmetric=symmetric):
-            buckets = edge_buckets(self.g, symmetric)
-
-        def gen():
-            for cap, v0, v1, n in shard_bucket_steps(
-                    buckets, self.chunk, self._shards, self.feed_partition):
-                for s in range(self._shards):
-                    feed[s].inc(int(n[s]))
-                self._count_feed_fill(cap, v0, n)
-                yield (cap, jax.device_put(v0, sh), jax.device_put(v1, sh),
-                       v1, n)
-        return self._double_buffered(gen(), frozenset())
+    def _cut(self, block, nb: int):
+        """Deal one super-step over the shards (``deal``), crediting each
+        shard's live items to ``shard_feed_items`` and the lockstep padding
+        slots to ``shard_pad_items``; the base feed uploads it split over
+        the mining axis."""
+        v0, v1, n = deal(block, nb, self._shards, self.feed_partition)
+        for c, k in zip(self._shard_feed, n):
+            c.inc(int(k))
+        self._ct_shard_pad.inc(v0.shape[0] - int(n.sum()))
+        return v0, v1, n
 
     # ------------------------------------------------- boundary-meta plumbing
     def _pack_total(self, tot):
@@ -324,6 +331,7 @@ class ShardedWaveRunner(WaveRunner):
         totals = np.asarray(totals, dtype=np.int64).reshape(-1)
         for lo in range(0, int(totals.max()), self.chunk):
             m = np.clip(totals - lo, 0, self.chunk).astype(np.int32)
+            self._ct_shard_pad.inc(self._shards * self.chunk - int(m.sum()))
             if op.carry_out:
                 outs, vch, carry2 = cfn(rows2, src, verts2, fwdvals, lo, m)
             else:
