@@ -14,8 +14,16 @@ contract checked here, on a hub-heavy Holme–Kim graph:
   * ``feed_row_slots`` matches a brute-force count, below the old
     chunk-max count;
   * counts equal ``mining.reference`` on the one-device runner and on the
-    8-device CPU mesh in both feed partitions.
+    8-device CPU mesh in both feed partitions (a subprocess, so the mesh
+    check runs whatever devices this process sees); N(v1) rows here span
+    one to four 128-lane blocks of the CSR's block plane.
 """
+import json
+import os
+import pathlib
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
@@ -36,6 +44,7 @@ needs8 = pytest.mark.skipif(
     reason="needs 8 devices "
            "(XLA_FLAGS=--xla_force_host_platform_device_count=8)")
 
+ROOT = pathlib.Path(__file__).resolve().parents[1]
 N = 800
 CHUNK = 128
 
@@ -164,8 +173,34 @@ def test_counts_match_the_reference_on_one_device(query, want):
     assert Miner(HUB, chunk=CHUNK).count(query) == want[query]
 
 
-@needs8
+MESH8_SCRIPT = r"""
+import json
+import jax
+from test_feed_order import CHUNK, HUB, QUERIES
+from repro.mining.session import Miner
+from repro.mining.shard import FEED_PARTITIONS
+
+assert jax.device_count() == 8
+print(json.dumps({mode: {q: Miner(HUB, chunk=CHUNK, mesh=8,
+                                  feed_partition=mode).count(q)
+                         for q in QUERIES} for mode in FEED_PARTITIONS}))
+"""
+
+
+@pytest.fixture(scope="module")
+def mesh8_counts():
+    """The queries on an 8-device CPU mesh, in a subprocess that sees
+    eight devices (this process may see one)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=8",
+               PYTHONPATH=os.pathsep.join([str(ROOT / "src"),
+                                           str(ROOT / "tests")]))
+    p = subprocess.run([sys.executable, "-c", MESH8_SCRIPT], env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    return json.loads(p.stdout.strip().splitlines()[-1])
+
+
 @pytest.mark.parametrize("mode", FEED_PARTITIONS)
-def test_counts_match_the_reference_on_the_mesh(mode, want):
-    m = Miner(HUB, chunk=CHUNK, mesh=8, feed_partition=mode)
-    assert {q: m.count(q) for q in QUERIES} == want
+def test_counts_match_the_reference_on_the_mesh(mode, want, mesh8_counts):
+    assert mesh8_counts[mode] == want
