@@ -76,36 +76,22 @@ def _level2_dispatches(level_execs: dict) -> int:
 
 
 def wave_throughput_report(g, k: int = 4) -> dict:
-    """Before/after the device-resident rewrite: work items/s through the
-    expand -> compact -> next-wave loop on a warmed executable cache.
-
-    'host' routes every level through the np.nonzero + re-upload oracle;
-    'device' keeps the worklist on device (ops.xinter_compact) with only
-    the 3-scalar meta sync per level. Same counts by construction (tested
-    bit-identical in tests/test_wave_device.py)."""
+    """Work items/s through the device expand -> compact -> next-wave loop
+    of a k-clique query, on a warmed executable cache."""
     from repro.mining.engine import WaveRunner
-    out = {}
-    for label, dc in (("host", False), ("device", True)):
-        runner = WaveRunner(g, device_compact=dc)
-        runner.clique(k)                    # warm-up: traces + compiles
-        warm = dict(runner.stats)
-        count, dt = _stopwatch(f"wave_throughput:{label}",
-                               lambda: runner.clique(k))
-        items = runner.stats["items"] - warm["items"]
-        out[label] = {
-            "count": count, "seconds": round(dt, 4), "items": items,
-            "items_per_s": round(items / max(dt, 1e-9), 1),
-            # per-timed-run deltas: the warm-up pass must not inflate these
-            "host_compactions": (runner.stats["host_compactions"]
-                                 - warm["host_compactions"]),
-            "device_compactions": (runner.stats["device_compactions"]
-                                   - warm["device_compactions"]),
-            "exec_misses": runner.stats["exec_misses"] - warm["exec_misses"],
-        }
-    assert out["host"]["count"] == out["device"]["count"]
-    out["wave_speedup"] = round(
-        out["host"]["seconds"] / max(out["device"]["seconds"], 1e-9), 2)
-    return out
+    runner = WaveRunner(g)
+    runner.clique(k)                        # warm-up: traces + compiles
+    warm = dict(runner.stats)
+    count, dt = _stopwatch("wave_throughput", lambda: runner.clique(k))
+    items = runner.stats["items"] - warm["items"]
+    return {
+        "count": count, "seconds": round(dt, 4), "items": items,
+        "items_per_s": round(items / max(dt, 1e-9), 1),
+        # per-timed-run deltas: the warm-up pass must not inflate these
+        "device_compactions": (runner.stats["device_compactions"]
+                               - warm["device_compactions"]),
+        "exec_misses": runner.stats["exec_misses"] - warm["exec_misses"],
+    }
 
 
 def forest_fusion_report(g) -> dict:
@@ -152,49 +138,30 @@ def forest_fusion_report(g) -> dict:
     return out
 
 
-def fused_level_report(g) -> dict:
-    """Fused k-operand level kernel vs the per-ref mark fallback.
-
-    4-cycle's terminal level references two streams (v3 ∈ N(v1) ∩ N(v2) \\
-    N(v0) after the base pull: one INTER + one SUB ref), so the per-ref path
-    issues k=2 membership dispatches per executable call where the fused
-    path (``ops.xlevel_count``) issues exactly 1 — the per-operand B-tile
-    DMA the tentpole removes. Counts are asserted bit-identical; dispatch
-    counts come from ``WaveRunner.stats['level_kernel_dispatches']``."""
+def general_level_report(g) -> dict:
+    """4-cycle's terminal level references two streams (v3 ∈ N(v1) ∩ N(v2)
+    \\ N(v0) after the base pull: one INTER + one SUB ref); the k-operand
+    kernel (``ops.xlevel_count``) covers it with one membership dispatch
+    per executable call. Read from ``level_kernel_dispatches`` on a warmed
+    runner, net of the other levels' one dispatch a call (each holds one
+    ref)."""
     from repro.mining.engine import WaveRunner
     from repro.mining.plan import CYCLE4, compile_pattern
     plan = compile_pattern(CYCLE4)
-    k_general = len(plan.ops[-1].inter) + len(plan.ops[-1].sub)
-    out = {}
-    for label, fl in (("per_ref", False), ("fused", True)):
-        runner = WaveRunner(g, fused_level=fl)
-        runner.run(plan)                    # warm-up: traces + compiles
-        warm = dict(runner.stats)
-        warm_execs = dict(runner.level_execs)
-        count, dt = _stopwatch(f"fused_level:{label}",
-                               lambda: runner.run(plan))
-        gen_execs = (runner.level_execs.get(("count", 3), 0)
-                     - warm_execs.get(("count", 3), 0))
-        dispatches = (runner.stats["level_kernel_dispatches"]
-                      - warm["level_kernel_dispatches"])
-        out[label] = {
-            "count": count, "seconds": round(dt, 4),
-            "kernel_dispatches": dispatches,
-            "general_level_execs": gen_execs,
-        }
-    assert out["fused"]["count"] == out["per_ref"]["count"]
-    # isolate the general level: the single-op level-2 dispatches (one each,
-    # identical in both modes) are whatever the fused run spent beyond its
-    # one-per-general-level — the acceptance metric is k -> 1 per level
-    n = out["fused"]["general_level_execs"]
-    shared = out["fused"]["kernel_dispatches"] - n
-    for label in ("per_ref", "fused"):
-        out[label]["dispatches_per_general_level"] = round(
-            (out[label]["kernel_dispatches"] - shared) / max(n, 1), 2)
-    out["k_general"] = k_general
-    out["fused_level_speedup"] = round(
-        out["per_ref"]["seconds"] / max(out["fused"]["seconds"], 1e-9), 2)
-    return out
+    *rest, last = plan.ops
+    assert all(len(op.inter) + len(op.sub) == 1 for op in rest)
+    runner = WaveRunner(g)
+    runner.run(plan)                        # warm-up: traces + compiles
+    d0 = runner.stats["level_kernel_dispatches"]
+    e0 = dict(runner.level_execs)
+    count = runner.run(plan)
+    execs = {k: v - e0.get(k, 0) for k, v in runner.level_execs.items()}
+    n = execs[(last.kind, last.level)]
+    others = sum(execs.values()) - n
+    return {"count": count, "k_general": len(last.inter) + len(last.sub),
+            "dispatches_per_general_level": round(
+                (runner.stats["level_kernel_dispatches"] - d0 - others)
+                / max(n, 1), 2)}
 
 
 def session_serving_report(g) -> dict:
@@ -462,15 +429,9 @@ def run(quick: bool = True):
         stats = dataset_stats(g)
         wt = wave_throughput_report(g)
         print(f"[mining] {name:14s} 4C wave loop: "
-              f"host {wt['host']['items_per_s']:.0f} items/s "
-              f"({wt['host']['host_compactions']} np.nonzero round-trips) | "
-              f"device {wt['device']['items_per_s']:.0f} items/s "
-              f"(0 host round-trips) | wave_speedup={wt['wave_speedup']}x",
-              flush=True)
-        rows.append(dict(dataset=name, app="4C-wave", **{
-            "host_items_per_s": wt["host"]["items_per_s"],
-            "device_items_per_s": wt["device"]["items_per_s"],
-            "wave_speedup": wt["wave_speedup"]}))
+              f"{wt['items_per_s']:.0f} items/s", flush=True)
+        rows.append(dict(dataset=name, app="4C-wave",
+                         items_per_s=wt["items_per_s"]))
         po = plan_overhead_report(g)
         print(f"[mining] {name:14s} plan vs hand-coded: "
               + " | ".join(f"{a} {v['plan_s']:.3f}s vs {v['handcoded_s']:.3f}s "
@@ -479,18 +440,11 @@ def run(quick: bool = True):
         rows.append(dict(dataset=name, app="plan-overhead", **{
             f"{a}_{k}": v[k] for a, v in po.items()
             for k in ("plan_s", "handcoded_s", "plan_overhead")}))
-        fl = fused_level_report(g)
-        print(f"[mining] {name:14s} CY fused level: "
-              f"{fl['per_ref']['dispatches_per_general_level']:.0f} -> "
-              f"{fl['fused']['dispatches_per_general_level']:.0f} membership "
-              f"dispatches per general level (k={fl['k_general']}) | "
-              f"fused {fl['fused']['seconds']:.3f}s vs per-ref "
-              f"{fl['per_ref']['seconds']:.3f}s "
-              f"(speedup {fl['fused_level_speedup']}x)", flush=True)
-        rows.append(dict(dataset=name, app="CY-fused-level", **{
-            "per_ref_dispatches": fl["per_ref"]["kernel_dispatches"],
-            "fused_dispatches": fl["fused"]["kernel_dispatches"],
-            "fused_level_speedup": fl["fused_level_speedup"]}))
+        gl = general_level_report(g)
+        print(f"[mining] {name:14s} CY general level: "
+              f"{gl['dispatches_per_general_level']:.0f} membership "
+              f"dispatch per call (k={gl['k_general']})", flush=True)
+        rows.append(dict(dataset=name, app="CY-general-level", **gl))
         if name == "email-eu-core":
             import jax as _jax
             sr = sharded_scaling_report(g)

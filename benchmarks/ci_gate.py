@@ -5,13 +5,12 @@ to ``BENCH_mining.json`` (uploaded as a CI artifact) and compares it
 against the checked-in ``benchmarks/baseline.json``:
 
 * **exact metrics** — mining counts and structural counters (forest level-2
-  dispatch/feed counts, fused-level membership dispatches per general
-  level). The datasets are deterministic synthetic generators and the
+  dispatch/feed counts, membership dispatches per general level). The datasets are deterministic synthetic generators and the
   counters are schedule facts, so these are machine-independent and must
   match the baseline EXACTLY: any drift is a correctness or scheduling
   regression, not noise.
 * **ratio metrics** — wall-clock ratios (plan interpreter overhead, forest
-  fusion speedup, fused-level speedup, device-vs-host wave speedup).
+  fusion speedup).
   Ratios, not absolute times, so they transfer across machines, but CI
   runners are noisy: a metric only fails when it is worse than baseline by
   more than its tolerance (per-metric ``tolerances`` in baseline.json,
@@ -53,13 +52,12 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from benchmarks.bench_mining import (fused_level_report,   # noqa: E402
-                                     forest_fusion_report,
+from benchmarks.bench_mining import (forest_fusion_report,  # noqa: E402
+                                     general_level_report,
                                      plan_overhead_report,
                                      session_serving_report,
                                      sharded_scaling_report,
-                                     svpu_report,
-                                     wave_throughput_report)
+                                     svpu_report)
 
 # exact app counts: small + cheap (deterministic synthetic graphs)
 COUNT_SETS = [("citeseer", 1.0), ("email-eu-core", 0.25)]
@@ -97,8 +95,6 @@ DEFAULT_TOLERANCES = {
     "plan_overhead_4C": 0.6,
     "plan_overhead_TT": 0.8,
     "fusion_speedup": 0.5,
-    "fused_level_speedup": 0.5,
-    "wave_speedup": 0.6,
     # service vs sequential: thread scheduling + queueing make these the
     # noisiest gated ratios (p50 is artifact-only for the same reason)
     "qps_vs_sequential": 0.6,
@@ -112,8 +108,6 @@ DIRECTIONS = {
     "plan_overhead_4C": "lower_better",
     "plan_overhead_TT": "lower_better",
     "fusion_speedup": "higher_better",
-    "fused_level_speedup": "higher_better",
-    "wave_speedup": "higher_better",
     "qps_vs_sequential": "higher_better",
     "p99_vs_sequential": "lower_better",
     "weighted_overhead": "lower_better",
@@ -399,13 +393,11 @@ def measure(sharded: bool = False, telemetry: bool = False,
     g = get_dataset(name, scale=scale)
     tag = f"{name}@{scale}"
     print(f"[gate] {tag}: perf reports ...", flush=True)
-    fl = fused_level_report(g)
-    exact[f"{tag}.CY"] = fl["fused"]["count"]
-    exact[f"{tag}.fused_level.k_general"] = fl["k_general"]
+    gl = general_level_report(g)
+    exact[f"{tag}.CY"] = gl["count"]
+    exact[f"{tag}.fused_level.k_general"] = gl["k_general"]
     exact[f"{tag}.fused_level.dispatches_per_general_level"] = {
-        m: fl[m]["dispatches_per_general_level"]
-        for m in ("per_ref", "fused")}
-    ratios[f"{tag}.fused_level_speedup"] = fl["fused_level_speedup"]
+        "fused": gl["dispatches_per_general_level"]}
 
     ff = forest_fusion_report(g)
     exact[f"{tag}.forest.level2_execs"] = [
@@ -417,9 +409,6 @@ def measure(sharded: bool = False, telemetry: bool = False,
     po = plan_overhead_report(g)
     ratios[f"{tag}.plan_overhead_4C"] = po["4C"]["plan_overhead"]
     ratios[f"{tag}.plan_overhead_TT"] = po["TT"]["plan_overhead"]
-
-    wt = wave_throughput_report(g)
-    ratios[f"{tag}.wave_speedup"] = wt["wave_speedup"]
 
     if sharded:
         measure_sharded(exact)

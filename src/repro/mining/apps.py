@@ -64,7 +64,7 @@ def _deprecated(name: str) -> None:
 # the module-level session pool backing the deprecated one-shot surface
 # ---------------------------------------------------------------------------
 
-# (id(graph), chunk, device_compact) -> (weakref to graph, Miner). The
+# (id(graph), chunk) -> (weakref to graph, Miner). The
 # weakref guards against id() reuse after the original graph is collected;
 # a small LRU bounds how many sessions (device stagings + exec caches) the
 # shim surface keeps alive at once.
@@ -72,68 +72,63 @@ _SESSION_POOL: OrderedDict = OrderedDict()
 _SESSION_POOL_CAP = 8
 
 
-def shared_session(g: CSRGraph, chunk: int | None = None,
-                   device_compact: bool = True) -> Miner:
+def shared_session(g: CSRGraph, chunk: int | None = None) -> Miner:
     """Get-or-create the module-level ``Miner`` for (graph, config).
 
     This is what makes the legacy free functions sessions in disguise:
     every call over the same graph and config lands on one ``Miner``, so
     graph staging, compiled plans, schedules and executables are all
     reused across calls."""
-    key = (id(g), chunk, device_compact)
+    key = (id(g), chunk)
     ent = _SESSION_POOL.get(key)
     if ent is not None and ent[0]() is g:
         _SESSION_POOL.move_to_end(key)
         return ent[1]
-    miner = Miner(g, chunk=chunk, device_compact=device_compact)
+    miner = Miner(g, chunk=chunk)
     _SESSION_POOL[key] = (weakref.ref(g), miner)
     while len(_SESSION_POOL) > _SESSION_POOL_CAP:
         _SESSION_POOL.popitem(last=False)
     return miner
 
 
-def pattern_count(g: CSRGraph, pat: Pattern, chunk: int | None = None,
-                  device_compact: bool = True) -> int:
+def pattern_count(g: CSRGraph, pat: Pattern, chunk: int | None = None) -> int:
     """Deprecated shim: ``Miner.count`` on the shared session."""
     _deprecated("pattern_count")
-    return shared_session(g, chunk, device_compact).count(pat)
+    return shared_session(g, chunk).count(pat)
 
 
-def pattern_embeddings(g: CSRGraph, pat: Pattern, chunk: int | None = None,
-                       device_compact: bool = True) -> np.ndarray:
+def pattern_embeddings(g: CSRGraph, pat: Pattern,
+                       chunk: int | None = None) -> np.ndarray:
     """Deprecated shim: ``Miner.embeddings`` on the shared session."""
     _deprecated("pattern_embeddings")
-    return shared_session(g, chunk, device_compact).embeddings(pat)
+    return shared_session(g, chunk).embeddings(pat)
 
 
 def pattern_set_run(g: CSRGraph, plans: list[WavePlan] | PlanForest,
-                    chunk: int | None = None,
-                    device_compact: bool = True) -> list:
+                    chunk: int | None = None) -> list:
     """Deprecated shim: run a batch of compiled plans (or a pre-built
     ``PlanForest``) as one fused pass on the shared session. Results come
     back per plan, in order — ints for counting plans, (N, k) matrices for
     emit plans — bit-identical to independent ``Miner.count`` runs."""
     _deprecated("pattern_set_run")
-    miner = shared_session(g, chunk, device_compact)
+    miner = shared_session(g, chunk)
     if isinstance(plans, PlanForest):
         return miner.runner.run_set(plans)
     return miner.run_plans(plans)
 
 
 def pattern_set_count(g: CSRGraph, pats: list[Pattern],
-                      chunk: int | None = None,
-                      device_compact: bool = True) -> list[int]:
+                      chunk: int | None = None) -> list[int]:
     """Deprecated shim: ``Miner.count_many`` on the shared session."""
     _deprecated("pattern_set_count")
-    return shared_session(g, chunk, device_compact).count_many(pats)
+    return shared_session(g, chunk).count_many(pats)
 
 
-def triangle_count(g: CSRGraph, chunk: int | None = None,
-                   device_compact: bool = True) -> int:
+def triangle_count(g: CSRGraph, chunk: int | None = None) -> int:
     """Symmetry-broken triangle counting: one bounded intersection per half
     edge (v0 > v1), bound v1 => each triangle v0 > v1 > v2 counted once."""
     _deprecated("triangle_count")
-    return shared_session(g, chunk, device_compact).count(TRIANGLE)
+    return shared_session(g, chunk).count(TRIANGLE)
 
 
 def triangle_count_nested(g: CSRGraph, chunk: int | None = None) -> int:
@@ -186,17 +181,15 @@ def three_motif(g: CSRGraph, fused: bool = True) -> dict[str, int]:
     return {"triangle": t, "chain": chains}
 
 
-def clique_count(g: CSRGraph, k: int, chunk: int | None = None,
-                 device_compact: bool = True) -> int:
+def clique_count(g: CSRGraph, k: int, chunk: int | None = None) -> int:
     """k-clique counting, k >= 3: the compiled chain-restricted plan. Every
     level reuses the parent's survivor stream (the compiler's carry
     analysis), so the interpreter issues the exact executable sequence the
-    old hand-coded engine did. ``device_compact=False`` routes the same plan
-    through the host np.nonzero oracle."""
+    old hand-coded engine did."""
     _deprecated("clique_count")
     if k < 3:
         raise ValueError("clique_count needs k >= 3")
-    return shared_session(g, chunk, device_compact).count(clique_pattern(k))
+    return shared_session(g, chunk).count(clique_pattern(k))
 
 
 def four_motif(g: CSRGraph, chunk: int | None = None,

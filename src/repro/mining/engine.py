@@ -17,19 +17,15 @@ a dense worklist (the translation buffer of §IV-F), and the prefix capacity
 is re-derived from the actual max survivor length — the paper's Fig. 14
 observation (clique streams are short) becomes an adaptive buffer size.
 
-Two compaction paths exist:
-
-  * **device (fast path, ``WaveRunner``)**: the expand's match mask is
-    compacted on-device (segmented prefix-sum scatter,
-    ``ops.xinter_compact`` / ``ops.xlevel_compact``) into the next wave's
-    (rows, verts) buffers;
-    only three level-boundary scalars (total, max count, max degree) ever
-    cross to the host. Executables are cached per (cap_a, cap_b, chunk) so
-    degree-bucketed shapes never retrace, and the level-1 edge feed is
-    double-buffered (chunk N+1 uploads while chunk N computes) — the
-    S-Cache residency win, restated as "operands never leave HBM".
-  * **host (oracle, ``compact``)**: ``np.nonzero`` + re-upload. Kept as the
-    semantic reference the device path is property-tested against.
+``WaveRunner`` compacts the expand's match mask on-device (segmented
+prefix-sum scatter, ``ops.xinter_compact`` / ``ops.xlevel_compact``) into
+the next wave's (rows, verts) buffers; only the level-boundary scalars
+(total, max count, max degree) ever cross to the host. Executables are
+cached per (cap_a, cap_b, chunk) so degree-bucketed shapes never retrace,
+and the level-1 edge feed is double-buffered (chunk N+1 uploads while
+chunk N computes) — the S-Cache residency win, restated as "operands
+never leave HBM". The numpy ``compact`` (``np.nonzero``) is the oracle
+the kernel-level compaction tests check against; no runner uses it.
 
 Work is chunked so device buffers stay bounded; padded tail items carry
 bound=0 so they contribute nothing (branch-free masking, no special cases).
@@ -43,15 +39,16 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core.batch import (batch_compact_scan, batch_inter,
-                              batch_inter_count, compact_indices_scan)
+from repro.core.batch import (batch_inter, batch_inter_count,
+                              compact_indices_scan)
 from repro.obs import LegacyStatsView, Telemetry
 from repro.core.stream import LANE, SENTINEL, round_capacity
 from repro.graph.csr import CSRGraph, padded_rows, padded_value_rows
 from repro.kernels.ops import (xinter_compact, xinter_count, xlevel_agg,
-                               xlevel_compact, xlevel_count, xmark,
-                               xsub_compact, xsub_count)
+                               xlevel_compact, xlevel_count, xsub_compact,
+                               xsub_count)
 from repro.values import edge_value_lookup, prefix_scale
+from .forest import build_forest
 from .plan import LevelOp, WavePlan, clique_pattern, compile_pattern, pattern
 
 
@@ -217,10 +214,9 @@ def compact(rows: np.ndarray, counts: np.ndarray, limit: int | None = None,
             return_src: bool = False):
     """Host compaction oracle: expand (rows, counts) into the next Wave.
 
-    The device fast path (``WaveRunner`` via ``ops.xinter_compact``) is
-    property-tested to produce item-for-item identical waves; this np.nonzero
-    form stays as the semantic reference and the ``return_src`` provider for
-    embedding enumeration (``apps.triangle_list``).
+    The device compaction (``ops.xinter_compact``, which ``WaveRunner``
+    runs) is tested item for item against this np.nonzero form; it is also
+    the ``return_src`` provider of ``apps.triangle_list_host``.
 
     Every valid key rows[i, j] (j < counts[i]) becomes a work item whose
     prefix is rows[i] and whose extension vertex/bound is that key. The
@@ -319,8 +315,10 @@ class WaveRunner:
     """Stream-program interpreter: executes any compiled ``WavePlan`` on the
     device-resident wavefront pipeline.
 
-    ``run(plan)`` is the single generic entry point — the §IV-F translator's
-    software half. Each ``LevelOp`` lowers to one cached jitted executable
+    ``run_set(forest)`` is the interpreter — the §IV-F translator's
+    software half; ``run(plan)`` runs one plan as a one-plan forest
+    (``build_forest([plan])`` walks exactly ``plan.ops``, so it is the same
+    program). Each ``LevelOp`` lowers to one cached jitted executable
     that gathers the neighbor streams it references, AND-combines their
     membership marks (INTER) / complements (SUB) over the base stream, applies
     bound and injectivity masks, and either counts, materialises + compacts
@@ -346,32 +344,24 @@ class WaveRunner:
       compute;
     * **per-chunk device partial sums**: count levels reduce to one scalar
       per chunk on device (synced in a deferred batch at the end of
-      ``run``) — no count vectors ever cross to the host.
+      ``run_set``) — no count vectors ever cross to the host.
 
-    ``run_set(forest)`` generalises ``run`` to a ``mining.forest.PlanForest``
-    of several plans at once: one edge-feed pass per orientation, each
-    shared trie node's expand + compaction dispatched once per wave chunk
-    and fanned out to every child branch (children whose branch deferred
-    constraints into residuals first get a per-branch packed worklist, so
-    relaxation never inflates their downstream item count), with per-leaf
-    accumulators — results bit-identical to per-plan ``run`` calls.
+    A ``mining.forest.PlanForest`` holds one or several plans: one
+    edge-feed pass per orientation, each shared trie node's expand +
+    compaction dispatched once per wave chunk and fanned out to every child
+    branch (children whose branch deferred constraints into residuals first
+    get a per-branch packed worklist, so relaxation never inflates their
+    downstream item count), with per-leaf accumulators — results
+    bit-identical to per-plan ``run`` calls.
 
     General (multi-operand) levels — several INTER/SUB refs, or injectivity
     excludes — dispatch ONE fused k-operand kernel per executable call
     (``ops.xlevel_count`` / ``ops.xlevel_compact``: the refs are stacked
     into a (k, B, cap) operand, polarity INTER-first, window/excludes folded
-    in-kernel) instead of one ``xmark`` per reference; compaction everywhere
-    is the O(B·cap) segmented prefix-sum scatter (``batch_compact_scan``),
-    never a masked sort. ``fused_level=False`` keeps the per-ref mark
-    composition as the comparison fallback — counts are property-tested
-    bit-identical with the flag on and off, and the executable cache is
-    keyed on it (plus the per-ref capacity signature, so a k-operand level's
-    trace is reused across degree buckets exactly like the single-op ones).
-
-    ``device_compact=False`` routes every expand through the host
-    ``compact`` oracle (np.nonzero + re-upload) — the twin the fast path is
-    property-tested against. ``record=True`` captures each wave's live
-    (carry-or-prefix-columns, verts) into ``trace`` for those comparisons.
+    in-kernel); the executable cache is keyed on the per-ref capacity
+    signature, so a k-operand level's trace is reused across degree buckets
+    exactly like the single-op ones. Compaction everywhere is the O(B·cap)
+    segmented prefix-sum scatter, never a masked sort.
 
     Every executable is built in two halves: an unjitted *body*
     (``_count_body`` / ``_expand_body`` / ``_emit_body`` / ``_chunk_body`` /
@@ -400,13 +390,12 @@ class WaveRunner:
     # legacy ``stats`` keys, in their historical insertion order — each is
     # a registry counter the view derives from (see __init__)
     _STAT_KEYS = ("exec_hits", "exec_misses", "host_syncs",
-                  "device_compactions", "host_compactions", "items",
+                  "device_compactions", "items",
                   "level_kernel_dispatches", "count_rides")
 
     def __init__(self, g: CSRGraph, chunk: int | None = None,
-                 backend: str = "auto", device_compact: bool = True,
-                 record: bool = False, fused_level: bool = True,
-                 exec_cache=None, telemetry: Telemetry | None = None):
+                 backend: str = "auto", exec_cache=None,
+                 telemetry: Telemetry | None = None):
         self.g = g
         # chunk <= 2^15 is the exactness envelope of the (hi, lo) int32
         # per-chunk count partials (see _plan_count_fn): a 2^15-item chunk of
@@ -414,16 +403,12 @@ class WaveRunner:
         # it; explicit larger requests are clamped, never silently wrapped.
         self.chunk = min(chunk or choose_chunk(g.padded_max_degree), 1 << 15)
         self.backend = backend
-        self.device_compact = device_compact
-        self.record = record
-        self.fused_level = fused_level
         # session-lifetime executable cache (mining.session.ExecutableCache):
         # when provided, compiled executables outlive this runner — repeated
         # queries on one Miner retrace nothing. Keys are widened with the
-        # runner config (chunk / backend / flags) so runners with different
-        # shapes never collide; None keeps the private per-runner dict.
+        # runner config (chunk / backend) so runners with different shapes
+        # never collide; None keeps the private per-runner dict.
         self._exec_cache = exec_cache
-        self.trace: list[tuple[int, np.ndarray, np.ndarray]] = []
         self._exec: dict[tuple, Callable] = {}
         # telemetry substrate (repro.obs): the metrics registry is the
         # single source of truth for every counter, and ``self.stats`` is
@@ -451,33 +436,23 @@ class WaveRunner:
         # independent-plan path dispatches it once per pattern.
         self.level_execs: dict[tuple[str, int], int] = {}
 
-    def _level_dispatches(self, op: LevelOp, host: bool = False) -> int:
+    @staticmethod
+    def _level_dispatches(op: LevelOp) -> int:
         """Membership-kernel dispatches one executable call issues for
-        ``op`` — the per-operand DMA metric the fused level path collapses:
-        a general level costs one dispatch per INTER/SUB ref on the per-ref
-        fallback (and always on the host-oracle mark composition), exactly
-        one with ``fused_level``; window-only levels need none."""
-        k = len(op.inter) + len(op.sub)
-        if host:
-            return k
-        if self._fused_shape(op) is not None:
-            return 1
-        if k == 0:
-            return 0
-        return 1 if self.fused_level else k
+        ``op``: one fused kernel covers every INTER/SUB ref of a level, and
+        window-only levels need none."""
+        return 1 if op.inter or op.sub else 0
 
-    def _bump(self, op: LevelOp, host: bool = False) -> None:
+    def _bump(self, op: LevelOp) -> None:
         key = (op.kind, op.level)
         self.level_execs[key] = self.level_execs.get(key, 0) + 1
-        self._ct["level_kernel_dispatches"].inc(
-            self._level_dispatches(op, host))
+        self._ct["level_kernel_dispatches"].inc(self._level_dispatches(op))
 
     # ------------------------------------------------------------------ cache
     def _executable(self, key: tuple, build: Callable) -> Callable:
         key = self._exec_prefix + key
         if self._exec_cache is not None:
-            key = (self.chunk, self.backend, self.device_compact,
-                   self.fused_level) + key
+            key = (self.chunk, self.backend) + key
             fn, fresh = self._exec_cache.get_or_build(key, build)
             self._exec_fresh = fresh
             self._ct["exec_misses" if fresh else "exec_hits"].inc()
@@ -494,14 +469,14 @@ class WaveRunner:
 
     # -------------------------------------------------------- traced dispatch
     def _dispatch(self, op: LevelOp, fn: Callable, args: tuple,
-                  items=None, caps_sig: tuple = (), host: bool = False):
+                  items=None, caps_sig: tuple = ()):
         """Run one level executable inside a ``dispatch`` span (op
         kind/level, wavefront items, capacity signature, exec-cache
         hit/miss). The span times the host's enqueue — trace and compile
         included when the executable is fresh — and never waits for the
         device: device time is the profiler's to report."""
         attrs = {"kind": op.kind, "level": op.level,
-                 "dispatches": self._level_dispatches(op, host),
+                 "dispatches": self._level_dispatches(op),
                  "exec_cached": not self._exec_fresh}
         if op.agg is not None:
             attrs["agg"] = op.agg
@@ -509,8 +484,6 @@ class WaveRunner:
             attrs["items"] = lambda: int(np.asarray(items).sum())
         if caps_sig:
             attrs["caps"] = lambda: str(tuple(caps_sig))
-        if host:
-            attrs["host"] = True
         with self.telemetry.tracer.span("dispatch", cat="dispatch", **attrs):
             return fn(*args)
 
@@ -524,14 +497,6 @@ class WaveRunner:
     def _sync_span(self, site: str):
         """Span around one blocking device->host read at ``site``."""
         return self.telemetry.tracer.span("sync", cat="sync", site=site)
-
-    def _rows_fn(self, cap: int):
-        def build():
-            @jax.jit
-            def fn(g, vs):
-                return padded_rows(g, vs, cap)[0]
-            return fn
-        return self._executable(("rows", cap), build)
 
     # ------------------------------------------------------------------ feeds
     @staticmethod
@@ -608,8 +573,8 @@ class WaveRunner:
 
         Lower bounds ride the kernels' lbounds operand (whole-tile skipping,
         like the R3 upper bound); residuals and the live mask fold into the
-        per-row bound (bound 0 = dead row). Only per-element injectivity
-        (``exclude``) still needs the general mark composition."""
+        per-row bound (bound 0 = dead row). Per-element injectivity
+        (``exclude``) and several refs take the k-operand kernel."""
         if op.exclude:
             return None
         if len(op.inter) == 1 and not op.sub:
@@ -617,38 +582,6 @@ class WaveRunner:
         if len(op.sub) == 1 and not op.inter:
             return "sub"
         return None
-
-    def _mask_ops(self, op: LevelOp, caps: dict):
-        """Traced general path: AND-combine one membership mark per INTER/SUB
-        reference plus bound / injectivity masks — the multi-µop level."""
-        backend = self.backend
-
-        def keep_of(g, base, get, n):
-            keep = base != SENTINEL
-            for j in op.inter:
-                nbr, _ = padded_rows(g, get[j], caps[j])
-                keep = keep & xmark(base, nbr, backend=backend)
-            for j in op.sub:
-                nbr, _ = padded_rows(g, get[j], caps[j])
-                keep = keep & ~xmark(base, nbr, backend=backend)
-            if op.ub:
-                ub = get[op.ub[0]]
-                for u in op.ub[1:]:
-                    ub = jnp.minimum(ub, get[u])
-                keep = keep & (base < ub[:, None])
-            if op.lb:
-                lb = get[op.lb[0]]
-                for w in op.lb[1:]:
-                    lb = jnp.maximum(lb, get[w])
-                keep = keep & (base > lb[:, None])
-            for e in op.exclude:
-                keep = keep & (base != get[e][:, None])
-            for kind, i, j in op.residual:
-                ok = (get[i] < get[j]) if kind == "lt" else (get[i] != get[j])
-                keep = keep & ok[:, None]
-            live = jnp.arange(base.shape[0], dtype=jnp.int32) < n
-            return keep & live[:, None]
-        return keep_of
 
     @staticmethod
     def _stack_refs(g, get, caps: dict, refs: tuple[int, ...]):
@@ -731,8 +664,7 @@ class WaveRunner:
         def build():
             return self._jit_count(
                 op, self._count_body(op, caps_sig, cap_base))
-        return self._executable(
-            ("pcount", op, caps_sig, cap_base, self.fused_level), build)
+        return self._executable(("pcount", op, caps_sig, cap_base), build)
 
     def _count_body(self, op: LevelOp, caps_sig: tuple, cap_base: int):
         """Unjitted count-level body (see the two-halves note in the class
@@ -741,33 +673,26 @@ class WaveRunner:
         in_cols = self._in_cols(op)
         caps = dict(caps_sig)
         fused = self._fused_shape(op)
-        keep_of = self._mask_ops(op, caps)
         refs = op.inter + op.sub
         pol = (1,) * len(op.inter) + (0,) * len(op.sub)
-        use_xlevel = fused is None and self.fused_level
 
         def fn(g, vals, carry, n):
             get = dict(zip(in_cols, vals))
             base = carry if op.use_carry else \
                 padded_rows(g, get[op.base], caps[op.base])[0]
+            ub = self._ub_vec(op, get, n, base.shape[0])
+            lb = self._max_lb(op, get) if op.lb else None
             if fused:
-                ub = self._ub_vec(op, get, n, base.shape[0])
-                lb = self._max_lb(op, get) if op.lb else None
                 ref = op.inter[0] if fused == "inter" else op.sub[0]
                 nbr, _ = padded_rows(g, get[ref], caps[ref])
                 cfun = xinter_count if fused == "inter" else xsub_count
                 counts = cfun(base, nbr, ub, backend=backend, lbounds=lb)
-            elif use_xlevel:
-                ub = self._ub_vec(op, get, n, base.shape[0])
-                lb = self._max_lb(op, get) if op.lb else None
+            else:
                 bs = self._stack_refs(g, get, caps, refs) if refs \
                     else None
                 counts = xlevel_count(base, bs, pol, ub, backend=backend,
                                       lbounds=lb,
                                       excludes=self._excl_vals(op, get))
-            else:
-                counts = jnp.sum(keep_of(g, base, get, n), axis=1,
-                                 dtype=jnp.int32)
             if op.tail is not None:
                 col, c = op.tail
                 counts = counts * (g.degrees[get[col]].astype(jnp.int32)
@@ -782,8 +707,7 @@ class WaveRunner:
         leaf (``xlevel_agg`` shares ``xlevel_count``'s tile schedule)."""
         def build():
             return self._jit_agg(op, self._agg_body(op, caps_sig, cap_base))
-        return self._executable(
-            ("pagg", op, caps_sig, cap_base, self.fused_level), build)
+        return self._executable(("pagg", op, caps_sig, cap_base), build)
 
     def _agg_body(self, op: LevelOp, caps_sig: tuple, cap_base: int):
         """Unjitted aggregate-leaf body; ``_jit_agg`` wraps it for dispatch.
@@ -879,41 +803,28 @@ class WaveRunner:
         ``xlevel_compact`` dispatch. In both, the per-row bound vector
         (``_ub_vec``) folds the declared upper bounds, the live mask and any
         forest residuals into the bound operand (bound 0 kills dead rows
-        inside the kernel) and lower bounds ride ``lbounds``. The
-        ``fused_level=False`` fallback composes one mark per ref; every
-        path's epilogue is the O(B·cap) ``batch_compact_scan`` prefix-sum
-        scatter (no masked sort anywhere).
+        inside the kernel) and lower bounds ride ``lbounds``. The epilogue
+        is the O(B·cap) prefix-sum scatter (no masked sort anywhere).
         """
         backend = self.backend
         fused = self._fused_shape(op)
-        keep_of = self._mask_ops(op, caps)
         refs = op.inter + op.sub
         pol = (1,) * len(op.inter) + (0,) * len(op.sub)
-        use_xlevel = fused is None and self.fused_level
 
         def core(g, get, base, n):
+            ub = self._ub_vec(op, get, n, base.shape[0])
+            lb = self._max_lb(op, get) if op.lb else None
             if fused:
-                ub = self._ub_vec(op, get, n, base.shape[0])
-                lb = self._max_lb(op, get) if op.lb else None
                 ref = op.inter[0] if fused == "inter" else op.sub[0]
                 nbr, _ = padded_rows(g, get[ref], caps[ref])
                 cfun = xinter_compact if fused == "inter" else xsub_compact
-                rows2, counts, src, verts, total, maxc = cfun(
-                    base, nbr, ub, out_cap=out_cap, out_items=out_items,
-                    backend=backend, lbounds=lb)
-            elif use_xlevel:
-                ub = self._ub_vec(op, get, n, base.shape[0])
-                lb = self._max_lb(op, get) if op.lb else None
-                bs = self._stack_refs(g, get, caps, refs) if refs else None
-                rows2, counts, src, verts, total, maxc = xlevel_compact(
-                    base, bs, pol, ub, out_cap=out_cap, out_items=out_items,
-                    backend=backend, lbounds=lb,
-                    excludes=self._excl_vals(op, get))
-            else:
-                keep = keep_of(g, base, get, n)
-                rows2, counts, src, verts, total, maxc = batch_compact_scan(
-                    base, keep, out_cap, out_items)
-            return rows2, counts, src, verts, total, maxc
+                return cfun(base, nbr, ub, out_cap=out_cap,
+                            out_items=out_items, backend=backend, lbounds=lb)
+            bs = self._stack_refs(g, get, caps, refs) if refs else None
+            return xlevel_compact(
+                base, bs, pol, ub, out_cap=out_cap, out_items=out_items,
+                backend=backend, lbounds=lb,
+                excludes=self._excl_vals(op, get))
         return core
 
     def _plan_expand_fn(self, op: LevelOp, caps_sig: tuple, cap_base: int,
@@ -934,7 +845,7 @@ class WaveRunner:
                                       out_items, want_count), want_count)
         return self._executable(
             ("pexpand", op, caps_sig, cap_base, out_cap, out_items,
-             self.fused_level, want_count), build)
+             want_count), build)
 
     def _expand_body(self, op: LevelOp, caps_sig: tuple, cap_base: int,
                      out_cap: int, out_items: int, want_count: bool):
@@ -962,27 +873,6 @@ class WaveRunner:
             return rows2, src, verts, jnp.stack(metas)
         return fn
 
-    def _plan_expand_host_fn(self, op: LevelOp, caps_sig: tuple,
-                             cap_base: int, out_cap: int):
-        """Oracle-path twin: masks + materialise only; compaction on host."""
-        in_cols = self._in_cols(op)
-        caps = dict(caps_sig)
-        keep_of = self._mask_ops(op, caps)
-
-        def build():
-            @jax.jit
-            def fn(g, vals, carry, n):
-                get = dict(zip(in_cols, vals))
-                base = carry if op.use_carry else \
-                    padded_rows(g, get[op.base], caps[op.base])[0]
-                keep = keep_of(g, base, get, n)
-                masked = jnp.where(keep, base, SENTINEL)
-                rows2 = jnp.sort(masked, axis=1)[:, :out_cap]
-                return rows2, jnp.sum(keep, axis=1, dtype=jnp.int32)
-            return fn
-        return self._executable(
-            ("pexpandh", op, caps_sig, cap_base, out_cap), build)
-
     def _plan_emit_fn(self, op: LevelOp, caps_sig: tuple, cap_base: int,
                       out_cap: int, out_items: int):
         """Terminal emit level: compacted embeddings stay device-side until
@@ -992,8 +882,7 @@ class WaveRunner:
                 op, self._emit_body(op, caps_sig, cap_base, out_cap,
                                     out_items))
         return self._executable(
-            ("pemit", op, caps_sig, cap_base, out_cap, out_items,
-             self.fused_level), build)
+            ("pemit", op, caps_sig, cap_base, out_cap, out_items), build)
 
     def _emit_body(self, op: LevelOp, caps_sig: tuple, cap_base: int,
                    out_cap: int, out_items: int):
@@ -1042,20 +931,6 @@ class WaveRunner:
         return fn
 
     # ------------------------------------------------------- the interpreter
-    def _record(self, level: int, rows, verts, n: int) -> None:
-        if self.record:
-            self.trace.append((level, np.asarray(rows)[:n].copy(),
-                               np.asarray(verts)[:n].copy()))
-
-    @staticmethod
-    def _wave_repr(cols2: dict, out_cols, carry2, vch):
-        """Trace representative for a wave chunk (device/host comparable)."""
-        if carry2 is not None:
-            return carry2
-        if out_cols:
-            return np.stack([np.asarray(cols2[c]) for c in out_cols], axis=1)
-        return vch
-
     def _finalize(self, plan: WavePlan, parts: list):
         """Reduce one plan's accumulated chunk outputs to its result."""
         if plan.ops[-1].kind == "emit":
@@ -1095,31 +970,13 @@ class WaveRunner:
         return total
 
     def run(self, plan: WavePlan):
-        """Execute a compiled ``WavePlan``.
+        """Execute one compiled ``WavePlan`` as a one-plan forest.
 
         Counting plans return a Python int (divided by ``plan.div``); emit
         plans return the (N, k) int32 embedding matrix in matching order.
+        ``Miner`` caches the forest beside the plan and calls ``run_set``.
         """
-        op0 = plan.ops[0]
-        outs: list = []
-        tr = self.telemetry.tracer
-        with tr.span("execute", plan=plan.pattern.name):
-            for cap0, dv0, dv1, v1h, n in self._edge_feed(plan.symmetric):
-                self._ct_feed_chunks.inc()
-                with tr.span("feed", cat="level", cap=cap0,
-                             items=lambda: int(np.asarray(n).sum())):
-                    caps = {0: cap0}
-                    if 1 in op0.row_refs():
-                        caps[1] = _neighbor_cap(self.g, self._live(v1h, n))
-                        self._count_feed_fill(caps[1], v1h, n)
-                    if self.record:
-                        self._record(1, self._rows_fn(cap0)(self.g, dv0),
-                                     dv1, n)
-                    outs += self._plan_descend(plan, 0, {0: dv0, 1: dv1},
-                                               caps, None, n)
-            self._ct["host_syncs"].inc(len(outs))
-            with tr.span("finalize"):
-                return self._finalize(plan, outs)
+        return self.run_set(build_forest([plan]))[0]
 
     def run_set(self, forest):
         """Execute a ``mining.forest.PlanForest``: each feed orientation is
@@ -1149,9 +1006,6 @@ class WaveRunner:
                         if need1:
                             caps[1] = _neighbor_cap(self.g, self._live(v1h, n))
                             self._count_feed_fill(caps[1], v1h, n)
-                        if self.record:
-                            self._record(1, self._rows_fn(cap0)(self.g, dv0),
-                                         dv1, n)
                         for root in roots:
                             self._forest_descend(root, {0: dv0, 1: dv1},
                                                  caps, None, n, acc)
@@ -1164,10 +1018,10 @@ class WaveRunner:
                         acc: list) -> None:
         """Execute one forest node on a wave chunk; fan out over children.
 
-        Identical per-op machinery to ``_plan_descend`` — same cached
-        executables, same compaction — except an expand's chunk loop feeds
-        *every* child branch instead of a single successor op, and terminal
-        nodes append their partials to each owning plan's accumulator."""
+        A count leaf dispatches its executable and appends the partial to
+        each owning plan's accumulator; an emit leaf appends its embedding
+        block; an expand runs once and its compacted worklist's chunks feed
+        *every* child branch."""
         op = node.op
         caps_sig = tuple(sorted((c, caps[c]) for c in op.row_refs()))
         cap_base = int(carry.shape[1]) if op.use_carry else caps[op.base]
@@ -1192,35 +1046,12 @@ class WaveRunner:
             out_items = -(-b * out_cap // self.chunk) * self.chunk
             if op.kind == "emit":
                 parts = self._plan_emit(op, caps_sig, cap_base, out_cap,
-                                        out_items, cols, vals, carry_in, n)
+                                        out_items, vals, carry_in, n)
                 for i in node.plans:
                     acc[i].extend(parts)
                 return
             if node.ride_plans:
                 self._ct["count_rides"].inc(len(node.ride_plans))
-            if not self.device_compact:
-                ride_out: dict = {}
-                chunks = self._expand_chunks_host(op, caps_sig, cap_base,
-                                                  out_cap, cols, vals,
-                                                  carry_in, n,
-                                                  ride_out=ride_out)
-                for cols2, caps2, carry2, vch, m in chunks:
-                    self._record(op.level + 1,
-                                 self._wave_repr(cols2, op.out_cols, carry2,
-                                                 vch),
-                                 vch, m)
-                    for child in node.children:
-                        self._forest_descend(child, cols2, caps2, carry2, m,
-                                             acc)
-                part = ride_out.get("count_part")
-                if part is not None:
-                    for i in node.ride_plans:
-                        acc[i].append(part)
-                    # host-resident partials: no sync at finalize (see above).
-                    # Counter.dec raises on underflow — the ride credit can
-                    # never exceed syncs actually paid (invariant, tested).
-                    self._ct["host_syncs"].dec(len(node.ride_plans))
-                return
             exp = self._expand_device(op, caps_sig, cap_base, out_cap,
                                       out_items, vals, carry_in, n,
                                       want_count=bool(node.ride_plans))
@@ -1258,86 +1089,24 @@ class WaveRunner:
                 if has_b:
                     feeds.append(([ch], src_b, verts_b, tot_b))
             for children, s, v, t in feeds:
-                for cols2, carry2, vch, m in self._expand_chunks(
+                for cols2, carry2, m in self._expand_chunks(
                         op, b, out_cap, cap2, rows2, s, v, cols, t):
-                    self._record(op.level + 1,
-                                 self._wave_repr(cols2, op.out_cols, carry2,
-                                                 vch),
-                                 vch, m)
                     for child in children:
                         self._forest_descend(child, cols2, caps2, carry2, m,
                                              acc)
 
-    def _plan_descend(self, plan: WavePlan, oi: int, cols: dict, caps: dict,
-                      carry, n: int) -> list:
-        """Execute plan.ops[oi] on one wave chunk; recurse over survivors."""
-        op = plan.ops[oi]
-        caps_sig = tuple(sorted((c, caps[c]) for c in op.row_refs()))
-        cap_base = int(carry.shape[1]) if op.use_carry else caps[op.base]
-        vals = tuple(cols[c] for c in self._in_cols(op))
-        carry_in = carry if op.use_carry else np.int32(0)
-        with self._level_span(op, n):
-            if op.kind == "count":
-                self._bump(op)
-                if op.agg is not None:
-                    self._ct_value_lanes.inc()
-                    fn = self._plan_agg_fn(op, caps_sig, cap_base)
-                else:
-                    fn = self._plan_count_fn(op, caps_sig, cap_base)
-                return [self._dispatch(op, fn, (self.g, vals, carry_in, n),
-                                       items=n, caps_sig=caps_sig)]
-            b = (int(carry.shape[0]) if op.use_carry
-                 else int(cols[op.base].shape[0])) // self._shards
-            out_cap = min([cap_base] + [caps[j] for j in op.inter])
-            out_items = -(-b * out_cap // self.chunk) * self.chunk
-            if op.kind == "emit":
-                return self._plan_emit(op, caps_sig, cap_base, out_cap,
-                                       out_items, cols, vals, carry_in, n)
-            nxt = plan.ops[oi + 1]
-            if self.device_compact:
-                chunks = self._expand_chunks_device(op, caps_sig, cap_base,
-                                                    out_cap, out_items, b,
-                                                    cols, vals, carry_in, n)
-            else:
-                chunks = self._expand_chunks_host(op, caps_sig, cap_base,
-                                                  out_cap, cols, vals,
-                                                  carry_in, n)
-            parts: list = []
-            for cols2, caps2, carry2, vch, m in chunks:
-                self._record(nxt.level,
-                             self._wave_repr(cols2, op.out_cols, carry2, vch),
-                             vch, m)
-                parts += self._plan_descend(plan, oi + 1, cols2, caps2,
-                                            carry2, m)
-            return parts
-
-    def _plan_emit(self, op, caps_sig, cap_base, out_cap, out_items, cols,
-                   vals, carry_in, n) -> list:
-        self._bump(op, host=not self.device_compact)
-        if self.device_compact:
-            fn = self._plan_emit_fn(op, caps_sig, cap_base, out_cap,
-                                    out_items)
-            emb, total = self._dispatch(op, fn, (self.g, vals, carry_in, n),
-                                        items=n, caps_sig=caps_sig)
-            with self._sync_span("emit"):
-                total = int(total)
-                emb = np.asarray(emb)[:total] if total else None
-            self._ct["device_compactions"].inc()
-            self._ct["items"].inc(total)
-            return [emb] if total else []
-        hfn = self._plan_expand_host_fn(op, caps_sig, cap_base, out_cap)
-        rows2, counts2 = self._dispatch(op, hfn, (self.g, vals, carry_in, n),
-                                        items=n, caps_sig=caps_sig, host=True)
-        with self._sync_span("host_compact"):
-            wave, ii = compact(np.asarray(rows2), np.asarray(counts2),
-                               return_src=True)
-        self._ct["host_compactions"].inc()
-        if wave is None:
-            return []
-        self._ct["items"].inc(len(wave))
-        cols_out = [wave.verts if c == op.level else np.asarray(cols[c])[ii]
-                    for c in op.out_cols]
-        return [np.stack(cols_out, axis=1)]
+    def _plan_emit(self, op, caps_sig, cap_base, out_cap, out_items, vals,
+                   carry_in, n) -> list:
+        self._bump(op)
+        fn = self._plan_emit_fn(op, caps_sig, cap_base, out_cap, out_items)
+        emb, total = self._dispatch(op, fn, (self.g, vals, carry_in, n),
+                                    items=n, caps_sig=caps_sig)
+        with self._sync_span("emit"):
+            total = int(total)
+            emb = np.asarray(emb)[:total] if total else None
+        self._ct["device_compactions"].inc()
+        self._ct["items"].inc(total)
+        return [emb] if total else []
 
     def _expand_device(self, op, caps_sig, cap_base, out_cap, out_items,
                        vals, carry_in, n, want_count: bool = False):
@@ -1370,7 +1139,7 @@ class WaveRunner:
     def _expand_chunks(self, op, b, out_cap, cap2, rows2, src, verts2, cols,
                        total):
         """Slice a compacted (src, verts) worklist into next-level device
-        chunks; yields (cols2, carry2, vch, m)."""
+        chunks; yields (cols2, carry2, m)."""
         cfn = self._plan_chunk_fn(op, b, out_cap, cap2, self.chunk)
         fwdvals = tuple(cols[c] for c in op.out_cols if c < op.level)
         for lo in range(0, total, self.chunk):
@@ -1383,21 +1152,7 @@ class WaveRunner:
             cols2 = dict(zip([c for c in op.out_cols if c < op.level], outs))
             if op.level in op.out_cols:
                 cols2[op.level] = vch
-            yield cols2, carry2, vch, m
-
-    def _expand_chunks_device(self, op, caps_sig, cap_base, out_cap,
-                              out_items, b, cols, vals, carry_in, n):
-        """Run one expand level on device; yield the next wave's chunks as
-        (cols2, caps2, carry2, vch, m). Shared by the single-plan descent and
-        the forest fan-out (one expand feeding k child levels)."""
-        exp = self._expand_device(op, caps_sig, cap_base, out_cap, out_items,
-                                  vals, carry_in, n)
-        if exp is None:
-            return
-        rows2, src, verts2, total, caps2, cap2, _ = exp
-        for cols2, carry2, vch, m in self._expand_chunks(
-                op, b, out_cap, cap2, rows2, src, verts2, cols, total):
-            yield cols2, caps2, carry2, vch, m
+            yield cols2, carry2, m
 
     def _residual_pack_fn(self, level: int, residual: tuple, out_items: int):
         """Per-branch worklist pack: drop items failing a child branch's
@@ -1434,50 +1189,6 @@ class WaveRunner:
             return src[order], \
                 jnp.where(live, verts[order], 0).astype(jnp.int32), tot
         return fn
-
-    def _expand_chunks_host(self, op, caps_sig, cap_base, out_cap, cols,
-                            vals, carry_in, n, ride_out: dict | None = None):
-        """Oracle twin of ``_expand_chunks_device``: same masks, np.nonzero
-        compaction + re-upload; same (cols2, caps2, carry2, vch, m) yield.
-        ``ride_out`` (forest count-rides) receives the survivor-count sum as
-        an (hi, lo) int32 partial under ``"count_part"``."""
-        self._bump(op, host=True)
-        hfn = self._plan_expand_host_fn(op, caps_sig, cap_base, out_cap)
-        rows2, counts2 = self._dispatch(op, hfn, (self.g, vals, carry_in, n),
-                                        items=n, caps_sig=caps_sig, host=True)
-        # a generator: the span closes before the first yield
-        with self._sync_span("host_compact"):
-            if ride_out is not None:
-                t = int(np.asarray(counts2, dtype=np.int64).sum())
-                ride_out["count_part"] = np.asarray([t >> 16, t & 0xFFFF],
-                                                    dtype=np.int32)
-            wave, ii = compact(np.asarray(rows2), np.asarray(counts2),
-                               return_src=True)
-            if wave is not None:
-                fwd = [c for c in op.out_cols if c < op.level]
-                hostcols = {c: np.asarray(cols[c])[ii] for c in fwd}
-        self._ct["host_syncs"].inc()
-        self._ct["host_compactions"].inc()
-        if wave is None:
-            return
-        total = len(wave)
-        self._ct["items"].inc(total)
-        caps2 = {c: _neighbor_cap(self.g, wave.verts if c == op.level
-                                  else hostcols[c])
-                 for c in op.gather_refs}
-        for lo in range(0, total, self.chunk):
-            m = min(self.chunk, total - lo)
-            sl = slice(lo, lo + self.chunk)
-            cols2 = {c: jnp.asarray(_pad_to(hostcols[c][sl], self.chunk, 0))
-                     for c in fwd}
-            vch = jnp.asarray(_pad_to(wave.verts[sl], self.chunk, 0))
-            if op.level in op.out_cols:
-                cols2[op.level] = vch
-            carry2 = None
-            if op.carry_out:
-                carry2 = jnp.asarray(
-                    _pad_to(wave.rows[sl], self.chunk, SENTINEL))
-            yield cols2, caps2, carry2, vch, m
 
     # ----------------------------------------------- plan wrappers (compat)
     def count_edges(self, symmetric: bool = True, bounded: bool = True) -> int:

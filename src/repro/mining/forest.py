@@ -81,14 +81,16 @@ Trie interpretation contract (``WaveRunner.run_set``)
   ``gather_refs`` are the union of its subtree's value/row references (so
   residual columns are forwarded), and ``carry_out`` is the OR over children
   — non-carrying children simply ignore the carry;
-* every node is executed through the same cached executables as the
-  single-plan path (``LevelOp`` hashes by value, residuals included), so a
-  forest node and an identical single-plan level share compiled traces;
+* a single plan runs as a one-plan forest (``WaveRunner.run``): the
+  merge of one plan walks exactly ``plan.ops``, and ``LevelOp`` hashes by
+  value (residuals included), so a forest node and an identical level of
+  another forest share compiled traces;
 * each expand node runs its gather + masks + on-device compaction once per
   wave chunk and feeds the resulting (cols2, caps2, carry2) to every child;
 * leaf partials — (hi, lo) int32 count pairs or embedding blocks — are
-  appended to per-plan accumulators and finalised per plan (division by
-  ``Pattern.div``, emit concatenation) exactly as ``run`` does.
+  appended to per-plan accumulators and finalised per plan
+  (``WaveRunner._finalize``: division by ``Pattern.div``, emit
+  concatenation).
 """
 from __future__ import annotations
 

@@ -3,7 +3,7 @@
 IntersectX's core claim is that stream state — the SMT, the S-Cache, the
 cached stream registers — persists *across* intersections, so repeated
 queries over one graph amortise all data movement. The one-shot entry
-points this repo grew up with (``WaveRunner.run(plan)``, ``run_set``, the
+points this repo grew up with (``WaveRunner.run``/``run_set``, the
 per-app wrappers in ``mining.apps``) re-stage the graph and re-derive
 every schedule per call. A ``Miner`` is the session that owns a graph for
 its lifetime and serves any number of queries against it:
@@ -19,7 +19,9 @@ memoised for the session's lifetime:
 
 **compile** — a query (a name from ``plan._NAMED_QUERIES``, a ``Motif``
 shape, or an explicit ``Pattern``) lowers to a ``WavePlan`` via
-``plan.compile_pattern``. Plans are cached per (query, emit) pair.
+``plan.compile_pattern``. Plans are cached per (query, emit, aggregate),
+each beside its one-plan ``PlanForest`` — the program a single query
+runs — so a repeated query builds nothing on the host.
 
 **schedule** — for batches, the automatic matching-order search
 (``forest.schedule_patterns``) picks each ``Motif``'s matching order to
@@ -28,7 +30,7 @@ queries are fixed points), then ``forest.build_forest`` merges the
 compiled plans into a ``PlanForest``. Forests are cached on the batch's
 canonical plan keys, so a repeated batch re-derives nothing.
 
-**execute** — the ``WaveRunner`` machinery interprets the plan/forest,
+**execute** — ``WaveRunner.run_set`` interprets the forest,
 with two session-level residency guarantees: the graph's CSR buffers are
 staged to device ONCE at construction (``jax.device_put``), and every
 jitted executable lives in the session's ``ExecutableCache``, so repeated
@@ -43,7 +45,7 @@ This section is THE definition of the executable-cache key — every other
 docstring (``MinerConfig``, ``ExecutableCache``, ``mining.shard``) points
 here instead of restating it. ``ExecutableCache`` keys are::
 
-    (mesh/shape signature) + (chunk, backend, device_compact, fused_level)
+    (mesh/shape signature) + (chunk, backend)
         + (kind, LevelOp, capacity signature, ...)
 
 segment by segment:
@@ -56,11 +58,11 @@ segment by segment:
   ``("mesh", axis, shards)`` so sharded and unsharded traces can never
   collide.
 * **runner config** — the ``MinerConfig`` execution knobs that change
-  compiled shapes or kernel paths: ``chunk``, ``backend``,
-  ``device_compact``, ``fused_level``. ``mesh``/``mesh_axis``/
-  ``feed_partition`` enter through the mesh segment and the feed
-  partitioner instead; ``telemetry`` is deliberately NOT part of any key
-  (tracing must never force a retrace — gated in ci_gate ``--telemetry``).
+  compiled shapes or kernel paths: ``chunk``, ``backend``.
+  ``mesh``/``mesh_axis``/``feed_partition`` enter through the mesh segment
+  and the feed partitioner instead; ``telemetry`` is deliberately NOT part
+  of any key (tracing must never force a retrace — gated in ci_gate
+  ``--telemetry``).
 * **per-executable key** — the runner's trailing segment:
   ``(kind, LevelOp, capacity signature, ...)``. LevelOps hash by value,
   so structurally equal levels of different patterns share one trace.
@@ -117,13 +119,12 @@ Every session carries a ``repro.obs.Telemetry``: ``miner.telemetry``.
   ``compile``/``schedule``/``execute`` → ``feed_bucket`` (host bucketing
   of a feed pass), per-``feed`` and per-level ``L{l}:{kind}`` spans →
   ``dispatch`` spans (op kind, items, capacities, exec-cache hit/miss)
-  and ``sync`` spans (attribute ``site``: ``meta``, ``pack``, ``emit``,
-  ``host_compact``) around each blocking device→host read. A
-  ``dispatch`` span times the host's enqueue, not the device: tracing
-  never synchronises, so on or off the same work runs. Export with
-  ``telemetry.write_trace(path)`` (Chrome-trace JSON — chrome://tracing /
-  ui.perfetto.dev) or aggregate with ``telemetry.snapshot()`` /
-  ``tracer.level_seconds()``.
+  and ``sync`` spans (attribute ``site``: ``meta``, ``pack``, ``emit``)
+  around each blocking device→host read. A ``dispatch`` span times the
+  host's enqueue, not the device: tracing never synchronises, so on or
+  off the same work runs. Export with ``telemetry.write_trace(path)``
+  (Chrome-trace JSON — chrome://tracing / ui.perfetto.dev) or aggregate
+  with ``telemetry.snapshot()`` / ``tracer.level_seconds()``.
 * **jax profiler** — ``with miner.telemetry.jax_profile(logdir): ...``
   wraps a query in ``jax.profiler`` start/stop for an XLA-level trace.
 
@@ -238,8 +239,6 @@ class MinerConfig:
 
     chunk: int | None = None          # wave chunk; None = auto-sized
     backend: str = "auto"             # kernel backend (pallas/xla/auto)
-    device_compact: bool = True       # False: host np.nonzero oracle path
-    fused_level: bool = True          # k-operand fused level kernels
     mesh: int | None = None           # >1: shard over that many devices
     mesh_axis: str = "mine"           # mesh axis name (cache-key relevant)
     feed_partition: str = "round_robin"  # edge-feed dealing (shard.py)
@@ -303,9 +302,7 @@ class Miner:
             self._runner = ShardedWaveRunner(
                 graph, self.mesh, axis=config.mesh_axis,
                 feed_partition=config.feed_partition, chunk=config.chunk,
-                backend=config.backend,
-                device_compact=config.device_compact,
-                fused_level=config.fused_level, exec_cache=self.exec_cache,
+                backend=config.backend, exec_cache=self.exec_cache,
                 telemetry=self.telemetry)
             # the runner replicated the CSR buffers across the mesh
             self.graph: CSRGraph = self._runner.g
@@ -317,10 +314,9 @@ class Miner:
             self.exec_cache = ExecutableCache()
             self._runner = WaveRunner(
                 self.graph, chunk=config.chunk, backend=config.backend,
-                device_compact=config.device_compact,
-                fused_level=config.fused_level, exec_cache=self.exec_cache,
-                telemetry=self.telemetry)
-        self._plans: dict[tuple, WavePlan] = {}
+                exec_cache=self.exec_cache, telemetry=self.telemetry)
+        # (query, emit, aggregate) -> (plan, its one-plan forest)
+        self._plans: dict[tuple, tuple[WavePlan, PlanForest]] = {}
         self._seq = itertools.count()
         self._forests: dict[tuple, PlanForest] = {}
         self.metrics = self.telemetry.metrics
@@ -338,20 +334,33 @@ class Miner:
         paper patterns keep their declared matching order. ``aggregate``
         compiles the weighted (SVPU value) program — see the module
         docstring's "Value streams" section."""
+        return self._compiled(query, emit, aggregate)[0]
+
+    def _compiled(self, query, emit: bool = False,
+                  aggregate: str | None = None):
+        """(plan, one-plan forest) of one query: ``compile``'s cache entry,
+        the forest being what ``run_set`` executes for a single query."""
         tr = self.telemetry.tracer
         with tr.span("compile", query=str(query), emit=emit):
             resolved = resolve_query(query)
             key = (resolved, emit, aggregate)
-            plan = self._plans.get(key)
-            if plan is not None:
+            hit = self._plans.get(key)
+            if hit is not None:
                 self._sct["plan_hits"].inc()
-                return plan
+                return hit
             self._sct["plan_misses"].inc()
             if isinstance(resolved, Motif):
                 resolved = schedule_patterns([resolved])[0]
             plan = compile_pattern(resolved, emit=emit, aggregate=aggregate)
-            self._plans[key] = plan
-            return plan
+            hit = self._plans[key] = (plan, build_forest([plan]))
+            return hit
+
+    def _run_one(self, query, emit: bool = False,
+                 aggregate: str | None = None):
+        """Execute one query: its cached one-plan forest through
+        ``run_set``."""
+        return self._runner.run_set(
+            self._compiled(query, emit, aggregate)[1])[0]
 
     # ----------------------------------------------------------- schedule
     def schedule(self, queries: Sequence, emit: bool = False,
@@ -381,7 +390,9 @@ class Miner:
             plans = []
             for r, p in zip(resolved, pats):
                 plan = compile_pattern(p, emit=emit, aggregate=aggregate)
-                self._plans.setdefault((r, emit, aggregate), plan)
+                if (r, emit, aggregate) not in self._plans:
+                    self._plans[(r, emit, aggregate)] = \
+                        (plan, build_forest([plan]))
                 plans.append(plan)
             forest = build_forest(plans)
             self._forests[key] = forest
@@ -398,7 +409,7 @@ class Miner:
         """Count embeddings of one pattern query."""
         self._sct["queries"].inc()
         with self._query_span("count", query=str(query)):
-            return self._runner.run(self.compile(query))
+            return self._run_one(query)
 
     def count_many(self, queries: Sequence) -> list[int]:
         """Count a batch of pattern queries in one fused forest pass.
@@ -423,7 +434,7 @@ class Miner:
         self._require_values()
         self._sct["queries"].inc()
         with self._query_span("aggregate", query=str(query), op=op):
-            return self._runner.run(self.compile(query, aggregate=op))
+            return self._run_one(query, aggregate=op)
 
     def aggregate_many(self, queries: Sequence, op: str = "sum") -> list:
         """Aggregate a batch of queries in one fused forest pass (same
@@ -438,16 +449,14 @@ class Miner:
         """Enumerate embeddings of one query as an (N, k) int32 matrix."""
         self._sct["queries"].inc()
         with self._query_span("embeddings", query=str(query)):
-            return self._runner.run(self.compile(query, emit=True))
+            return self._run_one(query, emit=True)
 
     def run_plans(self, plans: Sequence[WavePlan]) -> list:
-        """Execute pre-compiled plans (FSM's feed, power users): one plan
-        runs directly, several fuse through a cached forest."""
+        """Execute pre-compiled plans (FSM's feed, power users) as one
+        cached forest."""
         self._sct["queries"].inc()
         plans = list(plans)
         with self._query_span("run_plans", plans=len(plans)):
-            if len(plans) == 1:
-                return [self._runner.run(plans[0])]
             key = ("plans", tuple(p.canonical_key() for p in plans))
             forest = self._forests.get(key)
             if forest is None:

@@ -29,7 +29,7 @@ program on its local feed block:
     pull per chunk, then a per-shard slice to each live total).
 
 Orchestration stays on the host and stays *identical* to the single-device
-interpreter — same plan descent, same forest fan-out, same residual packs —
+interpreter — same forest fan-out, same residual packs —
 because the only per-shard state it tracks is the live-total vector
 (``_pack_total``). Counts are therefore bit-identical to the single-device
 session: the same integer summands, grouped differently.
@@ -146,16 +146,7 @@ class ShardedWaveRunner(WaveRunner):
     def __init__(self, g: CSRGraph, mesh, *, axis: str = "mine",
                  feed_partition: str = "round_robin",
                  chunk: int | None = None, backend: str = "auto",
-                 device_compact: bool = True, record: bool = False,
-                 fused_level: bool = True, exec_cache=None, telemetry=None):
-        if not device_compact:
-            raise ValueError(
-                "ShardedWaveRunner requires device_compact=True: the host "
-                "np.nonzero oracle is inherently single-device")
-        if record:
-            raise ValueError(
-                "ShardedWaveRunner does not support record=True (wave "
-                "traces are per-shard; record on the single-device runner)")
+                 exec_cache=None, telemetry=None):
         if axis not in dict(mesh.shape):
             raise ValueError(f"axis {axis!r} not in mesh axes "
                              f"{tuple(dict(mesh.shape))}")
@@ -163,9 +154,7 @@ class ShardedWaveRunner(WaveRunner):
             raise ValueError(f"feed_partition must be one of "
                              f"{FEED_PARTITIONS}, got {feed_partition!r}")
         super().__init__(g, chunk=chunk, backend=backend,
-                         device_compact=True, record=False,
-                         fused_level=fused_level, exec_cache=exec_cache,
-                         telemetry=telemetry)
+                         exec_cache=exec_cache, telemetry=telemetry)
         self.mesh = mesh
         self.axis = axis
         self.feed_partition = feed_partition
@@ -265,8 +254,8 @@ class ShardedWaveRunner(WaveRunner):
         return self._shmap(wrapped, ((psh,) * nrefs, psh, psh, psh),
                            (psh, psh, psh))
 
-    def _bump(self, op, host: bool = False) -> None:
-        super()._bump(op, host)
+    def _bump(self, op) -> None:
+        super()._bump(op)
         if op.kind == "count":
             self._ct["psum_reductions"].inc()
 
@@ -340,10 +329,10 @@ class ShardedWaveRunner(WaveRunner):
             cols2 = dict(zip([c for c in op.out_cols if c < op.level], outs))
             if op.level in op.out_cols:
                 cols2[op.level] = vch
-            yield cols2, carry2, vch, m
+            yield cols2, carry2, m
 
-    def _plan_emit(self, op, caps_sig, cap_base, out_cap, out_items, cols,
-                   vals, carry_in, n) -> list:
+    def _plan_emit(self, op, caps_sig, cap_base, out_cap, out_items, vals,
+                   carry_in, n) -> list:
         """Terminal emit: one bulk embedding pull, then per-shard survivor
         blocks sliced to each shard's live total."""
         self._bump(op)

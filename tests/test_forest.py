@@ -6,8 +6,8 @@ Layers:
     trie (level-2: 6 plan ops -> 3 shared nodes; feed passes 6 -> 2), with
     relaxed constraints reappearing as residuals on the right branches;
   * count identity — ``run_set`` output equals per-plan ``run`` output,
-    equals the independent brute-force oracles (census + ESU), on device
-    and host compaction, and under tiny chunks (multi-chunk fan-out);
+    equals the independent brute-force oracles (census + ESU), and under
+    tiny chunks (multi-chunk fan-out);
   * emit plans through the forest (FSM's triangle feed) and mixed
     emit+count batches;
   * a hypothesis property over random pattern *sets* (plus its seeded
@@ -112,6 +112,54 @@ def test_canonical_plan_key_distinguishes_and_matches():
     assert t1.canonical_key() != P.compile_pattern(P.TRIANGLE_NESTED).canonical_key()
 
 
+# every distinct pattern of the session's named-query catalog
+CATALOG: dict = {}
+for _name, _query in P._NAMED_QUERIES.items():
+    if _query not in CATALOG.values():
+        CATALOG[_name] = _query
+
+
+@pytest.mark.parametrize("emit", [False, True], ids=["count", "emit"])
+@pytest.mark.parametrize("name", list(CATALOG))
+def test_one_plan_forest_is_the_plan(name, emit, monkeypatch):
+    """A single plan runs as a one-plan forest: the trie is one chain on the
+    plan's feed orientation that walks exactly ``plan.ops``, and ``Miner``
+    builds it once, beside the compiled plan — a repeat query builds none."""
+    from repro.mining import session
+    built = []
+
+    def counting(plans):
+        built.append(len(plans))
+        return build_forest(plans)
+    monkeypatch.setattr(session, "build_forest", counting)
+    m = session.Miner(TINY)
+    run = m.embeddings if emit else m.count
+    first = run(name)
+    assert built == [1]
+    plan = m.compile(name, emit=emit)
+    forest = build_forest([plan])
+    roots, other = ((forest.symmetric_roots, forest.directed_roots)
+                    if plan.symmetric
+                    else (forest.directed_roots, forest.symmetric_roots))
+    assert other == ()
+    ops, nodes = [], roots
+    while nodes:
+        assert len(nodes) == 1 and nodes[0].ride_plans == ()
+        ops.append(nodes[0].op)
+        leaf, nodes = nodes[0], nodes[0].children
+    assert tuple(ops) == plan.ops and leaf.plans == (0,)
+    # the repeat reuses the cached forest and the compiled executables
+    misses = m.exec_cache.misses
+    second = run(name)
+    assert built == [1] and m.exec_cache.misses == misses
+    if emit:
+        np.testing.assert_array_equal(first, second)
+        assert first.shape[1] == plan.k
+    else:
+        assert first == second == reference.pattern_count_oracle(
+            TINY, plan.pattern)
+
+
 # ---------------------------------------------------------------------------
 # count identity: fused == independent == oracles
 # ---------------------------------------------------------------------------
@@ -152,21 +200,22 @@ def test_fused_four_motif_matches_exhaustive_esu():
 @pytest.mark.parametrize("name", ["er", "cliq"])
 def test_forest_device_host_compaction_agree(name):
     g = GRAPHS[name]
-    forest = build_forest(FOUR_MOTIF_PLANS)
-    dev = WaveRunner(g).run_set(forest)
-    host = WaveRunner(g, device_compact=False).run_set(forest)
-    assert dev == host
+    got = WaveRunner(g).run_set(build_forest(FOUR_MOTIF_PLANS))
+    assert got == [reference.four_motif_counts(g)[n] for n in P.FOUR_MOTIFS]
 
 
 def test_run_set_records_waves():
-    """record=True must trace forest runs like single-plan runs: the level-1
-    feed plus every fan-out chunk at each interior node's output level."""
+    """A forest run visits every level of its trie: each interior node's
+    expand and each leaf's count shows in ``level_execs``."""
     g = TINY
-    runner = WaveRunner(g, record=True)
-    runner.run_set(build_forest(FOUR_MOTIF_PLANS))
-    levels = {lv for lv, _, _ in runner.trace}
-    assert 1 in levels and 3 in levels
-    assert sum(n.shape[0] for lv, n, _ in runner.trace if lv == 1) > 0
+    forest = build_forest(FOUR_MOTIF_PLANS)
+    runner = WaveRunner(g)
+    runner.run_set(forest)
+    assert runner.metrics.value("feed_chunks") > 0
+    want = set(forest.sharing_stats()["forest_ops"])
+    assert set(runner.level_execs) == want
+    assert {lv for _, lv in runner.level_execs} == {2, 3}
+    assert all(n > 0 for n in runner.level_execs.values())
 
 
 def test_forest_tiny_chunks_agree():
@@ -231,8 +280,6 @@ def _assert_forest_matches_independent(pats):
     indep = [WaveRunner(g).run(pl) for pl in plans]
     oracle = [reference.pattern_count_oracle(g, p) for p in pats]
     assert fused == indep == oracle, (pats, fused, indep, oracle)
-    host = WaveRunner(g, device_compact=False).run_set(build_forest(plans))
-    assert host == fused
 
 
 @settings(max_examples=10, deadline=None)
@@ -246,7 +293,6 @@ def test_random_pattern_sets_fuse_bit_identically(data):
 @pytest.mark.parametrize("seed", range(6))
 def test_seeded_random_pattern_sets_fuse_bit_identically(seed):
     """Hypothesis-free twin of the property test (fixed corpus): pairs of
-    pseudo-random patterns must fuse without changing any count, on device
-    and host compaction."""
+    pseudo-random patterns must fuse without changing any count."""
     pats = [_seeded_pattern(2 * seed), _seeded_pattern(2 * seed + 1)]
     _assert_forest_matches_independent(pats)

@@ -1,11 +1,9 @@
-"""Fused multi-operand level path vs the per-ref mark fallback.
+"""Fused multi-operand level path vs the host oracles.
 
-``WaveRunner(fused_level=True)`` dispatches ONE k-operand kernel per general
-level (``ops.xlevel_count``/``xlevel_compact``); ``fused_level=False`` keeps
-the per-reference ``xmark`` composition. The acceptance contract of PR 4:
-every mining app's counts are bit-identical with the flag on and off (and
-equal to the oracles), while the general-level kernel dispatch count drops
-from k per level to 1.
+``WaveRunner`` dispatches ONE k-operand kernel per general level
+(``ops.xlevel_count``/``xlevel_compact``): every mining app's counts equal
+the oracles of ``mining/reference.py``, and a general level with k >= 2
+references costs one kernel dispatch per executable call.
 """
 import numpy as np
 import pytest
@@ -42,12 +40,6 @@ APP_PLANS = {
 }
 
 
-def _runs(g, plan, **kw):
-    on = WaveRunner(g, fused_level=True, **kw)
-    off = WaveRunner(g, fused_level=False, **kw)
-    return on.run(plan), off.run(plan), on, off
-
-
 # fast oracles per app (the permutation oracle is reserved for TINY — it is
 # O(n^k · k!) and the census/closed forms already cover these patterns)
 _ORACLE = {
@@ -65,35 +57,31 @@ _ORACLE = {
 @pytest.mark.parametrize("name", list(APP_PLANS))
 def test_apps_bit_identical_fused_on_off(name):
     g = GRAPHS["er"]
-    got_on, got_off, *_ = _runs(g, APP_PLANS[name])
-    assert got_on == got_off, (name, got_on, got_off)
-    assert got_on == _ORACLE[name](g), name
+    got = WaveRunner(g).run(APP_PLANS[name])
+    assert got == _ORACLE[name](g), (name, got)
 
 
 @pytest.mark.parametrize("gname", list(GRAPHS))
 def test_four_motif_forest_fused_on_off(gname):
     """The F4M batch (shared expands + residual-packed branches) through
-    run_set with the fused level path on and off, vs independent plans."""
+    run_set vs independent plans and the brute-force census."""
     g = GRAPHS[gname]
     plans = [P.compile_pattern(p) for p in P.FOUR_MOTIFS.values()]
-    forest = build_forest(plans)
-    f_on = WaveRunner(g, fused_level=True).run_set(forest)
-    f_off = WaveRunner(g, fused_level=False).run_set(forest)
+    fused = WaveRunner(g).run_set(build_forest(plans))
     indep = [WaveRunner(g).run(pl) for pl in plans]
-    assert f_on == f_off == indep
+    census = reference.four_motif_counts(g)
+    assert fused == indep == [census[n] for n in P.FOUR_MOTIFS]
 
 
 def test_three_motif_and_fsm_feed_fused_on_off():
     g = GRAPHS["plc"]
     t3 = [P.compile_pattern(P.TRIANGLE),
           P.compile_pattern(P.THREE_CHAIN_INDUCED)]
-    assert WaveRunner(g, fused_level=True).run_set(build_forest(t3)) \
-        == WaveRunner(g, fused_level=False).run_set(build_forest(t3))
+    t, chain = WaveRunner(g).run_set(build_forest(t3))
+    assert {"triangle": t, "chain": chain} == reference.motif3(g)
     # FSM's engine feed: the triangle emit plan — embeddings, not counts
-    emit = P.compile_pattern(P.TRIANGLE, emit=True)
-    e_on, e_off, *_ = _runs(g, emit)
-    np.testing.assert_array_equal(e_on, e_off)
-    np.testing.assert_array_equal(e_on, triangle_list_host(g))
+    emit = WaveRunner(g).run(P.compile_pattern(P.TRIANGLE, emit=True))
+    np.testing.assert_array_equal(emit, triangle_list_host(g))
 
 
 def test_tiny_chunks_fused_on_off():
@@ -103,35 +91,25 @@ def test_tiny_chunks_fused_on_off():
     census = reference.four_motif_counts(g)
     for name in ("4-cycle", "paw"):
         plan = APP_PLANS[name]
-        a = WaveRunner(g, chunk=128, fused_level=True).run(plan)
-        b = WaveRunner(g, chunk=128, fused_level=False).run(plan)
-        assert a == b == census[name]
-
-
-def test_host_oracle_unaffected_by_fused_level():
-    g = GRAPHS["er"]
-    plan = APP_PLANS["4-cycle"]
-    want = WaveRunner(g).run(plan)
-    assert WaveRunner(g, device_compact=False, fused_level=True).run(plan) \
-        == WaveRunner(g, device_compact=False, fused_level=False).run(plan) \
-        == want
+        assert WaveRunner(g, chunk=128).run(plan) == census[name]
 
 
 def test_dispatch_count_drops_from_k_to_one():
     """4-cycle's general level (inter + sub refs, k=2) must cost exactly one
-    kernel dispatch per executable call on the fused path, k on the
-    fallback — the per-operand DMA saving the tentpole claims."""
+    kernel dispatch per executable call, not one per reference."""
     g = GRAPHS["er"]
     plan = APP_PLANS["4-cycle"]
-    _, _, on, off = _runs(g, plan)
+    runner = WaveRunner(g)
+    assert runner.run(plan) == _ORACLE["4-cycle"](g)
     k3 = len(plan.ops[-1].inter) + len(plan.ops[-1].sub)
-    assert k3 == 2                                  # inter (2,), sub (0,)
-    n3_on = on.level_execs[("count", 3)]
-    n3_off = off.level_execs[("count", 3)]
-    assert n3_on == n3_off > 0
-    # fallback pays (k-1) extra dispatches per general-level executable call
-    assert off.stats["level_kernel_dispatches"] \
-        - on.stats["level_kernel_dispatches"] == (k3 - 1) * n3_off
+    assert k3 == 2                                  # inter (2,), sub (1,)
+    # the other levels each hold one ref, so one dispatch a call
+    assert all(len(op.inter) + len(op.sub) == 1 for op in plan.ops[:-1])
+    n3 = runner.level_execs[("count", 3)]
+    assert n3 > 0
+    # every level costs one dispatch a call: the k=2 level no more
+    assert runner.stats["level_kernel_dispatches"] \
+        == sum(runner.level_execs.values())
 
 
 def test_pallas_backend_fused_level_agrees():
@@ -142,18 +120,16 @@ def test_pallas_backend_fused_level_agrees():
     k-operand kernel's full parity sweep lives in test_kernels.py."""
     g = build_csr(erdos_renyi(12, 30, seed=5), 12)
     plan = APP_PLANS["4-cycle"]
-    got = WaveRunner(g, chunk=128, backend="pallas",
-                     fused_level=True).run(plan)
+    got = WaveRunner(g, chunk=128, backend="pallas").run(plan)
     assert got == reference.pattern_count_oracle(g, plan.pattern)
 
 
 def _assert_fused_level_invariant(pat):
     g = TINY
     plan = P.compile_pattern(pat)
-    on = WaveRunner(g, fused_level=True).run(plan)
-    off = WaveRunner(g, fused_level=False).run(plan)
+    got = WaveRunner(g).run(plan)
     want = reference.pattern_count_oracle(g, pat)
-    assert on == off == want, (pat, on, off, want)
+    assert got == want, (pat, got, want)
 
 
 @settings(max_examples=12, deadline=None)

@@ -127,7 +127,7 @@ def test_runner_stats_golden_bit_identity():
     assert r.count_edges() == 401
     assert dict(r.stats) == {
         "exec_hits": 0, "exec_misses": 4, "host_syncs": 3,
-        "device_compactions": 1, "host_compactions": 0, "items": 401,
+        "device_compactions": 1, "items": 401,
         "level_kernel_dispatches": 3, "count_rides": 0}
     # write-through: resetting a counter the legacy way hits the registry
     r.stats["exec_misses"] = 0
@@ -149,7 +149,7 @@ def test_session_stats_golden_bit_identity():
     assert st["exec_cache"] == {"hits": 3, "misses": 15, "entries": 15}
     assert st["runner"] == {
         "exec_hits": 3, "exec_misses": 15, "host_syncs": 13,
-        "device_compactions": 3, "host_compactions": 0, "items": 19937,
+        "device_compactions": 3, "items": 19937,
         "level_kernel_dispatches": 10, "count_rides": 0}
 
 
@@ -359,10 +359,8 @@ def test_traced_dispatch_never_blocks(monkeypatch):
     assert m.count("triangle") == 440
     assert m.count("4-clique") == 78
     assert m.embeddings("triangle").shape == (440, 3)
-    host = Miner(_pl_graph(), telemetry=tel, device_compact=False)
-    assert host.count("4-clique") == 78
     sites = {s.attrs["site"] for s in tel.tracer.spans("sync")}
-    assert sites == {"meta", "emit", "host_compact"}
+    assert sites == {"meta", "emit"}
     for d in tel.tracer.spans("dispatch"):
         assert isinstance(d.attrs["items"], int)
 

@@ -171,10 +171,6 @@ def test_mesh_signature_isolates_sharded_executables():
 def test_sharded_runner_rejects_unsupported_modes():
     g, mesh = GRAPHS["er"], mesh_for(2)
     with pytest.raises(ValueError):
-        ShardedWaveRunner(g, mesh, device_compact=False)
-    with pytest.raises(ValueError):
-        ShardedWaveRunner(g, mesh, record=True)
-    with pytest.raises(ValueError):
         ShardedWaveRunner(g, mesh, axis="model")
     with pytest.raises(ValueError):
         ShardedWaveRunner(g, mesh, feed_partition="hashed")

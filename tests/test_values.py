@@ -197,10 +197,10 @@ def test_aggregate_matches_weighted_oracle(name, op):
 
 @settings(max_examples=6, deadline=None)
 @given(st.integers(0, 10_000), st.integers(0, 10_000),
-       st.sampled_from(["sum", "max", "min"]), st.booleans())
-def test_aggregate_oracle_property(gseed, wseed, op, device_compact):
+       st.sampled_from(["sum", "max", "min"]))
+def test_aggregate_oracle_property(gseed, wseed, op):
     g = _weighted(erdos_renyi(16, 44, seed=gseed), 16, seed=wseed)
-    m = Miner(g, device_compact=device_compact, chunk=128)
+    m = Miner(g, chunk=128)
     for pat in (P.TRIANGLE, P.THREE_CHAIN_INDUCED, P.clique_pattern(4)):
         assert m.aggregate(pat, op=op) == \
             reference.weighted_pattern_oracle(g, pat, op), (pat.name, op)

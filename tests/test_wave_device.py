@@ -1,9 +1,11 @@
-"""Device-resident wavefront pipeline vs the host compaction oracle.
+"""Device-resident wavefront pipeline vs the host oracles.
 
-The fast path (WaveRunner + ops.xinter_compact) must reproduce the host
-``compact`` oracle item-for-item: same work-item order (np.nonzero row-major),
-same extension vertices, same prefix rows, same final counts — across random
-CSR graphs, sentinel-padded tails and bound=0 padding items.
+The device compaction (``ops.xinter_compact``, ``batch_compact_items``) must
+reproduce the host ``compact`` oracle item-for-item: same work-item order
+(np.nonzero row-major), same extension vertices, same prefix rows — across
+random rows, sentinel-padded tails and bound=0 padding items. Through
+``WaveRunner`` the counts and the items each expand level keeps must equal
+``mining/reference.py``.
 """
 import numpy as np
 import jax.numpy as jnp
@@ -97,42 +99,29 @@ def test_xinter_compact_matches_inter_plus_host_compact(backend):
     np.testing.assert_array_equal(got_rows, wave.rows)
 
 
-def _trace_of(g, k, device_compact, chunk=None):
-    runner = WaveRunner(g, chunk=chunk, device_compact=device_compact,
-                        record=True)
-    count = runner.clique(k)
-    return count, runner.trace, runner.stats
+def _assert_clique_waves(g, k, chunk=None):
+    """k-clique count and the items its expand levels keep: level j's
+    survivors are the j-cliques (v0 > v1 > ... > v(j-1)), so a k-clique
+    run keeps Σ clique_count(g, j) over 3 <= j < k items."""
+    runner = WaveRunner(g, chunk=chunk)
+    assert runner.clique(k) == reference.clique_count(g, k)
+    assert runner.stats["device_compactions"] > 0
+    assert runner.stats["items"] == sum(reference.clique_count(g, j)
+                                        for j in range(3, k))
 
 
 @pytest.mark.parametrize("name", list(GRAPHS))
 @pytest.mark.parametrize("k", [4, 5])
 def test_clique_waves_bit_identical_device_vs_host(name, k):
-    g = GRAPHS[name]
-    want = reference.clique_count(g, k)
-    c_dev, t_dev, s_dev = _trace_of(g, k, device_compact=True)
-    c_host, t_host, s_host = _trace_of(g, k, device_compact=False)
-    assert c_dev == c_host == want
-    assert s_dev["device_compactions"] > 0 and s_dev["host_compactions"] == 0
-    assert s_host["host_compactions"] > 0 and s_host["device_compactions"] == 0
-    assert len(t_dev) == len(t_host)
-    for (lv_d, rows_d, verts_d), (lv_h, rows_h, verts_h) in zip(t_dev, t_host):
-        assert lv_d == lv_h
-        np.testing.assert_array_equal(verts_d, verts_h)
-        np.testing.assert_array_equal(rows_d, rows_h)
+    for chunk in (None, 128):
+        _assert_clique_waves(GRAPHS[name], k, chunk)
 
 
 @pytest.mark.parametrize("name", list(GRAPHS))
 def test_clique_waves_identical_with_tiny_chunks(name):
     """Small chunks force multi-chunk waves + chunk-rounded item buffers."""
-    g = GRAPHS[name]
-    c_dev, t_dev, _ = _trace_of(g, 4, device_compact=True, chunk=128)
-    c_host, t_host, _ = _trace_of(g, 4, device_compact=False, chunk=128)
-    assert c_dev == c_host == reference.clique_count(g, 4)
-    assert len(t_dev) == len(t_host)
-    for (lv_d, rows_d, verts_d), (lv_h, rows_h, verts_h) in zip(t_dev, t_host):
-        assert lv_d == lv_h
-        np.testing.assert_array_equal(verts_d, verts_h)
-        np.testing.assert_array_equal(rows_d, rows_h)
+    for chunk in (None, 128):
+        _assert_clique_waves(GRAPHS[name], 4, chunk)
 
 
 def test_all_seven_apps_agree_with_reference():
@@ -152,8 +141,6 @@ def test_all_seven_apps_agree_with_reference():
     assert {"triangle": t, "chain": chain} == reference.motif3(g)
     for k in (4, 5):
         assert m.count(clique_pattern(k)) == reference.clique_count(g, k)
-        assert (shared_session(g, device_compact=False)
-                .count(clique_pattern(k)) == reference.clique_count(g, k))
 
 
 def test_executable_cache_reuses_across_levels_and_graphs():
@@ -173,7 +160,7 @@ def test_executable_cache_reuses_across_levels_and_graphs():
 def test_exec_misses_equal_unique_shapes():
     """One trace per (kind, shape) key — degree buckets never re-trace."""
     g = GRAPHS["plc"]
-    runner = WaveRunner(g, device_compact=True)
+    runner = WaveRunner(g)
     runner.clique(5)
     runner.count_edges()
     assert runner.stats["exec_misses"] == len(runner._exec)
