@@ -284,6 +284,33 @@ def wave_chunks(wave: Wave, chunk: int):
         yield Wave(rows=_pad_to(r, chunk, SENTINEL), verts=_pad_to(v, chunk, 0)), k
 
 
+# ---------------------------------------------------------------------------
+# count accumulator: a leaf's running count as three int32 limbs
+# ---------------------------------------------------------------------------
+
+COUNT_LIMBS = 3
+
+
+def count_acc_add(acc, w0, w16, w32):
+    """``acc`` (int32 limbs of weights 2^0, 2^16, 2^32) plus a partial
+    given as nonnegative int32 sums at those weights, carry-propagated so
+    the two low limbs stay below 2^16. Exact while every sum stays below
+    2^31: each weight sum below 2^30 (a chunk's (hi, lo) pair, or a psum'd
+    limb quad over fewer than 2^13 shards) and a running total below 2^63,
+    whose top limb is floor(total / 2^32) < 2^31. No limb ever wraps, so
+    an accumulator needs no host read before the query's end."""
+    t0 = acc[0] + w0
+    t1 = acc[1] + w16 + (t0 >> 16)
+    t2 = acc[2] + w32 + (t1 >> 16)
+    return jnp.stack([t0 & 0xFFFF, t1 & 0xFFFF, t2])
+
+
+def count_acc_value(limbs) -> int:
+    """The exact count an accumulator's limbs hold, as a Python int."""
+    a0, a1, a2 = (int(x) for x in limbs)
+    return (a2 << 32) + (a1 << 16) + a0
+
+
 DEFAULT_CHUNK = 4096
 
 
@@ -342,9 +369,13 @@ class WaveRunner:
       compacted ``src`` indices on device, never round-tripping to host;
     * **double-buffered feeds**: level-1 edge chunks upload one ahead of
       compute;
-    * **per-chunk device partial sums**: count levels reduce to one scalar
-      per chunk on device (synced in a deferred batch at the end of
-      ``run_set``) — no count vectors ever cross to the host.
+    * **device count accumulators**: a count leaf reduces each chunk to
+      an exact partial and adds it, in the same executable, to one int32
+      limb accumulator per leaf node (``count_acc_add``; exact for any
+      total below 2^63, never flushed), threaded through every dispatch
+      of the query. ``run_set`` reads every accumulator, and any
+      aggregate pair, in ONE ``jax.device_get`` at its end — no count
+      vector and no per-chunk partial ever crosses to the host.
 
     A ``mining.forest.PlanForest`` holds one or several plans: one
     edge-feed pass per orientation, each shared trie node's expand +
@@ -386,6 +417,9 @@ class WaveRunner:
     # prepended to every executable-cache key so sharded (shard_map-wrapped)
     # traces can never collide with unsharded traces of the same LevelOp
     _exec_prefix: tuple = ()
+    # where a count accumulator lives: the default device here, replicated
+    # over the mesh on ShardedWaveRunner
+    _acc_sharding = None
 
     # legacy ``stats`` keys, in their historical insertion order — each is
     # a registry counter the view derives from (see __init__)
@@ -651,20 +685,33 @@ class WaveRunner:
         return jnp.where(ok, ub, 0)
 
     def _plan_count_fn(self, op: LevelOp, caps_sig: tuple, cap_base: int):
-        """Terminal count level -> one tiny device sync per chunk.
+        """Terminal count level -> ``(acc, g, vals, carry, n) -> acc``.
 
-        The per-chunk sum is returned as an exact (hi, lo) int32 pair —
-        Σ(count >> 16) and Σ(count & 0xffff) — reassembled in Python ints at
-        ``run``'s deferred sync. With chunk <= 2^15 neither partial can wrap,
-        so the only remaining envelope is per *item*: a tail-folded count
-        (survivors x degree factor) must stay below 2^31, which holds
-        whenever maxc * max_degree < 2^31 (the old host path multiplied in
-        int64 but pulled the whole count vector to do it).
+        The body reduces the chunk to an exact (hi, lo) int32 pair —
+        Σ(count >> 16) and Σ(count & 0xffff); ``_jit_count`` reduces that
+        across the mesh on the sharded runner; the same executable then
+        adds it to the leaf's accumulator (``count_acc_add``, weights from
+        ``_count_weights``). With chunk <= 2^15 neither pair word can wrap
+        (lo < 2^31, hi < 2^30), and the accumulator is exact for any total
+        below 2^63, so the only remaining envelope is per *item*: a
+        tail-folded count (survivors x degree factor) must stay below 2^31,
+        which holds whenever maxc * max_degree < 2^31.
         """
         def build():
-            return self._jit_count(
+            part = self._jit_count(
                 op, self._count_body(op, caps_sig, cap_base))
+
+            def fn(acc, g, vals, carry, n):
+                return count_acc_add(
+                    acc, *self._count_weights(part(g, vals, carry, n)))
+            return jax.jit(fn)
         return self._executable(("pcount", op, caps_sig, cap_base), build)
+
+    @staticmethod
+    def _count_weights(part):
+        """(w0, w16, w32) of a chunk's (hi, lo) pair, worth hi·2^16 + lo."""
+        hi, lo = part[0], part[1]
+        return lo & 0xFFFF, (lo >> 16) + (hi & 0xFFFF), hi >> 16
 
     def _count_body(self, op: LevelOp, caps_sig: tuple, cap_base: int):
         """Unjitted count-level body (see the two-halves note in the class
@@ -932,7 +979,9 @@ class WaveRunner:
 
     # ------------------------------------------------------- the interpreter
     def _finalize(self, plan: WavePlan, parts: list):
-        """Reduce one plan's accumulated chunk outputs to its result."""
+        """Reduce one plan's host parts to its result: emit blocks, (value,
+        live) aggregate pairs, or exact counts (Python ints: each count
+        leaf's accumulator total and each ride partial)."""
         if plan.ops[-1].kind == "emit":
             if not parts:
                 return np.zeros((0, plan.k), dtype=np.int32)
@@ -955,15 +1004,7 @@ class WaveRunner:
                 else:
                     value = min(value, x)
             return float(value) if (value is not None and live > 0) else 0.0
-        total = 0
-        for p in parts:
-            v = np.asarray(p)
-            if v.shape[0] == 4:     # psum'd 16-bit limb quad (sharded runner)
-                hi = (int(v[0]) << 16) + int(v[1])
-                lo = (int(v[2]) << 16) + int(v[3])
-            else:                   # (hi, lo) int32 pair, exact
-                hi, lo = (int(x) for x in v)
-            total += (hi << 16) + lo
+        total = sum(parts)
         if plan.div > 1:
             assert total % plan.div == 0, (plan.pattern.name, total, plan.div)
             total //= plan.div
@@ -983,14 +1024,19 @@ class WaveRunner:
         materialised and iterated ONCE, every trie root consumes the same
         device-resident edge chunks, and shared interior nodes run their
         expand + compaction a single time before fanning out to all child
-        branches. Per-leaf accumulators collect (hi, lo) count partials /
-        embedding blocks per source plan.
+        branches. Each count-leaf node keeps one device accumulator
+        (``leaves``, keyed by node identity) that every dispatch of the
+        leaf threads through; per-plan lists collect ride partials,
+        aggregate pairs and embedding blocks. At the end one
+        ``jax.device_get`` reads every accumulator and every part still on
+        the device, and each leaf's total is credited to all its plans.
 
         Returns a list of per-plan results in ``forest.plans`` order (ints
         for counting plans, (N, k) int32 matrices for emit plans) —
         bit-identical to running each plan through ``run`` independently.
         """
         acc: list[list] = [[] for _ in forest.plans]
+        leaves: dict[int, list] = {}
         tr = self.telemetry.tracer
         with tr.span("execute", plans=len(forest.plans), forest=True):
             for symmetric, roots in ((True, forest.symmetric_roots),
@@ -1008,20 +1054,31 @@ class WaveRunner:
                             self._count_feed_fill(caps[1], v1h, n)
                         for root in roots:
                             self._forest_descend(root, {0: dv0, 1: dv1},
-                                                 caps, None, n, acc)
-            self._ct["host_syncs"].inc(sum(len(a) for a in acc))
+                                                 caps, None, n, acc, leaves)
             with tr.span("finalize"):
+                nodes = list(leaves.values())
+                limbs, acc = jax.device_get(([a for _, a in nodes], acc))
+                # one read per accumulator and per part (ride partials
+                # offset theirs at arrival: they came in a meta sync)
+                self._ct["host_syncs"].inc(
+                    len(nodes) + sum(len(a) for a in acc))
+                for (plans, _), lm in zip(nodes, limbs):
+                    total = count_acc_value(lm)
+                    for i in plans:
+                        acc[i].append(total)
                 return [self._finalize(plan, parts)
                         for plan, parts in zip(forest.plans, acc)]
 
     def _forest_descend(self, node, cols: dict, caps: dict, carry, n: int,
-                        acc: list) -> None:
+                        acc: list, leaves: dict) -> None:
         """Execute one forest node on a wave chunk; fan out over children.
 
-        A count leaf dispatches its executable and appends the partial to
-        each owning plan's accumulator; an emit leaf appends its embedding
-        block; an expand runs once and its compacted worklist's chunks feed
-        *every* child branch."""
+        A count leaf dispatches its executable on the node's accumulator
+        in ``leaves`` (zeros at its first chunk of the query) and keeps
+        the result there; an aggregate leaf appends its pair and an emit
+        leaf its embedding block to each owning plan's list in ``acc``; an
+        expand runs once and its compacted worklist's chunks feed *every*
+        child branch."""
         op = node.op
         caps_sig = tuple(sorted((c, caps[c]) for c in op.row_refs()))
         cap_base = int(carry.shape[1]) if op.use_carry else caps[op.base]
@@ -1030,11 +1087,19 @@ class WaveRunner:
         with self._level_span(op, n):
             if op.kind == "count":
                 self._bump(op)
-                if op.agg is not None:
-                    self._ct_value_lanes.inc()
-                    fn = self._plan_agg_fn(op, caps_sig, cap_base)
-                else:
+                if op.agg is None:
                     fn = self._plan_count_fn(op, caps_sig, cap_base)
+                    leaf = leaves.get(id(node))
+                    if leaf is None:
+                        zero = jax.device_put(np.zeros(COUNT_LIMBS, np.int32),
+                                              self._acc_sharding)
+                        leaf = leaves[id(node)] = [node.plans, zero]
+                    leaf[1] = self._dispatch(
+                        op, fn, (leaf[1], self.g, vals, carry_in, n),
+                        items=n, caps_sig=caps_sig)
+                    return
+                self._ct_value_lanes.inc()
+                fn = self._plan_agg_fn(op, caps_sig, cap_base)
                 part = self._dispatch(op, fn, (self.g, vals, carry_in, n),
                                       items=n, caps_sig=caps_sig)
                 for i in node.plans:
@@ -1093,7 +1158,7 @@ class WaveRunner:
                         op, b, out_cap, cap2, rows2, s, v, cols, t):
                     for child in children:
                         self._forest_descend(child, cols2, caps2, carry2, m,
-                                             acc)
+                                             acc, leaves)
 
     def _plan_emit(self, op, caps_sig, cap_base, out_cap, out_items, vals,
                    carry_in, n) -> list:
@@ -1112,7 +1177,8 @@ class WaveRunner:
                        vals, carry_in, n, want_count: bool = False):
         """Run one expand executable + meta sync. Returns ``None`` when no
         survivors, else (rows2, src, verts2, total, caps2, cap2, ride) —
-        ``ride`` is the (hi, lo) survivor-count partial when ``want_count``
+        ``ride`` is the survivor count, an exact Python int reassembled
+        from the meta's (hi, lo) pair, when ``want_count``
         (count-rides-expand), else None."""
         self._bump(op)
         fn = self._plan_expand_fn(op, caps_sig, cap_base, out_cap, out_items,
@@ -1122,7 +1188,7 @@ class WaveRunner:
         with self._sync_span("meta"):
             meta = [int(x) for x in np.asarray(meta)]
         if want_count:
-            meta, ride = meta[:-2], np.asarray(meta[-2:], dtype=np.int32)
+            meta, ride = meta[:-2], (meta[-2] << 16) + meta[-1]
         else:
             ride = None
         total, maxc, dmaxs = meta[0], meta[1], meta[2:]

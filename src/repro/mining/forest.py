@@ -87,8 +87,10 @@ Trie interpretation contract (``WaveRunner.run_set``)
   another forest share compiled traces;
 * each expand node runs its gather + masks + on-device compaction once per
   wave chunk and feeds the resulting (cols2, caps2, carry2) to every child;
-* leaf partials — (hi, lo) int32 count pairs or embedding blocks — are
-  appended to per-plan accumulators and finalised per plan
+* a count leaf adds each chunk's partial to one device accumulator per
+  leaf node, read once at the end of the run and credited to every plan
+  in ``plans``; ride partials, aggregate pairs and embedding blocks are
+  appended to per-plan lists; each plan is finalised on its own
   (``WaveRunner._finalize``: division by ``Pattern.div``, emit
   concatenation).
 """

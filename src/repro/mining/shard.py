@@ -17,8 +17,12 @@ program on its local feed block:
   * count leaves reduce their (hi, lo) partials with ``jax.lax.psum``
     over the mining axis. Per-shard hi words can reach 2^30, so an 8-way
     int32 psum could wrap: partials are split into four 16-bit limbs
-    *before* the psum (limb sums stay far below 2^31) and reassembled
-    exactly on the host (``WaveRunner._finalize``);
+    *before* the psum (limb sums stay far below 2^31). The same
+    executable then adds the psum'd quad to the leaf's count accumulator,
+    replicated (``PartitionSpec()``) on every device: three int32 limbs of
+    weights 2^0, 2^16, 2^32, carried after each add, exact for any total
+    below 2^63 on a mesh of fewer than 2^13 devices
+    (``engine.count_acc_add``). The host reads it once, at the query's end;
   * expand levels return their level-boundary meta per shard (an (S, m)
     row block): per-shard live totals drive lockstep chunking (every
     shard walks ``ceil(max_totals / chunk)`` steps; shards past their own
@@ -163,6 +167,7 @@ class ShardedWaveRunner(WaveRunner):
         self._psh = P(axis)          # sharded on the mining axis
         self._prp = P()              # replicated
         self._rep_sharding = NamedSharding(mesh, self._prp)
+        self._acc_sharding = self._rep_sharding
         self._feed_sharding = NamedSharding(mesh, self._psh)
         # replicate the CSR buffers across the mesh once per runner
         self.g = jax.device_put(g, self._rep_sharding)
@@ -207,6 +212,12 @@ class ShardedWaveRunner(WaveRunner):
                                    part[1] >> 16, part[1] & 0xFFFF])
                 return jax.lax.psum(limbs, axis)
         return self._shmap(wrapped, self._level_in_specs(op), self._prp)
+
+    @staticmethod
+    def _count_weights(q):
+        """(w0, w16, w32) of a psum'd limb quad: hi's limbs at 2^32 and
+        2^16, lo's at 2^16 and 2^0."""
+        return q[3], q[1] + q[2], q[0]
 
     def _jit_agg(self, op, body):
         axis = self.axis
@@ -282,7 +293,8 @@ class ShardedWaveRunner(WaveRunner):
         (shards, m) row block. Per-shard live totals come back as a vector
         (they drive lockstep chunking); capacities take the max over shards
         (upper bounds — lossless); ride partials are summed exactly on the
-        host (they already crossed in the meta sync, no extra collective)."""
+        host into one Python int (they already crossed in the meta sync, no
+        extra collective)."""
         self._bump(op)
         fn = self._plan_expand_fn(op, caps_sig, cap_base, out_cap, out_items,
                                   want_count)
@@ -291,8 +303,8 @@ class ShardedWaveRunner(WaveRunner):
         with self._sync_span("meta"):
             meta = np.asarray(meta).astype(np.int64)    # (shards, m)
         if want_count:
-            meta, rpart = meta[:, :-2], meta[:, -2:].sum(axis=0)
-            ride = np.asarray(rpart)                     # (hi_sum, lo_sum)
+            hi, lo = (int(x) for x in meta[:, -2:].sum(axis=0))
+            meta, ride = meta[:, :-2], (hi << 16) + lo
         else:
             ride = None
         totals = meta[:, 0]

@@ -13,7 +13,7 @@ A ``Tracer`` records a tree of ``Span``s per traced query:
        │     ├─ sync       (a blocking device->host read; attr ``site``)
        │     └─ level L3 count
        │        └─ dispatch
-       └─ finalize         (the deferred reads of the count partials)
+       └─ finalize         (one read of the count accumulators)
 
 Every span is also a ``jax.profiler.TraceAnnotation`` named ``ix.<name>``
 (its cheap attributes ride along as annotation metadata), entered whether

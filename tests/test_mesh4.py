@@ -12,7 +12,10 @@ subprocess that sees four devices (the test process sees one). Checked:
   * the leaf reduction runs in the ``mesh_psum`` name scope, which the
     count executable's ops carry as metadata;
   * a ``feed_step`` span (one feed step's slicing, dealing and upload) is
-    never open while a level is dispatched, here and on one device.
+    never open while a level is dispatched, here and on one device;
+  * split into many small chunks, a triangle count still equals the one-
+    device count, and each runner reads it once a query (one replicated
+    device accumulator on the mesh).
 """
 import json
 import os
@@ -77,7 +80,11 @@ out["overlaps"] = sum(d.t0 < s.t1 and s.t0 < d.t1
 
 chunk = 128
 m = Miner(g, mesh=4, chunk=chunk)
-m.count("triangle")
+one = Miner(g, chunk=chunk)
+out["chunked"] = {"counts": [m.count("triangle"), one.count("triangle")],
+                  "syncs": [m.runner.stats["host_syncs"],
+                            one.runner.stats["host_syncs"]],
+                  "dispatches": m.runner.stats["level_kernel_dispatches"]}
 slots, last = 0, {}
 for cap, v0, v1, n in shard_edge_steps(g, chunk, 4):
     slots += v0.shape[0]
@@ -122,6 +129,14 @@ def test_pad_and_feed_items_fill_every_super_step(mesh4):
 def test_mesh_psum_scope_in_the_count_executable(mesh4):
     assert mesh4["lowered_scoped"]
     assert mesh4["all_reduce_scoped"] and all(mesh4["all_reduce_scoped"])
+
+
+def test_many_chunks_count_once_on_the_mesh_and_one_device(mesh4):
+    ch = mesh4["chunked"]
+    assert ch["counts"][0] == ch["counts"][1] == \
+        mesh4["parity"]["triangle"][1]
+    assert ch["dispatches"] > 1
+    assert ch["syncs"] == [1, 1]
 
 
 def test_feed_step_never_spans_a_dispatch_on_the_mesh(mesh4):

@@ -22,13 +22,15 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from repro.graph import build_csr
 from repro.graph.generators import clique_planted, erdos_renyi, \
     powerlaw_cluster
 from repro.mining import plan as P
 from repro.mining import reference
-from repro.mining.engine import WaveRunner, half_edges
+from repro.mining.engine import COUNT_LIMBS, WaveRunner, count_acc_add, \
+    count_acc_value, half_edges
 from repro.mining.session import ExecutableCache, Miner, mesh_signature
 from repro.mining.shard import FEED_PARTITIONS, ShardedWaveRunner, \
     shard_edge_steps
@@ -136,14 +138,20 @@ def test_feed_rejects_unknown_partition_mode():
 
 def test_finalize_reassembles_limb_quads_exactly():
     """A psum'd 4-limb partial and the plain (hi, lo) pair for the same
-    count reduce to the same integer — including hi words past 2^16."""
+    count add the same amount to a count accumulator — including hi words
+    past 2^16 — and ``_finalize`` returns the exact total read from it."""
     r = WaveRunner(TINY)
     plan = P.compile_pattern(P.TRIANGLE)
     hi, lo = (1 << 17) + 9, (1 << 20) + 123
-    pair = np.array([hi, lo], np.int64)
-    quad = np.array([hi >> 16, hi & 0xFFFF, lo >> 16, lo & 0xFFFF], np.int64)
-    assert r._finalize(plan, [pair]) == r._finalize(plan, [quad])
-    assert r._finalize(plan, [quad]) == (hi << 16) + lo
+    pair = jnp.array([hi, lo], jnp.int32)
+    quad = jnp.array([hi >> 16, hi & 0xFFFF, lo >> 16, lo & 0xFFFF],
+                     jnp.int32)
+    zero = jnp.zeros(COUNT_LIMBS, jnp.int32)
+    a = count_acc_add(zero, *WaveRunner._count_weights(pair))
+    b = count_acc_add(zero, *ShardedWaveRunner._count_weights(quad))
+    assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert r._finalize(plan, [count_acc_value(a)]) == \
+        r._finalize(plan, [count_acc_value(b)]) == (hi << 16) + lo
 
 
 # ---------------------------------------------------------------------------
@@ -200,6 +208,20 @@ def test_sharded_counts_bit_identical(name):
     assert m8.count("triangle") == reference.triangle_count(g)
     assert dict(zip(MOTIF_NAMES, m8.count_many(MOTIF_NAMES))) == \
         reference.four_motif_counts(g)
+
+
+@needs8
+@pytest.mark.parametrize("query", ["triangle", "4-clique"])
+def test_sharded_many_chunk_counts_read_each_leaf_once(query):
+    """Tiny chunks: many count dispatches add to one replicated device
+    accumulator per leaf; the count equals the single-device one and each
+    runner reads the leaf once a query, beside its expand metas."""
+    g = GRAPHS["cliq"]
+    m1, m8 = Miner(g, chunk=128), Miner(g, mesh=8, chunk=128)
+    assert m8.count(query) == m1.count(query) > 0
+    for m in (m1, m8):
+        metas = m.runner.level_execs.get(("expand", 2), 0)
+        assert m.stats["runner"]["host_syncs"] == 1 + metas
 
 
 @needs8
